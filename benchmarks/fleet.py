@@ -474,6 +474,8 @@ def main():
     ap.add_argument("--check", action="store_true",
                     help="exit non-zero on any fleet-contract violation")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     payload = fleet_bench(seed=args.seed, smoke=args.smoke)
     print(format_fleet(payload))
     print("[fleet] wrote BENCH_fleet.json")

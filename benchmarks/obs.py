@@ -329,6 +329,8 @@ def main():
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.tp}")
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     payload = bench_obs(seed=args.seed, smoke=args.smoke, tp=args.tp)
     print(f"[obs] engine tokens/sec off={payload['engine_tokens_per_s_off']}"

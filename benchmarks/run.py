@@ -25,6 +25,8 @@ def main() -> None:
     ap.add_argument("--skip-obs", action="store_true",
                     help="skip the tracing-overhead benchmark")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     csv_lines = ["name,us_per_call,derived"]
 

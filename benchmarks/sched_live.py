@@ -620,7 +620,7 @@ def _sharded_cfg():
         head_dim=16, d_ff=128, vocab_size=256, compute_dtype="float32")
 
 
-def _parity_tokens(cfg, params, mesh) -> List[int]:
+def parity_tokens(cfg, params, mesh) -> List[int]:
     """Engine-only deterministic two-turn drive (submit+retain, then
     extend): the greedy token ids are the parity oracle across meshes."""
     from repro.serving import PagedInferenceEngine
@@ -654,10 +654,10 @@ def sharded_bench(seed: int = 0, smoke: bool = False) -> dict:
     params = build(cfg).init_params(jax.random.PRNGKey(seed))
 
     # ---- parity oracle: single-device vs every mesh width --------------
-    ref = _parity_tokens(cfg, params, None)
+    ref = parity_tokens(cfg, params, None)
     parity = {"tokens_single": ref}
     for tp in SHARDED_TPS:
-        toks = _parity_tokens(cfg, params, make_tp_mesh(tp))
+        toks = parity_tokens(cfg, params, make_tp_mesh(tp))
         parity[f"tp{tp}_tokens_equal"] = bool(toks == ref)
 
     # ---- scaling curve through the full middleware stack ---------------
@@ -771,6 +771,14 @@ def main():
                          "plan with the full recovery stack armed; writes "
                          "BENCH_chaos.json (gates with --check)")
     args = ap.parse_args()
+    if args.sharded:
+        # must land before ANY jax import (jax reads XLA_FLAGS at import
+        # time) — everything above imports jax lazily for exactly this
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=4")
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     if args.chaos:
         payload = chaos_soak(seed=args.seed, smoke=args.smoke)
@@ -781,11 +789,6 @@ def main():
         return
 
     if args.sharded:
-        # must land before ANY jax import (jax reads XLA_FLAGS at import
-        # time) — everything above imports jax lazily for exactly this
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=4")
         payload = sharded_bench(seed=args.seed, smoke=args.smoke)
         print(format_sharded(payload))
         print("[sched_live] wrote BENCH_sharded.json")

@@ -649,6 +649,8 @@ def main():
     ap.add_argument("--check", action="store_true",
                     help="exit non-zero if any overload gate fails")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     payload = overload_bench(seed=args.seed, smoke=args.smoke,
                              factor=args.factor)
     print(format_overload(payload))

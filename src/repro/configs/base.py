@@ -81,7 +81,10 @@ class ModelConfig:
     # --- compute ---
     param_dtype: str = "float32"    # optimizer-held precision
     compute_dtype: str = "bfloat16"
-    use_pallas: bool = False        # True on real TPU; CPU dry-run uses XLA ref
+    use_pallas: bool = False        # dense/training paths only (flash and
+                                    # decode kernels); the paged engine
+                                    # picks its kernel from its device's
+                                    # platform, never from this flag
     remat: bool = True              # checkpoint each layer in train_step
     # --- performance knobs (§Perf hillclimb; defaults = paper-faithful) ---
     gqa_mode: str = "tiled"         # optimized default (§Perf A1c):

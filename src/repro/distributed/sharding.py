@@ -193,12 +193,12 @@ def decode_state_shardings(cfg: ModelConfig, mesh: Mesh, state_tree) -> Any:
 # --------------------------------------------------------------------------
 
 def kv_pool_pspec() -> P:
-    """Paged KV pool ``(L, num_blocks, blk, hkv, hd)``: KV heads over
+    """Paged KV pool ``(L, num_blocks, hkv, blk, hd)``: KV heads over
     ``tp``, everything else local. Block ids (and therefore page tables)
     are shard-invariant — every shard holds the SAME blocks for its own
     head slice, so one host-side page table per sequence drives all
     shards."""
-    return P(None, None, None, TP, None)
+    return P(None, None, TP, None, None)
 
 
 def megastep_input_pspecs() -> Tuple[P, P, P, P, P]:

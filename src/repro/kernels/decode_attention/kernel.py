@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -89,7 +88,7 @@ def decode_attention_bhd(q, k, v, kv_len, *, scale=None, blk_k: int = 512,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, 1, dv), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(kv_len, jnp.int32).reshape(1), q4, k, v)

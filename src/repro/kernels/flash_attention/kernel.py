@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -102,7 +101,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, scale=None,
             pltpu.VMEM((blk_q,), jnp.float32),
             pltpu.VMEM((blk_q, dv), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,27 +1,14 @@
-"""Public wrappers for paged attention: model-layout queries against the
-pooled block cache (num_blocks, blk, hkv, d) + per-sequence page tables. The
+"""Public wrapper for paged attention: model-layout queries against the
+pooled block cache (num_blocks, hkv, blk, d) + per-sequence page tables. The
 pool layout is the allocator's native layout, so no transpose or gather of
 the cache happens on the hot path — the kernel's index maps do the page
-walk. ``paged_attention`` is the decode (one query token) form;
-``paged_prefill_attention`` is the chunked-prefill form the megastep uses
-(decode rows are its C == 1 special case; the chunk axis C is whatever
+walk. ``paged_prefill_attention`` is the chunked-prefill form the megastep
+uses; decode rows are its C == 1 special case (the chunk axis C is whatever
 pow2 trace bucket the engine's token-budget packer selected — per-row
 ``valids`` carry the ragged real widths)."""
 from __future__ import annotations
 
-from repro.kernels.paged_attention.kernel import (paged_attention_bhd,
-                                                  paged_prefill_attention_bcd)
-
-
-def paged_attention(q, k_pool, v_pool, lens, page_tables, *, scale=None,
-                    interpret: bool = False):
-    """q: (b, 1, hq, d); k_pool/v_pool: (nb, blk, hkv, d|dv); lens: (b,)
-    valid kv lengths; page_tables: (b, npages) int32. Returns (b, 1, hq, dv).
-    """
-    b, one, hq, d = q.shape
-    o = paged_attention_bhd(q[:, 0], k_pool, v_pool, lens, page_tables,
-                            scale=scale, interpret=interpret)
-    return o.reshape(b, 1, hq, -1)
+from repro.kernels.paged_attention.kernel import paged_prefill_attention_bcd
 
 
 def paged_prefill_attention(q, k_pool, v_pool, cache_lens, valids,

@@ -3,8 +3,9 @@
 ``paged_attention_ref`` (decode) and ``paged_prefill_attention_ref``
 (chunked prefill / megastep rows) gather each sequence's pages in table
 order — materialising the contiguous view the kernels avoid — then run a
-masked softmax with per-sequence offsets and valid lengths. They are the
-CPU fallback the models use when ``cfg.use_pallas`` is off. Like the
+masked softmax with per-sequence offsets and valid lengths.
+``paged_prefill_attention_ref`` is the path the paged engine runs on a
+CPU device (the kernel runs on a TPU). Like the
 kernels, they take the chunk axis C from the input shape and the ragged
 per-row real widths from ``valids`` — the oracles stay in lockstep with
 the kernels across every token-budget trace bucket, which is what the
@@ -26,30 +27,32 @@ NEG_INF = -1e30
 
 
 def gather_pages(pool, page_tables):
-    """pool: (nb, blk, hkv, d); page_tables: (b, npages) ->
+    """pool: (nb, hkv, blk, d); page_tables: (b, npages) ->
     (b, npages*blk, hkv, d) contiguous per-sequence view (position order)."""
     b, npages = page_tables.shape
-    blk, hkv, d = pool.shape[1:]
-    return pool[page_tables].reshape(b, npages * blk, hkv, d)
+    hkv, blk, d = pool.shape[1:]
+    return pool[page_tables].transpose(0, 1, 3, 2, 4).reshape(
+        b, npages * blk, hkv, d)
 
 
 def paged_attention_ref(q, k_pool, v_pool, lens, page_tables, *, scale=None):
-    """q: (b, hq, d); pools: (nb, blk, hkv, d|dv); lens: (b,) int32;
-    page_tables: (b, npages) int32. Returns (b, hq, dv)."""
+    """Decode oracle. q: (b, hq, d); pools: (nb, hkv, blk, d|dv); lens: (b,)
+    int32; page_tables: (b, npages) int32. Q head ``h`` reads kv head
+    ``h % hkv`` (g-major, the kernel's pairing). Returns (b, hq, dv)."""
     b, hq, d = q.shape
-    hkv, dv = k_pool.shape[2], v_pool.shape[-1]
+    hkv, dv = k_pool.shape[1], v_pool.shape[-1]
     g = hq // hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     k = gather_pages(k_pool, page_tables).transpose(0, 2, 1, 3)  # (b,hkv,S,d)
     v = gather_pages(v_pool, page_tables).transpose(0, 2, 1, 3)
     s = k.shape[2]
-    qg = q.reshape(b, hkv, g, d)
-    sc = jnp.einsum("bhgd,bhkd->bhgk", qg.astype(jnp.float32),
+    qg = q.reshape(b, g, hkv, d)
+    sc = jnp.einsum("bghd,bhkd->bghk", qg.astype(jnp.float32),
                     k.astype(jnp.float32)) * scale
     mask = jnp.arange(s)[None] < jnp.asarray(lens)[:, None]     # (b, S)
     sc = jnp.where(mask[:, None, None], sc, -jnp.inf)
     p = jax.nn.softmax(sc, axis=-1)
-    o = jnp.einsum("bhgk,bhkd->bhgd", p.astype(jnp.float32),
+    o = jnp.einsum("bghk,bhkd->bghd", p.astype(jnp.float32),
                    v.astype(jnp.float32))
     return o.reshape(b, hq, dv).astype(q.dtype)
 
@@ -69,19 +72,20 @@ def _mixed_mask(C, S, cache_lens, valids):
 
 def paged_prefill_attention_ref(q, k_pool, v_pool, cache_lens, valids,
                                 page_tables, *, scale=None,
-                                pairing: str = "kv_major"):
+                                pairing: str = "g_major"):
     """Batched gather-based oracle for chunked-prefill paged attention.
 
-    q: (b, C, hq, d); pools: (nb, blk, hkv, d|dv); cache_lens/valids: (b,)
+    q: (b, C, hq, d); pools: (nb, hkv, blk, d|dv); cache_lens/valids: (b,)
     int32; page_tables: (b, npages) int32. Same row semantics as the kernel
     (see ``kernel.paged_prefill_attention_bcd``). ``pairing`` selects which
-    kv head q-head h reads — "kv_major" (h // g, the kernels' layout) or
-    "g_major" (h % hkv, what full paths running gqa_mode="tiled" realize).
+    kv head q-head h reads — "g_major" (h % hkv: the kernel's pairing, and
+    what full paths running gqa_mode="tiled" realize) or "kv_major"
+    (h // g, gqa_mode="grouped").
     Returns (b, C, hq, dv). Safe-softmax throughout: fully-padded rows
     produce finite garbage, never NaN, so discarded rows cannot poison the
     pool on the next scatter."""
     b, C, hq, d = q.shape
-    hkv, dv = k_pool.shape[2], v_pool.shape[-1]
+    hkv, dv = k_pool.shape[1], v_pool.shape[-1]
     g = hq // hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     k = gather_pages(k_pool, page_tables)            # (b, S, hkv, d)
@@ -118,8 +122,8 @@ def paged_prefill_attention_gathered_oracle(q, k_pool, v_pool, cache_lens,
     independent check of the math, at fp32 tolerance.)"""
     from repro.kernels.paged_attention.kernel import \
         paged_prefill_attention_contig
-    kg = gather_pages(k_pool, page_tables)
-    vg = gather_pages(v_pool, page_tables)
+    kg = gather_pages(k_pool, page_tables).transpose(0, 2, 1, 3)
+    vg = gather_pages(v_pool, page_tables).transpose(0, 2, 1, 3)
     return paged_prefill_attention_contig(q, kg, vg, cache_lens, valids,
                                           page_tables, scale=scale,
                                           interpret=True)

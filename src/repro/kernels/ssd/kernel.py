@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 
 def _kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_ref, st_scr, *,
@@ -110,7 +109,7 @@ def ssd_bhsd(x, dt, A, B, C, *, chunk: int = 256, interpret: bool = False):
             jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
             jax.ShapeDtypeStruct((b, h, n, p), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(A.astype(jnp.float32), x, dt, B, C)

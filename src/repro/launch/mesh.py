@@ -7,18 +7,27 @@ while the dry-run forces 512 host devices via XLA_FLAGS before any import.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, **kw):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules here
+    place arrays with ``NamedSharding`` and let the compiler propagate the
+    rest, which ``Explicit`` axes (the default since JAX 0.7) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh():
     """1x1 mesh over whatever the host has — smoke tests / examples."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return _auto_mesh((1, n), ("data", "model"))
 
 
 def make_tp_mesh(tp: int):
@@ -35,4 +44,4 @@ def make_tp_mesh(tp: int):
         raise ValueError(
             f"tp={tp} exceeds the {n} visible device(s); on CPU set "
             "XLA_FLAGS=--xla_force_host_platform_device_count to fake more")
-    return jax.make_mesh((tp,), ("tp",), devices=jax.devices()[:tp])
+    return _auto_mesh((tp,), ("tp",), devices=jax.devices()[:tp])

@@ -51,6 +51,7 @@ from repro.core import AgentRM, AgentRMConfig
 from repro.core.scheduler.task import QueueClass
 from repro.distributed.elastic import FleetBackend
 from repro.distributed.sharding import validate_tp
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_tp_mesh
 from repro.models import build
 from repro.obs import Observability, TraceConfig
@@ -103,8 +104,27 @@ def build_obs(args) -> Observability:
     return Observability(trace=trace)
 
 
+def init_serving_params(cfg, seed: int = 0):
+    """Serving weights, made directly in the compute dtype under ``jit``.
+
+    Returns ``(cfg, params)`` with ``cfg.param_dtype`` set to the compute
+    dtype. Serving never updates weights, so an f32 master copy would only
+    double their bytes (chatglm3-6b: 25 GB in f32 does not fit a 16 GB
+    chip, 12.5 GB in bf16 does) and add a per-step cast inside the
+    megastep. The values are the f32 initialiser's, rounded once; ``jit``
+    fuses the rounding into the generator, so no f32 copy of a stacked
+    leaf is ever materialised."""
+    cfg = cfg.replace(param_dtype=cfg.compute_dtype)
+    params = jax.jit(build(cfg).init_params)(jax.random.PRNGKey(seed))
+    return cfg, params
+
+
 def build_backend(cfg, params, args, obs=None):
-    """Engine + middleware backend from CLI args (separated for tests)."""
+    """Engine + middleware backend from CLI args (separated for tests).
+
+    A fleet without a mesh puts member ``i`` on device ``i mod n``: each
+    member's params, pools and megastep live on its own chip (on one
+    device every member shares it)."""
     if not args.paged:
         if args.token_budget:
             raise SystemExit("--token-budget requires --paged (the dense "
@@ -128,7 +148,7 @@ def build_backend(cfg, params, args, obs=None):
             os.path.join(args.spill_dir, name),
             capacity_bytes=args.spill_capacity_mb << 20)
 
-    def make_engine(name: str = "engine"):
+    def make_engine(name: str = "engine", params=params):
         return PagedInferenceEngine(
             cfg, params, num_blocks=args.num_blocks,
             block_size=args.block_size, max_batch=args.max_batch,
@@ -146,12 +166,17 @@ def build_backend(cfg, params, args, obs=None):
 
     fleet_n = getattr(args, "fleet", 1) or 1
     if fleet_n > 1:
+        devices = jax.devices()
         members = []
         for i in range(fleet_n):
             name = f"engine{i}"
+            member_params = params
+            if mesh is None and len(devices) > 1:
+                member_params = jax.device_put(params,
+                                               devices[i % len(devices)])
 
-            def factory(name=name):
-                return make_engine(name)
+            def factory(name=name, member_params=member_params):
+                return make_engine(name, member_params)
 
             try:
                 eng = factory()
@@ -239,7 +264,8 @@ def print_obs_summary(obs: Observability, engine_names=("engine",)):
               f"{rec.dropped} dropped (capacity {rec.capacity})")
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI (``main`` and ``chip_smoke.py`` parse with it)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -255,7 +281,9 @@ def main(argv=None) -> int:
     ap.add_argument("--max-batch", type=int, default=4,
                     help="paged decode batch width (rows per megastep)")
     ap.add_argument("--num-blocks", type=int, default=129)
-    ap.add_argument("--block-size", type=int, default=8)
+    ap.add_argument("--block-size", type=int, default=16,
+                    help="tokens per KV page (16 = one bf16 sublane tile "
+                         "of the paged kernel's (blk, head_dim) slab)")
     ap.add_argument("--prefill-chunk", type=int, default=16)
     ap.add_argument("--token-budget", type=int, default=0,
                     help="stall-free per-step token budget (0 = fixed "
@@ -318,7 +346,11 @@ def main(argv=None) -> int:
                     metavar="SEC",
                     help="autopilot inter-token-latency p95 SLO in "
                          "seconds (default 0.5; requires --autopilot)")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
     if args.turn_timeout <= 0:
         raise SystemExit("invalid --turn-timeout: must be > 0 seconds")
     if args.step_deadline < 0:
@@ -355,11 +387,10 @@ def main(argv=None) -> int:
         raise SystemExit("invalid SLO: --slo-ttft-p95 and --slo-itl-p95 "
                          "must be > 0 seconds")
 
+    use_compile_cache()
     obs = build_obs(args)
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    cfg = cfg.replace(remat=False)
-    model = build(cfg)
-    params = model.init_params(jax.random.PRNGKey(0))
+    cfg, params = init_serving_params(cfg.replace(remat=False))
     engine, backend = build_backend(cfg, params, args, obs=obs)
     fleet = backend if isinstance(backend, FleetBackend) else None
     lanes = args.max_batch * args.fleet if args.paged else args.lanes

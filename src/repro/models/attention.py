@@ -116,11 +116,28 @@ def gqa_decode(params, x, cache_k, cache_v, cache_len, cfg: ModelConfig,
     return out, (cache_k, cache_v)
 
 
+def paged_kernel_attention(q, k_pool, v_pool, cache_lens, valids,
+                           page_tables, cfg: ModelConfig, *,
+                           interpret: bool = False):
+    """The Pallas paged kernel for (B, C) query rows. It pairs q head ``h``
+    with kv head ``h % hkv``, the pairing of ``gqa_mode="tiled"``; a model
+    pairing heads the other way would silently become a different model,
+    so that combination is refused."""
+    if cfg.gqa_mode != "tiled":
+        raise ValueError(
+            f"the paged kernel pairs heads g-major (gqa_mode='tiled'); "
+            f"{cfg.name} has gqa_mode={cfg.gqa_mode!r}")
+    from repro.kernels.paged_attention import ops as pa
+    return pa.paged_prefill_attention(q, k_pool, v_pool, cache_lens, valids,
+                                      page_tables, interpret=interpret)
+
+
 def gqa_decode_paged(params, x, k_pool, v_pool, page_tables, cache_len,
-                     cfg: ModelConfig, *, interpret: bool = False):
+                     cfg: ModelConfig, *, paged_kernel: bool = False,
+                     interpret: bool = False):
     """Paged-cache decode: one token per sequence against pooled KV blocks.
 
-    x: (b, 1, d); k_pool/v_pool: (num_blocks, blk, hkv, hd) — one layer's
+    x: (b, 1, d); k_pool/v_pool: (num_blocks, hkv, blk, hd) — one layer's
     slice of the shared block pool; page_tables: (b, npages) int32 block ids
     in position order (entries beyond the live length must be valid ids —
     the engine pads with the reserved null block 0); cache_len: (b,) int32
@@ -129,12 +146,12 @@ def gqa_decode_paged(params, x, k_pool, v_pool, page_tables, cache_len,
     The new token's K/V is scattered into block ``page_tables[b, len//blk]``
     at offset ``len % blk``; rows whose page table is all-null (inactive
     decode slots) harmlessly write to the null block. Attention then runs
-    either through the Pallas paged kernel (page-table scalar prefetch, no
-    contiguous cache copy) or a gather-based jnp path on CPU.
+    either through the Pallas paged kernel (``paged_kernel``: page-table
+    scalar prefetch, no contiguous cache copy) or a gather-based jnp path.
     """
     b = x.shape[0]
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    blk = k_pool.shape[1]
+    blk = k_pool.shape[2]
     cache_len = jnp.asarray(cache_len)
     q = (x @ params["wq"]).reshape(b, 1, hq, hd)
     k = (x @ params["wk"]).reshape(b, 1, hkv, hd)
@@ -148,12 +165,12 @@ def gqa_decode_paged(params, x, k_pool, v_pool, page_tables, cache_len,
     rows = jnp.arange(b)
     bids = page_tables[rows, cache_len // blk]
     offs = cache_len % blk
-    k_pool = k_pool.at[bids, offs].set(k[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[bids, offs].set(v[:, 0].astype(v_pool.dtype))
-    if cfg.use_pallas:
-        from repro.kernels.paged_attention import ops as pa
-        o = pa.paged_attention(q, k_pool, v_pool, cache_len + 1, page_tables,
-                               interpret=interpret)
+    k_pool = k_pool.at[bids, :, offs].set(k[:, 0].astype(k_pool.dtype))
+    v_pool = v_pool.at[bids, :, offs].set(v[:, 0].astype(v_pool.dtype))
+    if paged_kernel:
+        o = paged_kernel_attention(q, k_pool, v_pool, cache_len,
+                                   jnp.ones_like(cache_len), page_tables,
+                                   cfg, interpret=interpret)
     else:
         from repro.kernels.paged_attention.ref import gather_pages
         kg = gather_pages(k_pool, page_tables).astype(q.dtype)
@@ -166,13 +183,14 @@ def gqa_decode_paged(params, x, k_pool, v_pool, page_tables, cache_len,
 
 
 def gqa_prefill_chunk_paged(params, x, k_pool, v_pool, page_table, cache_len,
-                            valid, cfg: ModelConfig):
+                            valid, cfg: ModelConfig, *,
+                            paged_kernel: bool = False):
     """Sarathi-style chunked prefill against a paged cache: a fixed-width
     window of ``C`` prompt tokens for ONE sequence is processed in a single
     call, attending causally within the chunk and fully over the sequence's
     already-written pages.
 
-    x: (1, C, d) chunk embeddings; k_pool/v_pool: (num_blocks, blk, hkv, hd)
+    x: (1, C, d) chunk embeddings; k_pool/v_pool: (num_blocks, hkv, blk, hd)
     one layer's pool slice; page_table: (npages,) int32 block ids in position
     order (null-padded); cache_len: scalar int32 tokens already resident
     *before* this chunk; valid: scalar int32 — how many of the C positions
@@ -182,7 +200,7 @@ def gqa_prefill_chunk_paged(params, x, k_pool, v_pool, page_table, cache_len,
     """
     b, C, _ = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    blk = k_pool.shape[1]
+    blk = k_pool.shape[2]
     npages = page_table.shape[0]
     cache_len = jnp.asarray(cache_len)
     valid = jnp.asarray(valid)
@@ -201,21 +219,26 @@ def gqa_prefill_chunk_paged(params, x, k_pool, v_pool, page_table, cache_len,
     page_idx = jnp.clip(pos // blk, 0, npages - 1)
     bids = jnp.where(live, page_table[page_idx], 0)
     offs = pos % blk
-    k_pool = k_pool.at[bids, offs].set(k[0].astype(k_pool.dtype))
-    v_pool = v_pool.at[bids, offs].set(v[0].astype(v_pool.dtype))
-    from repro.kernels.paged_attention.ref import gather_pages
-    kg = gather_pages(k_pool, page_table[None]).astype(q.dtype)
-    vg = gather_pages(v_pool, page_table[None]).astype(q.dtype)
-    pairing = "g_major" if cfg.gqa_mode == "tiled" else "kv_major"
-    o = simple_attention(q, kg, vg, causal=True, q_offset=cache_len,
-                         kv_len=cache_len + valid,
-                         f32_inputs=cfg.attn_f32_inputs, pairing=pairing)
+    k_pool = k_pool.at[bids, :, offs].set(k[0].astype(k_pool.dtype))
+    v_pool = v_pool.at[bids, :, offs].set(v[0].astype(v_pool.dtype))
+    if paged_kernel:
+        o = paged_kernel_attention(q, k_pool, v_pool, cache_len[None],
+                                   valid[None], page_table[None], cfg)
+    else:
+        from repro.kernels.paged_attention.ref import gather_pages
+        kg = gather_pages(k_pool, page_table[None]).astype(q.dtype)
+        vg = gather_pages(v_pool, page_table[None]).astype(q.dtype)
+        pairing = "g_major" if cfg.gqa_mode == "tiled" else "kv_major"
+        o = simple_attention(q, kg, vg, causal=True, q_offset=cache_len,
+                             kv_len=cache_len + valid,
+                             f32_inputs=cfg.attn_f32_inputs, pairing=pairing)
     out = o.reshape(b, C, hq * hd) @ params["wo"]
     return out, (k_pool, v_pool)
 
 
 def gqa_mixed_step_paged(params, x, k_pool, v_pool, page_tables, cache_lens,
-                         valids, cfg: ModelConfig, *, interpret: bool = False,
+                         valids, cfg: ModelConfig, *,
+                         paged_kernel: bool = False, interpret: bool = False,
                          axis_name: Optional[str] = None):
     """One fused Sarathi megastep row set: every row of the ``(B, C)``
     batch is a prefill chunk — decode rows simply carry ``valids == 1`` —
@@ -223,7 +246,7 @@ def gqa_mixed_step_paged(params, x, k_pool, v_pool, page_tables, cache_lens,
     over chunk + resident history.
 
     x: (B, C, d) embeddings (token padding beyond ``valids`` is garbage the
-    caller discards); k_pool/v_pool: (num_blocks, blk, hkv, hd) one layer's
+    caller discards); k_pool/v_pool: (num_blocks, hkv, blk, hd) one layer's
     pool slice; page_tables: (B, npages) int32, null-padded; cache_lens:
     (B,) int32 tokens resident *before* this step; valids: (B,) int32 real
     tokens per row (0 = inactive slot; its writes land in the null block and
@@ -234,7 +257,9 @@ def gqa_mixed_step_paged(params, x, k_pool, v_pool, page_tables, cache_lens,
     of different real widths coexist in one dispatch and a wider bucket
     only adds masked padding columns. Per-row isolation is the page table
     itself: a row only reads/writes its own blocks, so batching rows into
-    one dispatch cannot change any row's math.
+    one dispatch cannot change any row's math. ``paged_kernel`` runs the
+    attention through the Pallas kernel (the engine sets it on a TPU);
+    otherwise the jnp gather reference computes the same rows.
 
     Under the sharded megastep (DESIGN.md §13) this runs INSIDE shard_map
     with per-shard views: ``cfg`` carries the LOCAL head counts
@@ -246,7 +271,7 @@ def gqa_mixed_step_paged(params, x, k_pool, v_pool, page_tables, cache_lens,
     """
     b, C, _ = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    blk = k_pool.shape[1]
+    blk = k_pool.shape[2]
     npages = page_tables.shape[1]
     cache_lens = jnp.asarray(cache_lens)
     valids = jnp.asarray(valids)
@@ -266,12 +291,11 @@ def gqa_mixed_step_paged(params, x, k_pool, v_pool, page_tables, cache_lens,
     bids = jnp.where(live, jnp.take_along_axis(page_tables, page_idx, axis=1),
                      0)
     offs = pos % blk
-    k_pool = k_pool.at[bids, offs].set(k.astype(k_pool.dtype))
-    v_pool = v_pool.at[bids, offs].set(v.astype(v_pool.dtype))
-    if cfg.use_pallas:
-        from repro.kernels.paged_attention import ops as pa
-        o = pa.paged_prefill_attention(q, k_pool, v_pool, cache_lens, valids,
-                                       page_tables, interpret=interpret)
+    k_pool = k_pool.at[bids, :, offs].set(k.astype(k_pool.dtype))
+    v_pool = v_pool.at[bids, :, offs].set(v.astype(v_pool.dtype))
+    if paged_kernel:
+        o = paged_kernel_attention(q, k_pool, v_pool, cache_lens, valids,
+                                   page_tables, cfg, interpret=interpret)
     else:
         from repro.kernels.paged_attention.ref import \
             paged_prefill_attention_ref

@@ -279,24 +279,26 @@ def decode_step(params, state: Dict, token, cache_len, cfg: ModelConfig):
 def init_paged_pools(cfg: ModelConfig, num_blocks: int,
                      block_size: int) -> Dict:
     """Pooled paged KV state for the GQA family: per-layer block pools of
-    shape (L, num_blocks, blk, hkv, hd). Block ids are shared across layers
+    shape (L, num_blocks, hkv, blk, hd). Block ids are shared across layers
     (every layer stores the same token positions in the same block id), so
-    one page table per sequence serves the whole stack."""
+    one page table per sequence serves the whole stack. Each page is
+    head-major, so the paged kernel reads one (blk, hd) slab per kv head."""
     assert cfg.family in ("dense", "moe", "vlm") and cfg.mla is None, \
         "paged KV pools target the decoder-only GQA family"
     assert cfg.first_dense_layers == 0, \
         "paged decode does not support heterogeneous leading layers yet"
     ct = jnp.dtype(cfg.kv_cache_dtype or cfg.compute_dtype)
     hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-    shape = (cfg.n_layers, num_blocks, block_size, hkv, hd)
+    shape = (cfg.n_layers, num_blocks, hkv, block_size, hd)
     return {"k": jnp.zeros(shape, ct), "v": jnp.zeros(shape, ct)}
 
 
 def decode_step_paged(params, pools: Dict, token, cache_len, page_tables,
-                      cfg: ModelConfig):
+                      cfg: ModelConfig, *, paged_kernel: bool = False):
     """Paged analogue of ``decode_step``: token (b, 1), cache_len (b,) int32
     lengths before this token, page_tables (b, npages) int32.
-    Returns (logits (b, 1, V) f32, updated pools)."""
+    ``paged_kernel`` selects the Pallas attention kernel (see
+    ``mixed_step_paged``). Returns (logits (b, 1, V) f32, updated pools)."""
     params = cast_floats(params, cfg.compute_dtype)
     x = _embed(params, token, cfg)
 
@@ -304,7 +306,8 @@ def decode_step_paged(params, pools: Dict, token, cache_len, page_tables,
         lp, kp, vp = xs
         hh = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         a, (kp, vp) = attn_mod.gqa_decode_paged(
-            lp["attn"], hh, kp, vp, page_tables, cache_len, cfg)
+            lp["attn"], hh, kp, vp, page_tables, cache_len, cfg,
+            paged_kernel=paged_kernel)
         h = h + a
         m, _, _ = _mlp_or_moe(lp, h, cfg)
         return h + m, (kp, vp)
@@ -316,7 +319,8 @@ def decode_step_paged(params, pools: Dict, token, cache_len, page_tables,
 
 
 def prefill_chunk_paged(params, pools: Dict, tokens, cache_len, valid,
-                        page_table, cfg: ModelConfig):
+                        page_table, cfg: ModelConfig, *,
+                        paged_kernel: bool = False):
     """Chunked prefill for one sequence over the paged pools (Sarathi-style
     admission: a long prompt enters the batch ``C`` tokens per engine step
     instead of blocking it). tokens: (1, C) int32 (null-padded to the fixed
@@ -331,7 +335,8 @@ def prefill_chunk_paged(params, pools: Dict, tokens, cache_len, valid,
         lp, kp, vp = xs
         hh = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         a, (kp, vp) = attn_mod.gqa_prefill_chunk_paged(
-            lp["attn"], hh, kp, vp, page_table, cache_len, valid, cfg)
+            lp["attn"], hh, kp, vp, page_table, cache_len, valid, cfg,
+            paged_kernel=paged_kernel)
         h = h + a
         m, _, _ = _mlp_or_moe(lp, h, cfg)
         return h + m, (kp, vp)
@@ -344,7 +349,7 @@ def prefill_chunk_paged(params, pools: Dict, tokens, cache_len, valid,
 
 def mixed_step_paged(params, pools: Dict, tokens, cache_lens, valids,
                      page_tables, cfg: ModelConfig, poison_mask=None, *,
-                     axis_name=None):
+                     paged_kernel: bool = False, axis_name=None):
     """The megastep forward: ONE jitted call advances the whole mixed batch
     one engine iteration — decode rows are width-1 prefill rows (Sarathi
     batch fusion over the paged pools).
@@ -365,6 +370,10 @@ def mixed_step_paged(params, pools: Dict, tokens, cache_lens, valids,
     rows (valids == 0) produce garbage ids the caller ignores; their K/V
     writes land in the reserved null block.
 
+    ``paged_kernel`` runs attention through the Pallas paged kernel; the
+    engine sets it from the platform of its device (the kernel on a TPU,
+    the jnp gather reference on a CPU).
+
     ``axis_name`` is set when this body runs inside the sharded megastep's
     shard_map (DESIGN.md §13): ``cfg`` then carries per-shard head counts,
     ``pools`` is this shard's KV-head slice, and each layer's attention
@@ -379,7 +388,7 @@ def mixed_step_paged(params, pools: Dict, tokens, cache_lens, valids,
         hh = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         a, (kp, vp) = attn_mod.gqa_mixed_step_paged(
             lp["attn"], hh, kp, vp, page_tables, cache_lens, valids, cfg,
-            axis_name=axis_name)
+            paged_kernel=paged_kernel, axis_name=axis_name)
         h = h + a
         m, _, _ = _mlp_or_moe(lp, h, cfg)
         return h + m, (kp, vp)

@@ -183,6 +183,21 @@ class PagedInferenceEngine:
                     "legacy per-sequence dispatch loop is single-device")
             validate_tp(cfg, mesh.shape[TP])
         self.tp = mesh.shape[TP] if mesh is not None else 1
+        # ---- the device this engine runs on, and its attention path -----
+        # The first mesh device, else wherever the params live (a fleet
+        # member's params are committed to its own chip). Pools are built
+        # there, and the platform picks the attention path once: the
+        # Pallas paged kernel on a TPU, the jnp gather reference on a CPU.
+        # A kernel that fails to lower on the chip is an error, never a
+        # quiet fall back to the reference or to interpret mode.
+        if mesh is not None:
+            self.device = mesh.devices.flat[0]
+        else:
+            leaf = jax.tree_util.tree_leaves(params)[0]
+            self.device = (next(iter(leaf.devices()))
+                           if isinstance(leaf, jax.Array)
+                           else jax.devices()[0])
+        self.paged_kernel = self.device.platform == "tpu"
         if mesh is not None:
             # head-permute (identity at tp=1) + place under the serving
             # rules; the pspec tree doubles as the shard_map in_specs
@@ -218,7 +233,8 @@ class PagedInferenceEngine:
         # reserving chunk-width blocks would over-reserve (issue #4 sat. 1)
         self.first_chunk_cap = (min(self.prefill_chunk, token_budget)
                                 if token_budget else self.prefill_chunk)
-        self.cache = PagedKVCache(cfg, num_blocks, block_size, mesh=mesh)
+        self.cache = PagedKVCache(cfg, num_blocks, block_size, mesh=mesh,
+                                  device=self.device)
         self.swap = SwapManager(self.cache, swap_store,
                                 on_evict=self._on_evicted)
         self.max_pages = self.cache.pages_for(self.max_len)
@@ -256,6 +272,7 @@ class PagedInferenceEngine:
         # their gap is the padding the budget packer exists to shrink.
         self.trace_buckets: set = set()
         self.compiled_buckets: set = set()   # pre-traced by compile_buckets
+        self.compile_seconds: Dict[int, float] = {}
         self._c_tokens_real = m.counter(f"{name}.tokens_real")
         self._c_tokens_disp = m.counter(f"{name}.tokens_dispatched")
         # wall-clock latency distributions (seconds): time-to-first-token
@@ -321,13 +338,16 @@ class PagedInferenceEngine:
         self._ev_finish = rec.name("session.finished",
                                    ("rid", "out_tokens"))
 
+        kern = self.paged_kernel
         self._decode = jax.jit(
             lambda params, pools, tok, lens, tables:
-            tr.decode_step_paged(params, pools, tok, lens, tables, cfg),
+            tr.decode_step_paged(params, pools, tok, lens, tables, cfg,
+                                 paged_kernel=kern),
             donate_argnums=(1,))
         self._chunk = jax.jit(
             lambda params, pools, toks, n, t, table:
-            tr.prefill_chunk_paged(params, pools, toks, n, t, table, cfg),
+            tr.prefill_chunk_paged(params, pools, toks, n, t, table, cfg,
+                                   paged_kernel=kern),
             donate_argnums=(1,))
         self._mega = self._build_mega()
 
@@ -340,29 +360,29 @@ class PagedInferenceEngine:
         argmax is computed identically on every shard so the out spec is
         replicated. Still exactly one jitted dispatch per engine iteration
         and one (max_batch,) int32 host transfer."""
-        cfg = self.cfg
+        cfg, kern = self.cfg, self.paged_kernel
         if self.mesh is None:
             return jax.jit(
                 lambda params, pools, toks, lens, valids, tables, poison:
                 tr.mixed_step_paged(params, pools, toks, lens, valids,
-                                    tables, cfg, poison),
+                                    tables, cfg, poison, paged_kernel=kern),
                 donate_argnums=(1,))
-        from jax.experimental.shard_map import shard_map
         # pin head_dim: configs that leave it 0 derive d_model // n_heads,
         # which would silently double when the local head count halves
         lcfg = cfg.replace(n_heads=cfg.n_heads // self.tp,
                            n_kv_heads=cfg.n_kv_heads // self.tp,
                            head_dim=cfg.resolved_head_dim)
         pool_specs = {"k": kv_pool_pspec(), "v": kv_pool_pspec()}
-        body = shard_map(
+        body = jax.shard_map(
             lambda params, pools, toks, lens, valids, tables, poison:
             tr.mixed_step_paged(params, pools, toks, lens, valids, tables,
-                                lcfg, poison, axis_name=TP),
+                                lcfg, poison, paged_kernel=kern,
+                                axis_name=TP),
             mesh=self.mesh,
             in_specs=(self._param_specs, pool_specs,
                       *megastep_input_pspecs()),
             out_specs=(megastep_output_pspec(), pool_specs),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(body, donate_argnums=(1,))
 
     # ----------------------------------------------------------- public
@@ -375,10 +395,15 @@ class PagedInferenceEngine:
         live state is untouched. Idempotent; recorded in
         ``compiled_buckets``, NOT in ``trace_buckets`` — the latter counts
         only widths live traffic actually dispatched, so the benchmark's
-        buckets-used column and the recompile guard stay meaningful."""
+        buckets-used column and the recompile guard stay meaningful.
+        ``compile_seconds`` records each bucket's set-up wall time: trace,
+        compile (or a persistent-cache load) and one dummy dispatch."""
         if not self.use_megastep:
             return
         for C in self.bucket_set:
+            if C in self.compiled_buckets:
+                continue
+            t0 = time.perf_counter()
             zeros = jnp.zeros
             _, pools = self._mega(
                 self.params, self.cache.pools(),
@@ -388,8 +413,9 @@ class PagedInferenceEngine:
                 jnp.full((self.max_batch, self.max_pages), NULL_BLOCK,
                          jnp.int32),
                 zeros((self.max_batch,), jnp.bool_))
-            self.cache.set_pools(pools)
+            self.cache.set_pools(jax.block_until_ready(pools))
             self.compiled_buckets.add(C)
+            self.compile_seconds[C] = time.perf_counter() - t0
 
     def set_token_budget(self, budget: int) -> int:
         """Retune the per-step token budget LIVE, without retracing.

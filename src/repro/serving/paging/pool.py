@@ -1,6 +1,6 @@
 """PagedKVCache: the pooled physical KV store + page-table bookkeeping.
 
-One ``(L, num_blocks, blk, hkv, hd)`` array per tensor (K and V) backs every
+One ``(L, num_blocks, hkv, blk, hd)`` array per tensor (K and V) backs every
 sequence; block ids are shared across layers, so a single page table per
 sequence maps its token positions for the whole stack. This is the layer
 that owns the bytes: the BlockAllocator decides *which* block, this class
@@ -46,8 +46,8 @@ from repro.serving.paging.allocator import NULL_BLOCK, BlockAllocator, PageTable
 def _pool_put(k, v, bids, k_pages, v_pages):
     """Scatter page-shaped updates into donated pools.
 
-    k/v: (L, nb, blk, hkv, hd); bids: (P,) int32 (NULL_BLOCK-padded);
-    k_pages/v_pages: (L, P, blk, hkv, hd) with zeros in padding rows — the
+    k/v: (L, nb, hkv, blk, hd); bids: (P,) int32 (NULL_BLOCK-padded);
+    k_pages/v_pages: (L, P, hkv, blk, hd) with zeros in padding rows — the
     padding writes land in the reserved null block, which exists exactly to
     absorb masked writes."""
     return k.at[:, bids].set(k_pages), v.at[:, bids].set(v_pages)
@@ -64,26 +64,29 @@ class PagedKVCache:
     """Pooled paged KV storage for the decoder-only GQA family."""
 
     def __init__(self, cfg: ModelConfig, num_blocks: int, block_size: int,
-                 mesh=None):
+                 mesh=None, device=None):
         self.cfg = cfg
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.mesh = mesh
-        pools = tr.init_paged_pools(cfg, num_blocks, block_size)
-        self.k: jax.Array = pools["k"]
-        self.v: jax.Array = pools["v"]
+        # the pools are built where they live: head-sharded over the mesh
+        # (page-shaped updates in _put_pages are placed the same way, so
+        # the donated scatter never needs a cross-device reshard), or
+        # committed to the engine's device, so later pool writes run there
+        # and not on the default device
         if mesh is not None:
-            # head-sharded placement; page-shaped updates in _put_pages
-            # are placed the same way so the donated scatter never needs
-            # a cross-device reshard
             sh = jax.sharding.NamedSharding(mesh, kv_pool_pspec())
             self._page_sharding = sh
-            self.k = jax.device_put(self.k, sh)
-            self.v = jax.device_put(self.v, sh)
         else:
+            sh = (jax.sharding.SingleDeviceSharding(device)
+                  if device is not None else None)
             self._page_sharding = None
+        pools = jax.jit(partial(tr.init_paged_pools, cfg, num_blocks,
+                                block_size), out_shardings=sh)()
+        self.k: jax.Array = pools["k"]
+        self.v: jax.Array = pools["v"]
         self.allocator = BlockAllocator(num_blocks)
-        L, _, blk, hkv, hd = self.k.shape
+        L, _, hkv, blk, hd = self.k.shape
         self.block_bytes = 2 * L * blk * hkv * hd * self.k.dtype.itemsize
         # ---- prompt-prefix dedup index -----------------------------------
         # key = the raw bytes of a block-aligned prompt prefix; value = the
@@ -246,7 +249,7 @@ class PagedKVCache:
         k_pages = jnp.asarray(k_pages, self.k.dtype)
         v_pages = jnp.asarray(v_pages, self.v.dtype)
         if self._page_sharding is not None:
-            # pages share the pool's (..., hkv, hd) trailing layout, so
+            # pages share the pool's (..., hkv, blk, hd) trailing layout, so
             # the same head-sharded spec applies; committing them here
             # keeps the donated scatter a pure per-shard write
             k_pages = jax.device_put(k_pages, self._page_sharding)
@@ -257,7 +260,7 @@ class PagedKVCache:
     def write_prefill(self, pt: PageTable, k_pre, v_pre):
         """Scatter prefill KV (L, plen, hkv, hd) into the sequence's blocks
         in one batched, jitted update (the last partial page is zero-padded,
-        the pool buffers are donated)."""
+        pages are laid out head-major, the pool buffers are donated)."""
         L, plen = k_pre.shape[0], k_pre.shape[1]
         self.ensure_capacity(pt, plen)
         pages = self.pages_for(plen)
@@ -268,14 +271,15 @@ class PagedKVCache:
             pre = jnp.asarray(pre)
             if pad:
                 pre = jnp.pad(pre, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            return pre.reshape(L, pages, self.block_size, *pre.shape[2:])
+            return pre.reshape(L, pages, self.block_size,
+                               *pre.shape[2:]).transpose(0, 1, 3, 2, 4)
 
         self._put_pages(bids, paged(k_pre), paged(v_pre))
         pt.num_tokens = plen
 
     # ------------------------------------------------- swap (host pages)
     def gather(self, pt: PageTable) -> Tuple[np.ndarray, np.ndarray]:
-        """Copy a sequence's live pages to host memory (L, pages, blk, hkv,
+        """Copy a sequence's live pages to host memory (L, pages, hkv, blk,
         hd) — O(dirty pages), not O(max_len)."""
         bids = np.asarray(pt.blocks, np.int32)
         return np.asarray(self.k[:, bids]), np.asarray(self.v[:, bids])

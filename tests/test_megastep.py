@@ -27,8 +27,8 @@ def _mixed_case(b, C, hq, hkv, d, dv, blk, npages, seed):
     rng = np.random.default_rng(seed)
     nb = b * npages + 1
     q = jnp.asarray(rng.standard_normal((b, C, hq, d)), jnp.float32)
-    k_pool = jnp.asarray(rng.standard_normal((nb, blk, hkv, d)), jnp.float32)
-    v_pool = jnp.asarray(rng.standard_normal((nb, blk, hkv, dv)), jnp.float32)
+    k_pool = jnp.asarray(rng.standard_normal((nb, hkv, blk, d)), jnp.float32)
+    v_pool = jnp.asarray(rng.standard_normal((nb, hkv, blk, dv)), jnp.float32)
     # shuffled, non-contiguous physical placement (never the null block)
     ids = rng.permutation(np.arange(1, nb))[: b * npages].reshape(b, npages)
     pt = jnp.asarray(ids, jnp.int32)
@@ -75,9 +75,9 @@ def test_budget_width_kernel_parity_ragged_valids(C):
     rng = np.random.default_rng(C)
     nb = b * npages + 1
     q = jnp.asarray(rng.standard_normal((b, C, hq, d)), jnp.float32)
-    k_pool = jnp.asarray(rng.standard_normal((nb, BLOCK_SIZE, hkv, d)),
+    k_pool = jnp.asarray(rng.standard_normal((nb, hkv, BLOCK_SIZE, d)),
                          jnp.float32)
-    v_pool = jnp.asarray(rng.standard_normal((nb, BLOCK_SIZE, hkv, dv)),
+    v_pool = jnp.asarray(rng.standard_normal((nb, hkv, BLOCK_SIZE, dv)),
                          jnp.float32)
     ids = rng.permutation(np.arange(1, nb))[: b * npages].reshape(b, npages)
     pt = jnp.asarray(ids, jnp.int32)
@@ -117,6 +117,61 @@ def test_chunked_prefill_width1_equals_decode_semantics():
     ref = paged_attention_ref(q[:, 0], k_pool, v_pool, cache + 1, pt)
     np.testing.assert_allclose(np.asarray(out[:, 0]), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
+
+
+def test_kernel_pairs_heads_g_major():
+    """The kernel pairs q head ``h`` with kv head ``h % hkv`` — the model's
+    ``gqa_mode="tiled"`` pairing, which the full path, the jnp reference
+    and the TP head permutation all use. At hkv 2, g 2 the other pairing
+    (``h // g``) is a different model, and the kernel must not match it."""
+    case = _mixed_case(3, PREFILL_CHUNK, 4, 2, 32, 32, BLOCK_SIZE, 4,
+                       seed=21)
+    out = np.asarray(paged_prefill_attention_bcd(*case, interpret=True))
+    g_major = np.asarray(paged_prefill_attention_ref(*case,
+                                                     pairing="g_major"))
+    kv_major = np.asarray(paged_prefill_attention_ref(*case,
+                                                      pairing="kv_major"))
+    live = np.asarray(case[4])[:, None] > np.arange(PREFILL_CHUNK)[None, :]
+    np.testing.assert_allclose(out[live], g_major[live], atol=1e-5,
+                               rtol=1e-5)
+    assert np.abs(out[live] - kv_major[live]).max() > 1e-2
+
+
+def test_mixed_step_kernel_matches_reference_path_at_f32():
+    """One megastep layer through the Pallas kernel (interpret mode) agrees
+    with the jnp reference path the engine runs on a CPU: same attention
+    output rows and bit-identical pool writes, at f32 on a GQA model with
+    hkv 2, g 2 and ragged rows (decode, partial chunk, inactive)."""
+    from repro.models.attention import gqa_mixed_step_paged, init_gqa
+    cfg = get_smoke_config("chatglm3-6b").replace(compute_dtype="float32")
+    hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    assert cfg.n_heads // hkv == 2 and hkv == 2
+    rng = np.random.default_rng(4)
+    params = init_gqa(jax.random.PRNGKey(4), cfg)
+    B, C, npages = 3, PREFILL_CHUNK, 4
+    nb = B * npages + 1
+    x = jnp.asarray(rng.standard_normal((B, C, cfg.d_model)), jnp.float32)
+    k_pool = jnp.asarray(rng.standard_normal((nb, hkv, BLOCK_SIZE, hd)),
+                         jnp.float32)
+    v_pool = jnp.asarray(rng.standard_normal((nb, hkv, BLOCK_SIZE, hd)),
+                         jnp.float32)
+    ids = rng.permutation(np.arange(1, nb))[: B * npages].reshape(B, npages)
+    tables = jnp.asarray(ids, jnp.int32)
+    cache_lens = jnp.asarray([9, 13, 0], jnp.int32)
+    valids = jnp.asarray([C, 1, 0], jnp.int32)
+
+    def run(kernel):
+        return gqa_mixed_step_paged(params, x, k_pool, v_pool, tables,
+                                    cache_lens, valids, cfg,
+                                    paged_kernel=kernel, interpret=kernel)
+
+    out_k, (kk, vk) = run(True)
+    out_r, (kr, vr) = run(False)
+    live = np.asarray(valids)[:, None] > np.arange(C)[None, :]
+    np.testing.assert_allclose(np.asarray(out_k)[live],
+                               np.asarray(out_r)[live], atol=1e-4, rtol=1e-4)
+    np.testing.assert_array_equal(np.asarray(kk), np.asarray(kr))
+    np.testing.assert_array_equal(np.asarray(vk), np.asarray(vr))
 
 
 # ------------------------------------------------------------ engine level

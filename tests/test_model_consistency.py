@@ -102,7 +102,8 @@ def test_elastic_remesh_checkpoint(tmp_path):
     params = model.init_params(jax.random.PRNGKey(0))
     ck = Checkpointer(str(tmp_path))
     ck.save(1, params)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))   # "different" mesh
+    mesh = jax.make_mesh((1, 1), ("data", "model"),    # "different" mesh
+                         axis_types=(jsh.AxisType.Auto,) * 2)
     placed, step, _ = elastic_restore(cfg, ck, params, mesh)
     assert step == 1
     for a, b in zip(jax.tree_util.tree_leaves(params),

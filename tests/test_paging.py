@@ -9,7 +9,7 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.kernels.decode_attention.ref import decode_attention_ref
-from repro.kernels.paged_attention.kernel import paged_attention_bhd
+from repro.kernels.paged_attention.kernel import paged_prefill_attention_bcd
 from repro.kernels.paged_attention.ref import (gather_pages,
                                                paged_attention_ref)
 from repro.models import build
@@ -72,27 +72,35 @@ def _tol(dtype):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_matches_dense_and_ref(b, hq, hkv, d, dv, blk,
                                                npages, lens, dtype):
-    """The Pallas paged kernel (interpret mode) == the paged jnp oracle ==
-    the dense decode oracle run on each sequence's gathered pages."""
+    """The Pallas paged kernel (interpret mode) at decode width (C == 1) ==
+    the paged jnp decode oracle == the dense decode oracle run on each
+    sequence's gathered pages. The kernel pairs q head ``h`` with kv head
+    ``h % hkv``; the dense oracle pairs kv-major, so its query heads are
+    regrouped to the same pairing first."""
     nb = b * npages + 1
     q = jnp.asarray(RNG.standard_normal((b, hq, d)), dtype)
-    k_pool = jnp.asarray(RNG.standard_normal((nb, blk, hkv, d)), dtype)
-    v_pool = jnp.asarray(RNG.standard_normal((nb, blk, hkv, dv)), dtype)
+    k_pool = jnp.asarray(RNG.standard_normal((nb, hkv, blk, d)), dtype)
+    v_pool = jnp.asarray(RNG.standard_normal((nb, hkv, blk, dv)), dtype)
     # shuffled, non-contiguous physical placement (never the null block)
     ids = RNG.permutation(np.arange(1, nb))[: b * npages].reshape(b, npages)
     pt = jnp.asarray(ids, jnp.int32)
     lens_v = jnp.asarray(lens, jnp.int32)
 
-    out = paged_attention_bhd(q, k_pool, v_pool, lens_v, pt, interpret=True)
+    out = paged_prefill_attention_bcd(q[:, None], k_pool, v_pool, lens_v - 1,
+                                      jnp.ones_like(lens_v), pt,
+                                      interpret=True)[:, 0]
     ref = paged_attention_ref(q, k_pool, v_pool, lens_v, pt)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=_tol(dtype), rtol=_tol(dtype))
+    g = hq // hkv
+    kv_major = q.reshape(b, g, hkv, d).swapaxes(1, 2).reshape(b, hq, d)
     kg = gather_pages(k_pool, pt).transpose(0, 2, 1, 3)
     vg = gather_pages(v_pool, pt).transpose(0, 2, 1, 3)
     for i in range(b):
-        dense = decode_attention_ref(q[i:i + 1], kg[i:i + 1], vg[i:i + 1],
-                                     int(lens[i]))
+        dense = decode_attention_ref(kv_major[i:i + 1], kg[i:i + 1],
+                                     vg[i:i + 1], int(lens[i]))
+        dense = dense.reshape(1, hkv, g, dv).swapaxes(1, 2).reshape(1, hq, dv)
         np.testing.assert_allclose(np.asarray(out[i:i + 1], np.float32),
                                    np.asarray(dense, np.float32),
                                    atol=1e-2, rtol=1e-2)
@@ -123,7 +131,7 @@ def test_pool_write_prefill_and_scatter_roundtrip(setup):
     from repro.serving.paging.pool import PagedKVCache
     cfg, params = setup
     cache = PagedKVCache(cfg, num_blocks=9, block_size=8)
-    L, _, blk, hkv, hd = cache.k.shape
+    L, _, hkv, blk, hd = cache.k.shape
     plen = 13                                    # 2 pages, partial last
     rng = np.random.default_rng(3)
     k_pre = rng.standard_normal((L, plen, hkv, hd)).astype(np.float32)
@@ -131,11 +139,11 @@ def test_pool_write_prefill_and_scatter_roundtrip(setup):
     pt = cache.alloc_table(plen)
     cache.write_prefill(pt, k_pre, v_pre)
     assert pt.num_tokens == plen
-    kg, vg = cache.gather(pt)
-    flat_k = kg.reshape(L, -1, hkv, hd)[:, :plen]
+    kg, vg = cache.gather(pt)                    # (L, pages, hkv, blk, hd)
+    flat = kg.transpose(0, 1, 3, 2, 4).reshape(L, -1, hkv, hd)
     want = np.asarray(jnp.asarray(k_pre, cache.k.dtype))  # pool precision
-    np.testing.assert_array_equal(flat_k, want)
-    assert (kg.reshape(L, -1, hkv, hd)[:, plen:] == 0).all()  # padded tail
+    np.testing.assert_array_equal(flat[:, :plen], want)
+    assert (flat[:, plen:] == 0).all()                      # padded tail
     # swap-style roundtrip: host pages -> fresh device blocks, same bytes
     pt2 = cache.scatter(kg, vg, plen)
     assert pt2.blocks != pt.blocks or len(pt2.blocks) == 0

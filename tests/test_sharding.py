@@ -85,8 +85,8 @@ def _tp_cfg(**kw):
 
 
 def test_megastep_pspecs_shapes():
-    # pool: (L, num_blocks, blk, hkv, hd) — ONLY the head axis is sharded
-    assert kv_pool_pspec() == P(None, None, None, "tp", None)
+    # pool: (L, num_blocks, hkv, blk, hd) — ONLY the head axis is sharded
+    assert kv_pool_pspec() == P(None, None, "tp", None, None)
     # row inputs and the sampled-token output are fully replicated: the
     # per-layer psum restores full activations on every shard, so only one
     # (max_batch,) int32 crosses to host — same bytes as single-device
