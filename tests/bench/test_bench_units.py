@@ -1,0 +1,298 @@
+"""The benchmark's yardstick on the CPU: the traffic generator, the
+end-to-end arithmetic, the work counts, the trace reduction, the peak
+table and how files are found by name. Nothing here needs a chip."""
+import hashlib
+import json
+import math
+import os
+import sys
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path[:0] = [REPO, os.path.join(REPO, "src")]
+
+from bench import flops, harness, model, stats, trace, traffic  # noqa: E402
+
+DATA = os.path.join(REPO, "tests", "bench", "data")
+MIXES = ["agent-tools", "short-chat"]
+
+
+# ----------------------------------------------------------- traffic
+@pytest.mark.parametrize("mix", MIXES)
+def test_traffic_plan_is_seeded_and_within_its_parameters(mix):
+    m = model.load("traffic", mix)
+    a, b = traffic.plan_slots(m), traffic.plan_slots(m)
+    assert a == b and len(a) == m["sessions"]
+    limit, sys_tok = m["reset_after_tokens"], m["system_prompt_tokens"]
+    for slot in a:
+        h = m.get("history_tokens")
+        if h:
+            assert h["lo"] <= slot.history <= h["hi"]
+        for t in slot.turns:
+            out = m["output_tokens"]
+            assert out["lo"] <= t.n_out <= out["hi"]
+            inp = m["input_tokens"]
+            assert 1 <= t.n_in <= inp["cap"]
+            assert t.n_in >= min(inp["min"], limit - sys_tok - t.n_out - 1)
+            assert sys_tok + t.n_in + t.n_out < limit
+            assert t.think_s > 0
+
+
+@pytest.mark.parametrize("mix", MIXES)
+def test_every_seed_gets_the_same_work_in_another_order(mix):
+    m = model.load("traffic", mix)
+    slots = traffic.plan_slots(m)
+    key = sorted((s.history, tuple((t.n_in, t.n_out, t.think_s)
+                                   for t in s.turns)) for s in slots)
+    for seed in (1, 2**31 + 7):
+        p = traffic.permuted(slots, seed)
+        assert sorted((s.history, tuple((t.n_in, t.n_out, t.think_s)
+                                        for t in s.turns))
+                      for s in p) == key
+        assert p == traffic.permuted(slots, seed)
+
+
+def test_every_session_gets_identical_system_prompt_bytes():
+    cell = harness.Cell("x", {}, model.load("traffic", "agent-tools"), 1,
+                        [], [], (harness.BENCH,))
+    sent = []
+
+    class FakeRM:
+        def submit(self, agent, prompt):
+            sent.append(prompt)
+            return None
+
+    class FakeWrap:
+        pending = {}
+        rec = harness.Recorder(None)
+
+    c = harness.Clients(FakeRM(), FakeWrap(), cell, seed=5, vocab=65024)
+    for i in range(len(c.slots)):
+        c._send(i, c.slots[i].history, 1, 0.0, fresh=True)
+    n = cell.mix["system_prompt_tokens"]
+    assert len({p[:n] for p in sent}) == 1
+    assert all(len(p) == n + s.history for p, s in zip(sent, c.slots))
+    c2 = harness.Clients(FakeRM(), FakeWrap(), cell, seed=5, vocab=65024)
+    assert c2.system == c.system
+    assert harness.Clients(FakeRM(), FakeWrap(), cell, seed=6,
+                           vocab=65024).system != c.system
+
+
+def test_byte_tokens_are_one_per_byte():
+    ids = harness.byte_ids("ab~", 65024)
+    assert list(ids) == [ord("a") + 1, ord("b") + 1, ord("~") + 1]
+
+
+# ---------------------------------------------------- end-to-end math
+def _turn(due, toks, n_out, submit=None, begin=None, failed=None):
+    return {"due": due, "tok_t": toks, "n_out": n_out, "submit": submit,
+            "begin": begin, "failed": failed}
+
+
+def test_end_to_end_arithmetic_with_censoring_and_edges():
+    w0, w1 = 10.0, 20.0
+    turns = [
+        _turn(9.0, [9.5, 10.5, 11.0], 3),          # due before the window
+        _turn(12.0, [13.0, 14.0, 18.0], 5),        # decoding at the end
+        _turn(19.0, [], 4),                        # no token yet: censored
+        _turn(15.0, [21.0], 4),                    # first token after w1
+        _turn(20.0, [20.5], 1),                    # due at w1: not counted
+    ]
+    e = stats.end_to_end(turns, w0, w1)
+    assert e["out_tok_s"] == pytest.approx(5 / 10)
+    assert sorted(stats.ttft_samples(turns, w0, w1)) == \
+        pytest.approx([1.0, 1.0, 5.0])
+    assert e["ttft_p50_s"] == pytest.approx(1.0)
+    assert e["ttft_p95_s"] == pytest.approx(1.0 + 0.9 * 4.0)
+    assert sorted(stats.itl_samples(turns, w0, w1)) == \
+        pytest.approx([0.5, 0.5, 1.0, 2.0, 4.0])
+    assert e["itl_p95_s"] == pytest.approx(2.0 + 0.8 * 2.0)
+    assert e["samples"]["first_tokens"] == 3
+
+
+def test_queue_wait_counts_unbegun_turns_until_the_window_ends():
+    turns = [_turn(0, [], 1, submit=11.0, begin=12.5),
+             _turn(0, [], 1, submit=18.0, begin=None),
+             _turn(0, [], 1, submit=5.0, begin=15.0)]
+    assert stats.queue_wait_samples(turns, 10.0, 20.0) == \
+        pytest.approx([1.5, 2.0])
+
+
+def test_a_failed_turn_leaves_no_open_gap():
+    t = _turn(11.0, [12.0], 5, failed="EngineError")
+    assert stats.itl_samples([t], 10.0, 20.0) == []
+
+
+# --------------------------------------------------------- work counts
+D = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
+     "head_dim": 16, "d_ff": 128, "vocab_size": 256, "mlp": "swiglu"}
+
+
+def test_attention_counts_match_a_hand_computed_case():
+    # prefill row: 3 new tokens after 5 cached; causal pairs = 3*8 - 3 = 21
+    assert flops.attn_flops_row(D, 5, 3) == 4 * 4 * 16 * 21
+    # K and V of 8 positions for 2 kv heads, bf16, plus q and out of 3 rows
+    assert flops.attn_bytes_row(D, 5, 3) == 2 * 2 * 16 * 8 * 2 + \
+        2 * 3 * 4 * 16 * 2
+    f, b = flops.paged_attn(D, [(5, 3), (9, 1)])
+    assert f == 2 * (4 * 4 * 16 * 21 + 4 * 4 * 16 * 10)
+    assert b == 2 * (flops.attn_bytes_row(D, 5, 3)
+                     + flops.attn_bytes_row(D, 9, 1))
+
+
+def test_counts_are_brute_force_pairs():
+    for cache_len, q in [(0, 1), (0, 7), (13, 1), (100, 37)]:
+        pairs = sum(1 for i in range(q) for j in range(cache_len + q)
+                    if j <= cache_len + i)
+        assert flops.attn_flops_row(D, cache_len, q) == \
+            4 * D["n_heads"] * D["head_dim"] * pairs
+
+
+@pytest.mark.parametrize("serving", [
+    {"max_len": 8192, "block_size": 16, "max_batch": 8, "token_budget": 1024},
+    {"max_len": 2048, "block_size": 64, "max_batch": 2, "token_budget": 64}])
+def test_counts_do_not_depend_on_the_kernel_grid(serving):
+    """Pages walked and dispatch widths are not inputs: the same live rows
+    give the same work whatever the pool's page size or the batch width."""
+    rows = [(1500, 1), (0, 200), (37, 1)]
+    d = dict(D, **serving)
+    assert flops.paged_attn(d, rows) == flops.paged_attn(D, rows)
+    assert flops.model_flops(d, rows) == flops.model_flops(D, rows)
+
+
+def test_model_flops_count_the_head_once_per_row():
+    per_tok = 2 * flops.matmul_params(D)
+    head = 2 * 64 * 256
+    assert flops.model_flops(D, [(0, 4)]) == \
+        4 * per_tok + head + 2 * flops.attn_flops_row(D, 0, 4)
+    # chatglm3-6b: 5.6e9 non-embedding matmul weights
+    d = model.dims(model.load("configs", "chatglm3-6b"))
+    assert 5.5e9 < flops.matmul_params(d) < 5.8e9
+
+
+def test_least_time_names_its_bound():
+    peaks = {"bf16_flops": 100.0, "hbm_bytes_per_s": 10.0}
+    assert flops.least_time(1000, 50, peaks) == (10.0, "flops")
+    assert flops.least_time(100, 50, peaks) == (5.0, "bytes")
+
+
+# ------------------------------------------------------ trace reduction
+def _trace():
+    with open(os.path.join(DATA, "trace_small.json")) as f:
+        return json.load(f)
+
+
+def _brute_busy(events, lo, hi, step=1000.0):
+    n = int((hi - lo) // step)
+    busy = 0
+    for i in range(n):
+        t = lo + (i + 0.5) * step
+        busy += any(e[1] <= t < e[1] + e[2] for e in events["device"])
+    return busy * step
+
+
+def test_trace_reduction_on_a_recorded_trace():
+    ev = _trace()
+    lo, hi = trace.window(ev)
+    busy = trace.busy_ns(ev, lo, hi)
+    assert busy == pytest.approx(_brute_busy(ev, lo, hi), rel=2e-3)
+    assert 0 < busy <= hi - lo
+    gaps = trace.idle_gaps(ev, lo, hi, n=10**6)
+    assert sum(g for _, g in gaps) * 1e9 == pytest.approx(hi - lo - busy,
+                                                          rel=1e-9)
+    top = trace.top_ops(ev, lo, hi, n=10**6)
+    # innermost ops do not overlap and fill nearly all of the busy time
+    assert 0.99 * busy <= sum(s for _, s in top) * 1e9 <= busy
+    assert [s for _, s in top] == sorted((s for _, s in top), reverse=True)
+    from bench.kernels import is_paged_attn
+    k = trace.op_ns(ev, lo, hi, is_paged_attn)
+    assert 0 < k <= busy
+    # recorded values: a decode step of agent-tools, nearly all kernel
+    assert busy == pytest.approx(1.002070672e9, rel=1e-9)
+    assert k == pytest.approx(0.963288918e9, rel=1e-9)
+
+
+def test_idle_gaps_are_named_by_the_open_annotation():
+    ev = {"device": [["a", 0, 10, ""], ["b", 35, 10, ""], ["c", 60, 40, ""]],
+          "host": [[trace.WINDOW, 0, 100], ["bench.step", 0, 45],
+                   ["bench.collect", 41, 3]]}
+    assert trace.busy_ns(ev, 0, 100) == 60
+    gaps = trace.idle_gaps(ev, 0, 100)
+    assert [g[0] for g in gaps] == ["bench.step", "host"]
+    assert [g[1] for g in gaps] == pytest.approx([25e-9, 15e-9])
+    assert trace.top_ops(ev, 0, 100)[0] == ["c", pytest.approx(40e-9)]
+
+
+# ------------------------------------------------------------ peaks
+def test_the_peak_table_refuses_an_unknown_device():
+    assert harness.peaks_for("TPU v5 lite")["bf16_flops"] == 197e12
+    with pytest.raises(harness.HarnessError):
+        harness.peaks_for("TPU v99 imaginary")
+
+
+# ------------------------------------------------------- found by name
+def _tree_digest(root):
+    h = hashlib.sha256()
+    for dirpath, dirs, files in sorted(os.walk(root)):
+        dirs.sort()
+        for f in sorted(files):
+            if f.endswith(".pyc"):
+                continue
+            p = os.path.join(dirpath, f)
+            h.update(p.encode())
+            with open(p, "rb") as fh:
+                h.update(fh.read())
+    return h.hexdigest()
+
+
+def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
+    before = _tree_digest(harness.BENCH)
+    for kind in ("configs", "traffic", "metrics"):
+        (tmp_path / kind).mkdir()
+    cfg = model.load("configs", "tiny", roots=(DATA,))
+    (tmp_path / "configs" / "new-model.json").write_text(json.dumps(cfg))
+    mix = dict(model.load("traffic", "short-chat"), sessions=3)
+    (tmp_path / "traffic" / "new-mix.json").write_text(json.dumps(mix))
+    (tmp_path / "metrics" / "new_metric.x.py").write_text(
+        "def read(ctx):\n    return 42.0\n")
+    spec = {"workloads": [{"name": "new-model.new-mix", "config": "new-model",
+                           "traffic": "new-mix", "chips": 1, "why": "t"}],
+            "end_to_end": [{"name": "out_tok_s", "unit": "tokens/s"}],
+            "per_layer": [{"name": "new_metric.x", "unit": "s",
+                           "moves": "out_tok_s"},
+                          {"name": "step_mfu", "unit": "%",
+                           "moves": "out_tok_s", "workloads": ["other"]}]}
+    roots = (str(tmp_path), harness.BENCH)
+    cell = harness.load_cell("new-model.new-mix", spec, roots=roots)
+    assert cell.config == cfg and cell.mix["sessions"] == 3
+    assert [m["name"] for m in cell.per_layer] == ["new_metric.x"]
+    assert harness.metric_reader("new_metric.x", roots)(None) == 42.0
+    assert harness.metric_reader("step_mfu", roots).__module__ \
+        .endswith("step_mfu")
+    assert _tree_digest(harness.BENCH) == before
+
+
+def test_every_named_file_of_the_benchmark_exists():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    for w in spec["workloads"]:
+        cell = harness.load_cell(w["name"], spec)
+        assert cell.per_layer and cell.end_to_end
+        for m in cell.per_layer:
+            assert callable(harness.metric_reader(m["name"], cell.roots))
+    for c in spec["configs"]:
+        assert os.path.isfile(os.path.join(REPO, c["file"]))
+
+
+@pytest.mark.parametrize("name", ["chatglm3-6b"])
+def test_configs_keep_published_widths(name):
+    d = model.dims(model.load("configs", name))
+    assert d["head_dim"] == 128 and d["n_heads"] % d["n_kv_heads"] == 0
+    shapes = model.param_shapes(d)
+    n = sum(math.prod(s) for s in jax.tree_util.tree_leaves(
+        shapes, is_leaf=lambda x: isinstance(x, tuple)))
+    want = {"chatglm3-6b": 6.24e9}[name]
+    assert n == pytest.approx(want, rel=0.01)
