@@ -273,13 +273,31 @@ class AgentRM:
         self._tr_mlfq = [rec.track(f"Q{lvl}", group="mlfq")
                          for lvl in range(3)]
         self._ev_submitted = rec.name("sched.submitted", ("tid", "level"))
-        self._ev_admitted = rec.name("sched.admitted",
-                                     ("tid", "level", "wait_s"))
+        self._ev_admitted = rec.name("sched.admitted", ("tid", "level"))
         self._ev_preempted = rec.name("sched.preempted",
                                       ("tid", "level", "served_tokens"))
         self._ev_demoted = rec.name("sched.demoted", ("tid", "level"))
         self._ev_boosted = rec.name("sched.boosted", ("tid",))
         self._ev_reaped = rec.name("sched.reaped", ("tid", "retries"))
+        # one MLFQ queue episode per span: submit (or the park that
+        # requeued the turn) -> admission. Episodes of different turns
+        # overlap, so each goes on the first "wait k" track free since it
+        # began: as many tracks as turns have ever waited at once.
+        self._ev_queued = rec.name("sched.queued",
+                                   ("tid", "level", "resumed", "rid"))
+        self._tr_waits: List[int] = []
+        self._wait_ends: List[float] = []
+        # the dispatcher's track: one sched.pass span per pass that steps
+        # the engine, its three sections, and the backend calls made in
+        # them (DESIGN.md §12)
+        self._tr_disp = rec.track("dispatcher", group="sched")
+        self._ev_pass = rec.name("sched.pass")
+        self._ev_sections = tuple(rec.name(f"sched.{p}") for p in
+                                  ("schedule", "step", "charge"))
+        self._ev_begin = rec.name("backend.begin_turn", ("tid", "rid"))
+        self._ev_resume = rec.name("backend.resume_turn", ("tid", "rid"))
+        self._ev_park = rec.name("backend.park_turn", ("tid", "rid"))
+        self._ev_collect = rec.name("backend.collect", ("tid", "rid"))
         # fault/recovery instrumentation (DESIGN.md §14): counters for every
         # recovery mechanism plus trace instants on a dedicated track, so a
         # chaos soak's Perfetto view shows faults next to scheduling
@@ -528,8 +546,12 @@ class AgentRM:
         # dispatcher thread runs, the cycle cannot bite
         from repro.serving import errors as engine_errors
         self._errs = engine_errors
+        trec = self.obs.recorder
+        tracing = trec.enabled
+        clock = time.perf_counter
         while not self._stop.is_set():
             now = time.monotonic()
+            t_pass = clock() if tracing else 0.0
             with self._lock:
                 self.policy.on_tick(now)
                 self._reap_condemned(be)
@@ -545,11 +567,13 @@ class AgentRM:
                 self._wake.wait(timeout=0.02)
                 self._wake.clear()
                 continue
+            t_step = clock() if tracing else 0.0
             try:
                 report = self._checked_step(be)
             except BaseException as e:  # noqa: BLE001 — step failed
                 self._on_step_failure(be, e)
                 continue
+            t_charge = clock() if tracing else 0.0
             self._consec_failures = 0
             self._backoff = self.cfg.step_backoff_s
             now = time.monotonic()
@@ -583,12 +607,22 @@ class AgentRM:
                             tid, error=rec.get("cancel_error")
                             or ZombieKilled(f"turn {tid} reaped"))
                         continue
+                    t = clock() if tracing else 0.0
                     try:
                         out = be.collect(rid)
                     except BaseException as e:  # noqa: BLE001
                         self._finish_fused(tid, error=e)
                         continue
+                    if tracing:
+                        trec.complete(self._ev_collect, self._tr_disp, t,
+                                      tid, rid)
                     self._finish_fused(tid, result=out)
+            if tracing:
+                t_end = clock()
+                bounds = (t_pass, t_step, t_charge, t_end)
+                for ev, a, b in zip(self._ev_sections, bounds, bounds[1:]):
+                    trec.interval(ev, self._tr_disp, a, b)
+                trec.interval(self._ev_pass, self._tr_disp, t_pass, t_end)
 
     def _checked_step(self, be):
         """One ``backend.step()``, optionally under the watchdog deadline.
@@ -703,6 +737,8 @@ class AgentRM:
         rec["served_run"] = 0
         turn.state = TurnState.QUEUED
         turn._enq_at = now
+        if self.obs.tracing:
+            turn._trace_enq = self.obs.recorder.now()
         self.policy.requeue(turn, now)
 
     def _reap_condemned(self, be):
@@ -734,14 +770,19 @@ class AgentRM:
             # nobody waiting, or a free slot could serve the waiter without
             # preempting anyone — parking would only cost page churn
             return
+        tracing = self.obs.tracing
         for tid, rec in list(self._running.items()):
             turn: Turn = rec["turn"]
             if rec["served_run"] < self.policy.quantum_for(turn):
                 continue
+            t = time.perf_counter() if tracing else 0.0
             try:
                 be.park_turn(rec["rid"])
             except BaseException:  # noqa: BLE001 — leave it running
                 continue
+            if tracing:
+                self.obs.recorder.complete(self._ev_park, self._tr_disp, t,
+                                           tid, rec["rid"])
             del self._running[tid]
             served = rec["served_run"]
             rec["served_run"] = 0
@@ -790,6 +831,8 @@ class AgentRM:
         priority inversion stalled admission until the starvation boost —
         a 45-second dead batch under multi-turn traffic."""
         deferred: list = []
+        tracing = self.obs.tracing
+        trec = self.obs.recorder
         while len(self._running) < self.cfg.lanes:
             nxt = self.policy.dequeue(now)
             if nxt is None:
@@ -830,6 +873,7 @@ class AgentRM:
                 self.admission.aimd.on_clean()
             if resuming:
                 rec = self._parked.pop(nxt.tid)
+                t = time.perf_counter() if tracing else 0.0
                 try:
                     be.resume_turn(rec["rid"])
                 except BaseException as e:  # noqa: BLE001
@@ -841,27 +885,32 @@ class AgentRM:
                         pass
                     self.handles[nxt.tid]._finish(error=e)
                     continue
+                if tracing:
+                    trec.complete(self._ev_resume, self._tr_disp, t,
+                                  nxt.tid, rec["rid"])
                 rec["last_beat"] = now
             else:
                 try:
-                    rid = be.begin_turn(nxt.agent_id,
-                                        self._build_context(nxt.agent_id),
-                                        prompt)
+                    context = self._build_context(nxt.agent_id)
+                    t = time.perf_counter() if tracing else 0.0
+                    rid = be.begin_turn(nxt.agent_id, context, prompt)
                 except BaseException as e:  # noqa: BLE001
                     self.handles[nxt.tid]._finish(error=e)
                     continue
+                if tracing:
+                    trec.complete(self._ev_begin, self._tr_disp, t,
+                                  nxt.tid, rid)
                 rec = {"turn": nxt, "rid": rid, "last_beat": now,
                        "served_run": 0, "cancelled": threading.Event()}
             self._running[nxt.tid] = rec
             self.monitor.on_lane(+1)
             self.drf.acquire(nxt.agent_id, 1.0, nxt.tokens)
             nxt.queue_wait += now - getattr(nxt, "_enq_at", now)
-            if self.obs.tracing:
-                trec = self.obs.recorder
+            if tracing:
                 lvl = self.policy.level_of(nxt)
-                wait = trec.now() - getattr(nxt, "_trace_enq", trec.now())
                 trec.instant(self._ev_admitted, self._tr_mlfq[lvl],
-                             nxt.tid, lvl, wait)
+                             nxt.tid, lvl)
+                self._trace_queued(nxt, lvl, resuming, rec["rid"])
             nxt.state = TurnState.RUNNING
             nxt.start = nxt.start or now
             if nxt.first_wait is None:
@@ -870,6 +919,22 @@ class AgentRM:
                                         len(self.policy))
         for t in deferred:
             self._requeue_waiting(t, now)
+
+    def _trace_queued(self, turn: Turn, lvl: int, resumed: bool, rid: int):
+        """Record the queue episode ``turn`` just left as a ``sched.queued``
+        span, on the first wait track whose last episode ended before this
+        one began. Caller holds the lock."""
+        trec = self.obs.recorder
+        t1 = trec.now()
+        t0 = getattr(turn, "_trace_enq", t1)
+        k = next((k for k, end in enumerate(self._wait_ends) if end <= t0),
+                 len(self._wait_ends))
+        if k == len(self._wait_ends):
+            self._tr_waits.append(trec.track(f"wait {k}", group="mlfq"))
+            self._wait_ends.append(t1)
+        self._wait_ends[k] = t1
+        trec.interval(self._ev_queued, self._tr_waits[k], t0, t1, turn.tid,
+                      lvl, 1.0 if resumed else 0.0, rid)
 
     # ------------------------------------------- autopilot mechanisms
     def _peek_queued(self) -> Optional[Turn]:
@@ -1010,10 +1075,15 @@ class AgentRM:
         if victim_tid is None:
             return False
         rec = self._running[victim_tid]
+        tracing = self.obs.tracing
+        t = time.perf_counter() if tracing else 0.0
         try:
             be.park_turn(rec["rid"])
         except BaseException:  # noqa: BLE001 — not parkable right now
             return False
+        if tracing:
+            self.obs.recorder.complete(self._ev_park, self._tr_disp, t,
+                                       victim_tid, rec["rid"])
         del self._running[victim_tid]
         rec["served_run"] = 0
         self._parked[victim_tid] = rec
@@ -1024,7 +1094,8 @@ class AgentRM:
         turn._enq_at = now
         self.policy.requeue(turn, now)
         self._c_degrade.inc()
-        if self.obs.tracing:
+        if tracing:
+            turn._trace_enq = self.obs.recorder.now()
             self.obs.recorder.instant(self._ev_degraded, self._tr_faults,
                                       victim_tid, nxt.tid)
         return True

@@ -100,7 +100,6 @@ class ResourceMonitor:
     def on_context(self, agent_id: str, window_tokens: int, limit: int):
         frac = window_tokens / max(limit, 1)
         self.context_pressure[agent_id] = frac
-        self.metrics.gauge(f"rm.context_pressure.{agent_id}").set(frac)
 
     # --- straggler detection (also used by launch/train.py) ---
     def observe_step(self, seconds: float) -> bool:

@@ -237,7 +237,8 @@ def print_obs_summary(obs: Observability, engine_names=("engine",)):
     real, disp = ce("tokens_real"), ce("tokens_dispatched")
     pad = 1.0 - real / disp if disp else 0.0
     print("[serve] --- metrics (unified registry) ---")
-    print(f"[serve] ttft  p50 {q('ttft_s', .5):.0f}ms  "
+    print(f"[serve] ttft (from engine admission) "
+          f"p50 {q('ttft_s', .5):.0f}ms  "
           f"p95 {q('ttft_s', .95):.0f}ms | "
           f"itl p50 {q('itl_s', .5):.1f}ms  "
           f"p95 {q('itl_s', .95):.1f}ms | "

@@ -276,38 +276,45 @@ def gqa_mixed_step_paged(params, x, k_pool, v_pool, page_tables, cache_lens,
     cache_lens = jnp.asarray(cache_lens)
     valids = jnp.asarray(valids)
     pos = cache_lens[:, None] + jnp.arange(C)[None, :]        # (B, C)
-    q = (x @ params["wq"]).reshape(b, C, hq, hd)
-    k = (x @ params["wk"]).reshape(b, C, hkv, hd)
-    v = (x @ params["wv"]).reshape(b, C, hkv, hd)
-    if cfg.rotary_pct > 0:
-        rot = int(hd * cfg.rotary_pct)
-        cos, sin = rope_tables(pos, rot, cfg.rope_theta)
-        q = apply_rope(q, cos, sin, cfg.rotary_pct)
-        k = apply_rope(k, cos, sin, cfg.rotary_pct)
+    # the named scopes label the device ops of a profiler trace
+    with jax.named_scope("qkv"):
+        q = (x @ params["wq"]).reshape(b, C, hq, hd)
+        k = (x @ params["wk"]).reshape(b, C, hkv, hd)
+        v = (x @ params["wv"]).reshape(b, C, hkv, hd)
+        if cfg.rotary_pct > 0:
+            rot = int(hd * cfg.rotary_pct)
+            cos, sin = rope_tables(pos, rot, cfg.rope_theta)
+            q = apply_rope(q, cos, sin, cfg.rotary_pct)
+            k = apply_rope(k, cos, sin, cfg.rotary_pct)
     # scatter every row's chunk into its own blocks; padding positions (and
     # inactive rows) aim at the reserved null block 0
-    live = jnp.arange(C)[None, :] < valids[:, None]
-    page_idx = jnp.clip(pos // blk, 0, npages - 1)
-    bids = jnp.where(live, jnp.take_along_axis(page_tables, page_idx, axis=1),
-                     0)
-    offs = pos % blk
-    k_pool = k_pool.at[bids, :, offs].set(k.astype(k_pool.dtype))
-    v_pool = v_pool.at[bids, :, offs].set(v.astype(v_pool.dtype))
-    if paged_kernel:
-        o = paged_kernel_attention(q, k_pool, v_pool, cache_lens, valids,
-                                   page_tables, cfg, interpret=interpret)
-    else:
-        from repro.kernels.paged_attention.ref import \
-            paged_prefill_attention_ref
-        pairing = "g_major" if cfg.gqa_mode == "tiled" else "kv_major"
-        o = paged_prefill_attention_ref(q, k_pool, v_pool, cache_lens,
-                                        valids, page_tables, pairing=pairing)
-    out = o.reshape(b, C, hq * hd) @ params["wo"]
-    if axis_name is not None:
-        # each shard contributed its head slice through its wo rows; the
-        # sum over shards completes the (B, C, d) attention output and
-        # re-replicates the residual stream on every shard
-        out = jax.lax.psum(out, axis_name)
+    with jax.named_scope("kv_write"):
+        live = jnp.arange(C)[None, :] < valids[:, None]
+        page_idx = jnp.clip(pos // blk, 0, npages - 1)
+        bids = jnp.where(live,
+                         jnp.take_along_axis(page_tables, page_idx, axis=1),
+                         0)
+        offs = pos % blk
+        k_pool = k_pool.at[bids, :, offs].set(k.astype(k_pool.dtype))
+        v_pool = v_pool.at[bids, :, offs].set(v.astype(v_pool.dtype))
+    with jax.named_scope("paged"):
+        if paged_kernel:
+            o = paged_kernel_attention(q, k_pool, v_pool, cache_lens, valids,
+                                       page_tables, cfg, interpret=interpret)
+        else:
+            from repro.kernels.paged_attention.ref import \
+                paged_prefill_attention_ref
+            pairing = "g_major" if cfg.gqa_mode == "tiled" else "kv_major"
+            o = paged_prefill_attention_ref(q, k_pool, v_pool, cache_lens,
+                                            valids, page_tables,
+                                            pairing=pairing)
+    with jax.named_scope("out"):
+        out = o.reshape(b, C, hq * hd) @ params["wo"]
+        if axis_name is not None:
+            # each shard contributed its head slice through its wo rows;
+            # the sum over shards completes the (B, C, d) attention output
+            # and re-replicates the residual stream on every shard
+            out = jax.lax.psum(out, axis_name)
     return out, (k_pool, v_pool)
 
 

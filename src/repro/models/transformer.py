@@ -381,38 +381,50 @@ def mixed_step_paged(params, pools: Dict, tokens, cache_lens, valids,
     replicated again, so the final unembed + argmax are computed
     identically on every shard with no further collective."""
     params = cast_floats(params, cfg.compute_dtype)
-    x = _embed(params, tokens, cfg)
+    # named scopes (embed, attn/qkv, attn/kv_write, attn/paged, attn/out,
+    # mlp, head) name the device ops in a profiler trace; they change op
+    # metadata only, not the computation
+    with jax.named_scope("embed"):
+        x = _embed(params, tokens, cfg)
 
     def body(h, xs):
         lp, kp, vp = xs
-        hh = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-        a, (kp, vp) = attn_mod.gqa_mixed_step_paged(
-            lp["attn"], hh, kp, vp, page_tables, cache_lens, valids, cfg,
-            paged_kernel=paged_kernel, axis_name=axis_name)
+        with jax.named_scope("attn"):
+            with jax.named_scope("qkv"):
+                hh = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+            a, (kp, vp) = attn_mod.gqa_mixed_step_paged(
+                lp["attn"], hh, kp, vp, page_tables, cache_lens, valids, cfg,
+                paged_kernel=paged_kernel, axis_name=axis_name)
         h = h + a
-        m, _, _ = _mlp_or_moe(lp, h, cfg)
+        with jax.named_scope("mlp"):
+            m, _, _ = _mlp_or_moe(lp, h, cfg)
         return h + m, (kp, vp)
 
     x, (k, v) = jax.lax.scan(body, x, (params["layers"], pools["k"],
                                        pools["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    rows = jnp.arange(x.shape[0])
-    last = jnp.clip(jnp.asarray(valids) - 1, 0, x.shape[1] - 1)
-    logits = _unembed(params, x[rows, last], cfg)    # (B, V) — last valid pos
-    if poison_mask is not None:
-        # Seeded chaos injection point: poison a row's logits AFTER its K/V
-        # writes so the damage is confined to this row's sampled token. With
-        # an all-False mask the where is a bitwise no-op, keeping the
-        # faults-disabled dispatch identical to an uninstrumented one.
-        logits = jnp.where(poison_mask[:, None], jnp.float32(jnp.nan), logits)
-    # In-jit per-row finiteness check (blast-radius = 1 row): a non-finite
-    # logits row — injected or genuine — reports the -1 sentinel instead of
-    # an argmax over garbage, so the host fails exactly that row's turn while
-    # batchmates' tokens stay bitwise identical to a fault-free step. Still
-    # only one (B,) int32 vector crosses to host.
-    row_ok = jnp.all(jnp.isfinite(logits), axis=-1)
-    ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jnp.where(row_ok, ids, jnp.int32(-1)), {"k": k, "v": v}
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        rows = jnp.arange(x.shape[0])
+        last = jnp.clip(jnp.asarray(valids) - 1, 0, x.shape[1] - 1)
+        logits = _unembed(params, x[rows, last], cfg)  # (B, V): last valid
+        if poison_mask is not None:
+            # Seeded chaos injection point: poison a row's logits AFTER its
+            # K/V writes so the damage is confined to this row's sampled
+            # token. With an all-False mask the where is a bitwise no-op,
+            # keeping the faults-disabled dispatch identical to an
+            # uninstrumented one.
+            logits = jnp.where(poison_mask[:, None], jnp.float32(jnp.nan),
+                               logits)
+        # In-jit per-row finiteness check (blast-radius = 1 row): a
+        # non-finite logits row — injected or genuine — reports the -1
+        # sentinel instead of an argmax over garbage, so the host fails
+        # exactly that row's turn while batchmates' tokens stay bitwise
+        # identical to a fault-free step. Still only one (B,) int32 vector
+        # crosses to host.
+        row_ok = jnp.all(jnp.isfinite(logits), axis=-1)
+        ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        ids = jnp.where(row_ok, ids, jnp.int32(-1))
+    return ids, {"k": k, "v": v}
 
 
 def prefill(params, batch, cfg: ModelConfig, state: Optional[Dict] = None,

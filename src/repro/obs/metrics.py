@@ -208,10 +208,10 @@ class Histogram:
 
 
 class MetricsRegistry:
-    """Name -> metric store with get-or-create accessors, a single
-    ``snapshot()`` for benchmarks/JSON dumps, and a Prometheus-style text
-    exposition. ``reset()`` zeroes every metric — benchmarks call it after
-    warmup so every reported column describes the same window."""
+    """Name -> metric store with get-or-create accessors and a single
+    ``snapshot()`` for benchmarks/JSON dumps. ``reset()`` zeroes every
+    metric — benchmarks call it after warmup so every reported column
+    describes the same window."""
 
     def __init__(self):
         self._metrics: Dict[str, object] = {}
@@ -259,21 +259,6 @@ class MetricsRegistry:
         with self._lock:
             return {name: m.snapshot()
                     for name, m in sorted(self._metrics.items())}
-
-    def render_text(self) -> str:
-        """Prometheus-ish exposition: one block per metric, histogram
-        quantiles as pre-baked lines (this is a dump format, not a live
-        scrape endpoint — no _bucket series needed)."""
-        out = []
-        for name, snap in self.snapshot().items():
-            flat = name.replace(".", "_").replace("-", "_")
-            out.append(f"# TYPE {flat} {snap['type']}")
-            if snap["type"] == "histogram":
-                for k in ("count", "sum", "min", "max", "p50", "p95", "p99"):
-                    out.append(f"{flat}_{k} {snap[k]:.9g}")
-            else:
-                out.append(f"{flat} {snap['value']:.9g}")
-        return "\n".join(out) + "\n"
 
     def dump_json(self, path: str):
         with open(path, "w") as f:

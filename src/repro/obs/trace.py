@@ -3,27 +3,36 @@
 A preallocated ring-buffer event log for the fused serving stack:
 
   * **Fixed capacity, allocation-free on the hot path.** Events live in
-    preallocated numpy arrays (timestamp, duration, interned name id,
-    interned track id, up to four float64 args); recording an event is a
-    handful of scalar stores. When the ring is full the oldest events are
+    preallocated parallel lists (timestamp, duration, interned name id,
+    interned track id, up to four float args); recording an event is a
+    handful of slot stores. When the ring is full the oldest events are
     overwritten — flight-recorder semantics — and ``dropped`` counts the
     casualties, so a reader always knows whether the window is complete.
-  * **One clock domain.** Every timestamp is host ``time.perf_counter()``
-    (monotonic, sub-microsecond). There are deliberately NO in-jit
-    timestamps: a device-side clock read would force a host sync (or a new
-    output crossing the jit boundary), breaking the megastep's
-    one-dispatch / int32-only-return contract — see DESIGN.md §12.
+  * **One clock domain, anchored to the profiler's.** Every timestamp is
+    host ``time.perf_counter()`` (monotonic, sub-microsecond). There are
+    deliberately NO in-jit timestamps: a device-side clock read would force
+    a host sync (or a new output crossing the jit boundary), breaking the
+    megastep's one-dispatch / int32-only-return contract — see DESIGN.md
+    §12. ``anchor()`` ties the ring's clock to a running ``jax.profiler``
+    session: it writes one ``obs.clock`` annotation into the profiler's
+    trace and one ``obs.clock`` instant into the ring at the same moment,
+    so a reader holding both maps every ring event onto the profiler's
+    nanoseconds, beside the device's operations.
+  * **Safe for concurrent writers.** The client thread (``submit``) and the
+    dispatcher thread (engine step) record into one ring; each event claims
+    its slot with one ``next()`` of an ``itertools.count``, which the
+    interpreter lock makes atomic, so no two writers share a slot.
   * **Spans and instants.** A span is recorded at its END as a Chrome
     "complete" event (begin timestamp captured by the caller via
     ``now()``); an instant is a point event. Interning (``name()``,
     ``track()``) happens once at instrumentation-setup time, so the hot
     path never hashes strings.
-  * **Exporters.** ``export_chrome`` writes Chrome trace-event JSON that
+  * **Exporter.** ``export_chrome`` writes Chrome trace-event JSON that
     loads in Perfetto / chrome://tracing — tracks are (pid, tid) pairs
     derived from the registered track groups (one process row per group:
-    engine, engine rows, sessions, mlfq) — and ``export_ndjson`` writes
-    newline-delimited JSON for ad-hoc tooling. ``validate_chrome`` is the
-    schema check CI runs against the exported artifact.
+    engine, engine rows, sessions, mlfq, sched) — with the clock anchor in
+    ``otherData``. ``validate_chrome`` is the schema check CI runs against
+    the exported artifact.
 
 A disabled recorder (``TraceConfig(enabled=False)``, the default) keeps
 the full API but drops every event before touching the buffer, so
@@ -32,6 +41,7 @@ instrumented code needs no branches beyond the recorder's own.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -42,6 +52,10 @@ __all__ = ["TraceConfig", "FlightRecorder", "validate_chrome"]
 
 _INSTANT, _SPAN = 0, 1
 _MAX_ARGS = 4
+# name of the clock anchor, in the profiler's trace and in the ring alike
+ANCHOR = "obs.clock"
+# rounding room (µs) when checking that exported spans nest
+_NEST_SLACK_US = 1e-3
 
 
 @dataclasses.dataclass
@@ -87,7 +101,11 @@ class FlightRecorder:
         self._a1 = [0.0] * n
         self._a2 = [0.0] * n
         self._a3 = [0.0] * n
-        self._total = 0                       # events ever recorded
+        # the claim each slot holds (-1: never written); claims come from
+        # one counter shared by every writer thread
+        self._seq = [-1] * n
+        self._claims = itertools.count()
+        self._anchor_ts: Optional[float] = None
         # interning tables: id 0 is reserved for "unnamed"/"main" so a
         # disabled recorder can hand out 0 without registering anything
         self._names: List[Tuple[str, Tuple[str, ...]]] = [("event", ())]
@@ -125,7 +143,8 @@ class FlightRecorder:
 
     def _emit(self, ph: int, name_id: int, track_id: int, ts: float,
               dur: float, a0: float, a1: float, a2: float, a3: float):
-        i = self._total % self.capacity
+        k = next(self._claims)
+        i = k % self.capacity
         self._ts[i] = ts
         self._dur[i] = dur
         self._ph[i] = ph
@@ -135,7 +154,7 @@ class FlightRecorder:
         self._a1[i] = a1
         self._a2[i] = a2
         self._a3[i] = a3
-        self._total += 1
+        self._seq[i] = k
 
     # instant/complete inline the _emit body: one less call frame per
     # event on the per-token hot path (the default-arg _clock binding
@@ -145,7 +164,8 @@ class FlightRecorder:
                 _clock=time.perf_counter):
         if not self.enabled:
             return
-        i = self._total % self.capacity
+        k = next(self._claims)
+        i = k % self.capacity
         self._ts[i] = _clock()
         self._dur[i] = 0.0
         self._ph[i] = _INSTANT
@@ -155,7 +175,7 @@ class FlightRecorder:
         self._a1[i] = a1
         self._a2[i] = a2
         self._a3[i] = a3
-        self._total += 1
+        self._seq[i] = k
 
     def complete(self, name_id: int, track_id: int, t0: float,
                  a0: float = 0.0, a1: float = 0.0, a2: float = 0.0,
@@ -165,7 +185,8 @@ class FlightRecorder:
         if not self.enabled:
             return
         dur = _clock() - t0
-        i = self._total % self.capacity
+        k = next(self._claims)
+        i = k % self.capacity
         self._ts[i] = t0
         self._dur[i] = dur if dur > 0.0 else 0.0
         self._ph[i] = _SPAN
@@ -175,7 +196,36 @@ class FlightRecorder:
         self._a1[i] = a1
         self._a2[i] = a2
         self._a3[i] = a3
-        self._total += 1
+        self._seq[i] = k
+
+    def interval(self, name_id: int, track_id: int, t0: float, t1: float,
+                 a0: float = 0.0, a1: float = 0.0, a2: float = 0.0,
+                 a3: float = 0.0):
+        """Record a span from ``t0`` to ``t1`` (both from ``now()``): for a
+        phase whose end has already passed, such as the children a step
+        emits once it knows it did work."""
+        if self.enabled:
+            self._emit(_SPAN, name_id, track_id, t0, max(t1 - t0, 0.0),
+                       a0, a1, a2, a3)
+
+    def anchor(self):
+        """Tie the ring's clock to a running ``jax.profiler`` session: one
+        ``obs.clock`` ``TraceAnnotation`` goes into the profiler's trace
+        and an ``obs.clock`` instant at the ring-clock midpoint of that
+        call into the ring. The offset between the two midpoints maps ring
+        time onto the profiler's nanoseconds. Call it right after
+        ``jax.profiler.start_trace``; nothing else of the recorder writes
+        into the profiler's trace."""
+        if not self.enabled:
+            return
+        import jax
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(ANCHOR):
+            pass
+        t1 = time.perf_counter()
+        self._anchor_ts = 0.5 * (t0 + t1)
+        self._emit(_INSTANT, self.name(ANCHOR), self.track("clock", "obs"),
+                   self._anchor_ts, 0.0, 0.0, 0.0, 0.0, 0.0)
 
     def span(self, label: str, track_label: str = "main",
              group: str = "main", **args):
@@ -186,38 +236,36 @@ class FlightRecorder:
                      tuple(float(v) for v in args.values()))
 
     # ------------------------------------------------------ accounting
+    # (export-time: each reads the slots' claims)
+    @property
+    def total(self) -> int:
+        """Events ever recorded."""
+        return max(self._seq) + 1
+
     @property
     def recorded(self) -> int:
         """Events currently held in the ring."""
-        return min(self._total, self.capacity)
-
-    @property
-    def total(self) -> int:
-        return self._total
+        return min(self.total, self.capacity)
 
     @property
     def dropped(self) -> int:
         """Events overwritten by drop-oldest wraparound."""
-        return max(0, self._total - self.capacity)
+        return max(0, self.total - self.capacity)
 
     def reset(self):
-        self._total = 0
+        self._seq = [-1] * self.capacity
+        self._claims = itertools.count()
+        self._anchor_ts = None
         self._t0 = time.perf_counter()
 
     # ------------------------------------------------------- exporting
     def events(self) -> List[dict]:
-        """Decode the ring into dicts sorted by timestamp (the ring is not
-        time-ordered after wraparound). Allocation-heavy; export-time
-        only."""
-        n = self.recorded
-        if n == 0:
-            return []
-        if self._total <= self.capacity:
-            idx = list(range(n))
-        else:                       # ring wrapped: oldest is at write head
-            head = self._total % self.capacity
-            idx = list(range(head, self.capacity)) + list(range(head))
-        idx.sort(key=self._ts.__getitem__)       # stable, like the ring
+        """Decode the ring into dicts sorted by timestamp, a span before
+        the spans it contains (the ring is not time-ordered: spans are
+        recorded at their end). ``seq`` is the event's place in recording
+        order. Allocation-heavy; export-time only."""
+        idx = [i for i, k in enumerate(self._seq) if k >= 0]
+        idx.sort(key=lambda i: (self._ts[i], -self._dur[i], self._seq[i]))
         av = (self._a0, self._a1, self._a2, self._a3)
         out = []
         for i in idx:
@@ -228,7 +276,7 @@ class FlightRecorder:
                 "name": name, "track": tlabel, "group": group,
                 "ph": "X" if self._ph[i] == _SPAN else "i",
                 "ts": self._ts[i], "dur": self._dur[i],
-                "args": args,
+                "args": args, "seq": self._seq[i],
             })
         return out
 
@@ -267,18 +315,19 @@ class FlightRecorder:
             else:
                 ev["s"] = "t"
             evs.append(ev)
+        other = {"dropped_events": self.dropped,
+                 "clock_domain": "host perf_counter"}
+        if self._anchor_ts is not None:
+            # where the profiler's obs.clock annotation sits in this
+            # file's timeline: shift the file by (the annotation's
+            # midpoint - this) to overlay it on the profiler's capture
+            other["clock_anchor_us"] = (self._anchor_ts - self._t0) * 1e6
         return {"traceEvents": evs, "displayTimeUnit": "ms",
-                "otherData": {"dropped_events": self.dropped,
-                              "clock_domain": "host perf_counter"}}
+                "otherData": other}
 
     def export_chrome(self, path: str):
         with open(path, "w") as f:
             json.dump(self.chrome(), f)
-
-    def export_ndjson(self, path: str):
-        with open(path, "w") as f:
-            for e in self.events():
-                f.write(json.dumps(e) + "\n")
 
 
 class _Span:
@@ -301,13 +350,16 @@ class _Span:
 
 def validate_chrome(obj: dict) -> List[str]:
     """Schema check for an exported Chrome trace: returns a list of
-    problems (empty = valid). CI runs this against the sched_live trace
-    artifact."""
+    problems (empty = valid). Besides the fields and the time order, the
+    spans of one track must nest: a span that starts inside another ends
+    inside it too, or Perfetto draws the pair as nonsense. CI runs this
+    against the sched_live trace artifact."""
     problems = []
     evs = obj.get("traceEvents")
     if not isinstance(evs, list) or not evs:
         return ["traceEvents missing or empty"]
     last_ts = -np.inf
+    open_ends: Dict[Tuple[int, int], List[float]] = {}
     for i, e in enumerate(evs):
         ph = e.get("ph")
         if ph not in ("X", "i", "M"):
@@ -328,7 +380,17 @@ def validate_chrome(obj: dict) -> List[str]:
             problems.append(f"event {i}: ts {ts} < previous {last_ts} "
                             "(export must be time-sorted)")
         last_ts = ts
-        if ph == "X" and (not isinstance(e.get("dur"), (int, float))
-                          or e["dur"] < 0):
+        if ph != "X":
+            continue
+        if not isinstance(e.get("dur"), (int, float)) or e["dur"] < 0:
             problems.append(f"event {i}: X event needs dur >= 0")
+            continue
+        stack = open_ends.setdefault((e.get("pid"), e.get("tid")), [])
+        while stack and stack[-1] <= ts + _NEST_SLACK_US:
+            stack.pop()
+        end = ts + e["dur"]
+        if stack and end > stack[-1] + _NEST_SLACK_US:
+            problems.append(f"event {i}: span {e['name']!r} overlaps the "
+                            "span it starts in on its track")
+        stack.append(end)
     return problems

@@ -276,10 +276,12 @@ class PagedInferenceEngine:
         self._c_tokens_real = m.counter(f"{name}.tokens_real")
         self._c_tokens_disp = m.counter(f"{name}.tokens_dispatched")
         # wall-clock latency distributions (seconds): time-to-first-token
-        # per turn, the gap between consecutive output tokens of one turn,
-        # and host wall time around each work-doing step. Fixed log-spaced
-        # buckets + a bounded reservoir — a long-lived engine no longer
-        # grows per-token Python lists forever.
+        # per turn from its entry into the engine (``submit``/``extend``,
+        # i.e. after the scheduler's admission — the MLFQ queue wait is
+        # not in it), the gap between consecutive output tokens of one
+        # turn, and host wall time around each work-doing step. Fixed
+        # log-spaced buckets + a bounded reservoir — a long-lived engine
+        # no longer grows per-token Python lists forever.
         self.h_ttft = m.histogram(f"{name}.ttft_s", LATENCY_BUCKETS_S,
                                   reservoir=512)
         self.h_itl = m.histogram(f"{name}.itl_s", LATENCY_BUCKETS_S,
@@ -301,8 +303,9 @@ class PagedInferenceEngine:
         self._c_swap_fail = m.counter(f"{name}.swap_io_failures")
 
         # flight-recorder interning (once, here — the hot path only passes
-        # ints). Tracks: one engine row for megasteps, one row per batch
-        # slot, one row per session (lazily, at submit).
+        # ints). Tracks: one engine row for megasteps (and the phases they
+        # contain), one row per batch slot, one row per session (lazily,
+        # at submit).
         rec = self.obs.recorder
         self._tr_step = rec.track("megastep", group=name)
         self._tr_rows = [rec.track(f"row {s}", group=f"{name} rows")
@@ -311,6 +314,12 @@ class PagedInferenceEngine:
         self._ev_step = rec.name(
             "engine.megastep",
             ("C", "rows", "tokens_real", "tokens_dispatched"))
+        # the megastep's phases, in order: admission, packing rows into
+        # host arrays, host->device transfer + the jitted call returning,
+        # the host waiting on the device for the sampled ids, token
+        # bookkeeping + prefix registration
+        self._ev_phases = tuple(rec.name(f"engine.{p}") for p in (
+            "admit", "pack", "dispatch", "readback", "retire"))
         # one instant per sharded megastep: mesh shape + per-shard work +
         # an estimate of what the per-layer attention-output psums moved —
         # Perfetto shows TP overhead next to the megastep span. Emitted
@@ -768,13 +777,15 @@ class PagedInferenceEngine:
         a decode call) is kept as the benchmark baseline."""
         self.last_serviced = {}
         self.last_failures = []
+        # the megastep's flight-recorder span opens before admission
+        t_admit = time.perf_counter() if self.obs.recorder.enabled else 0.0
         self._admit()                 # may append swap-IO casualties
         if not self.active:
             return []
         t0 = time.perf_counter()
         before = self._c_jit.value
         if self.use_megastep:
-            fins = self._step_megastep(t0)
+            fins = self._step_megastep(t0, t_admit)
         else:
             fins = self._step_legacy(t0)
         if self._c_jit.value != before:     # a work-doing iteration
@@ -997,7 +1008,8 @@ class PagedInferenceEngine:
                 remaining -= T
         return rows
 
-    def _step_megastep(self, t0: float = 0.0) -> List[PagedRequest]:
+    def _step_megastep(self, t0: float = 0.0,
+                       t_admit: float = 0.0) -> List[PagedRequest]:
         """The fused iteration: pack one (max_batch, C) token matrix
         (decode-first under a token budget — see ``_pack_rows``), run ONE
         jitted forward over the union (K/V scatter, paged attention, greedy
@@ -1007,12 +1019,16 @@ class PagedInferenceEngine:
         trace bucket (never paying chunk-width FLOPs) and the number of
         distinct traced shapes stays <= len(bucket_set).
 
-        ``t0`` anchors the step's flight-recorder span: host wall clock
-        around the one jitted dispatch (pack -> dispatch -> int32
-        readback), annotated with C / rows / tokens — all host-available
-        already, so the one-dispatch and int32-return contracts are
-        untouched by tracing."""
+        ``t_admit`` (before ``_admit``) opens the step's flight-recorder
+        span and ``t0`` (after it) closes its ``engine.admit`` child; the
+        span covers admission, packing, the one jitted dispatch, the int32
+        readback and retirement, each a child, and is annotated with C /
+        rows / tokens — all host-available already, so the one-dispatch
+        and int32-return contracts are untouched by tracing. With the
+        recorder off no phase boundary is read from the clock."""
         finished: List[PagedRequest] = []
+        rec = self.obs.recorder
+        tracing = rec.enabled
         rows = self._pack_rows()             # (req, T) surviving growth
         if not rows:
             return finished
@@ -1040,6 +1056,7 @@ class PagedInferenceEngine:
             tables[s] = req.table.padded(self.max_pages)
             if req.rid in self._poison_rids:
                 poison[s] = True
+        t_packed = time.perf_counter() if tracing else 0.0
         next_tok, pools = self._mega(
             self.params, self.cache.pools(), jnp.asarray(toks),
             jnp.asarray(lens), jnp.asarray(valids), jnp.asarray(tables),
@@ -1049,9 +1066,10 @@ class PagedInferenceEngine:
         self.steps_dispatched += 1
         if any(not r.prefilling for r, _ in rows):
             self.decode_steps += 1
+        t_sent = time.perf_counter() if tracing else 0.0
         out = np.asarray(next_tok)           # (max_batch,) int32 — the only
-        rec = self.obs.recorder              # per-step device->host transfer
-        tracing = rec.enabled
+        #                                      per-step device->host transfer
+        t_read = time.perf_counter() if tracing else 0.0
         for req, T in rows:
             was_prefilling = req.prefilling
             req.table.num_tokens += T
@@ -1089,7 +1107,12 @@ class PagedInferenceEngine:
                     self.last_serviced.get(req.rid, 0) + 1
             self._finish_token(req, int(out[req.slot]), finished)
         if tracing:
-            rec.complete(self._ev_step, self._tr_step, t0, C, len(rows),
+            t_end = time.perf_counter()
+            tr = self._tr_step
+            bounds = (t_admit, t0, t_packed, t_sent, t_read, t_end)
+            for ev, a, b in zip(self._ev_phases, bounds, bounds[1:]):
+                rec.interval(ev, tr, a, b)
+            rec.interval(self._ev_step, tr, t_admit, t_end, C, len(rows),
                          step_real, self.max_batch * C)
             if self.tp > 1:
                 # what this step's collectives moved, per shard: one
@@ -1258,7 +1281,10 @@ class PagedInferenceEngine:
 
     @property
     def ttft_s(self) -> List[float]:
-        """Bounded TTFT samples (the histogram's reservoir) — kept as a
+        """Bounded TTFT samples (the histogram's reservoir), each from the
+        turn's admission into the engine (``submit``/``extend``) to its
+        first token — the scheduler's queue wait before that is not in it
+        (the flight recorder's ``sched.queued`` spans carry it). A
         list-shaped view for tests/tools; the distribution itself lives in
         the registry histogram ``engine.ttft_s``."""
         return self.h_ttft.samples
