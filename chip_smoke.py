@@ -209,6 +209,10 @@ def one_chip(seed: int, problems: list):
         f"paged kernel {engine.paged_kernel}, block {args.block_size}, "
         f"blocks {args.num_blocks}, max_len {engine.max_len}, "
         f"buckets {list(engine.bucket_set)}")
+    log("paged kernel plan per bucket: " + "; ".join(
+        f"C={c}: {p.rows} rows x {p.query_tiles} tiles, "
+        f"{p.pages_per_block} pages/block"
+        for c, p in sorted(engine.kernel_plans.items())))
     if not engine.paged_kernel:
         problems.append("the engine did not choose the paged kernel")
     backend.prompt_tokens = PROMPT_TOKENS

@@ -65,6 +65,7 @@ from repro.distributed.sharding import (TP, kv_pool_pspec,
                                         megastep_input_pspecs,
                                         megastep_output_pspec,
                                         shard_serving_params, validate_tp)
+from repro.kernels.paged_attention.kernel import KernelPlan, kernel_plan
 from repro.models import build
 from repro.models import transformer as tr
 from repro.obs import LATENCY_BUCKETS_S, Observability
@@ -81,9 +82,8 @@ QUEUED, ACTIVE, PARKED, SWAPPED, FREED = \
     "queued", "active", "parked", "swapped", "freed"
 
 
-# minimum non-decode dispatch width: the Pallas chunk axis is padded to the
-# f32 sublane width anyway, so buckets narrower than 8 would retrace without
-# saving a single FLOP
+# minimum non-decode dispatch width: a bucket narrower than the f32 sublane
+# width (8) would add a trace for little saving
 _MIN_CHUNK_BUCKET = 8
 
 
@@ -273,6 +273,8 @@ class PagedInferenceEngine:
         self.trace_buckets: set = set()
         self.compiled_buckets: set = set()   # pre-traced by compile_buckets
         self.compile_seconds: Dict[int, float] = {}
+        # the paged kernel's tiling per compiled bucket (kernel path only)
+        self.kernel_plans: Dict[int, KernelPlan] = {}
         self._c_tokens_real = m.counter(f"{name}.tokens_real")
         self._c_tokens_disp = m.counter(f"{name}.tokens_dispatched")
         # wall-clock latency distributions (seconds): time-to-first-token
@@ -406,7 +408,8 @@ class PagedInferenceEngine:
         only widths live traffic actually dispatched, so the benchmark's
         buckets-used column and the recompile guard stay meaningful.
         ``compile_seconds`` records each bucket's set-up wall time: trace,
-        compile (or a persistent-cache load) and one dummy dispatch."""
+        compile (or a persistent-cache load) and one dummy dispatch;
+        ``kernel_plans`` the paged kernel's tiling there, where it runs."""
         if not self.use_megastep:
             return
         for C in self.bucket_set:
@@ -425,6 +428,19 @@ class PagedInferenceEngine:
             self.cache.set_pools(jax.block_until_ready(pools))
             self.compiled_buckets.add(C)
             self.compile_seconds[C] = time.perf_counter() - t0
+            if self.paged_kernel:
+                self.kernel_plans[C] = self.kernel_plan(C)
+
+    def kernel_plan(self, C: int) -> KernelPlan:
+        """The paged kernel's tiling at megastep width ``C``: a function of
+        the shapes one shard's megastep hands the kernel (local head counts
+        under a mesh, the table's ``max_pages``, the pool's dtype)."""
+        cfg, hd = self.cfg, self.cfg.resolved_head_dim
+        return kernel_plan(C=C, hq=cfg.n_heads // self.tp,
+                           hkv=cfg.n_kv_heads // self.tp,
+                           blk=self.cache.block_size, d=hd, dv=hd,
+                           npages=self.max_pages, q_dtype=cfg.compute_dtype,
+                           kv_dtype=self.cache.k.dtype)
 
     def set_token_budget(self, budget: int) -> int:
         """Retune the per-step token budget LIVE, without retracing.

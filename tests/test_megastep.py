@@ -9,7 +9,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
-from repro.kernels.paged_attention.kernel import paged_prefill_attention_bcd
+from repro.kernels.paged_attention.kernel import (kernel_plan,
+                                                  paged_prefill_attention_bcd)
 from repro.kernels.paged_attention.ref import (
     paged_prefill_attention_gathered_oracle, paged_prefill_attention_ref)
 from repro.models import build
@@ -174,6 +175,114 @@ def test_mixed_step_kernel_matches_reference_path_at_f32():
     np.testing.assert_array_equal(np.asarray(vk), np.asarray(vr))
 
 
+# --------------------------------------------------- kernel tiling limits
+
+# Each case crosses a boundary of the kernel's plan (``kernel_plan``):
+# several KV blocks with a table that is not a whole number of blocks, a
+# context ending exactly on a block edge, g = 9 at hkv 4, blk 8 and 16,
+# C in {1, 8, 64} and widths with several query tiles, ragged valids
+# (inactive 0, decode 1, partial, full) and bf16 q and pool.
+TILING_CASES = {
+    # P = 64 pages of 8: 70 pages is one block and 6 pages; row 2 reaches
+    # into the second block, row 1 stops just short of its edge
+    "blk8-table-not-whole-blocks": dict(
+        hq=4, hkv=2, blk=8, npages=70, C=8,
+        cache=[0, 503, 551, 200], valids=[0, 1, 8, 5]),
+    # P = 32 pages of 16: rows end exactly on the block edge (512), one
+    # past it, one short of it
+    "blk16-block-edge": dict(
+        hq=8, hkv=2, blk=16, npages=40, C=8,
+        cache=[0, 504, 512, 510], valids=[0, 8, 1, 1]),
+    "g9-hkv4-decode": dict(
+        hq=36, hkv=4, blk=16, npages=40, C=1,
+        cache=[0, 0, 299, 639], valids=[0, 1, 1, 1]),
+    # several query tiles, the later ones live in one row only
+    "g9-hkv4-C64-query-tiles": dict(
+        hq=36, hkv=4, blk=16, npages=40, C=64,
+        cache=[0, 33, 100, 300], valids=[0, 1, 20, 64]),
+    # several query tiles, tiles past valids skipped, a chunk walking two
+    # blocks
+    "g9-blk8-C128-query-tiles": dict(
+        hq=36, hkv=4, blk=8, npages=70, C=128,
+        cache=[0, 4, 300, 400], valids=[0, 1, 40, 128]),
+    # chatglm3-6b's group: g = 16 at hkv 2
+    "g16-C8": dict(
+        hq=32, hkv=2, blk=16, npages=40, C=8,
+        cache=[0, 7, 200, 600], valids=[0, 1, 3, 8]),
+    "bf16-g16-decode": dict(
+        hq=32, hkv=2, blk=16, npages=40, C=1, dtype=jnp.bfloat16,
+        cache=[0, 20, 511, 639], valids=[0, 1, 1, 1]),
+    "bf16-g9-C64": dict(
+        hq=36, hkv=4, blk=16, npages=40, C=64, dtype=jnp.bfloat16,
+        cache=[0, 3, 450, 576], valids=[0, 1, 30, 64]),
+}
+
+
+def _tiling_case(hq, hkv, blk, npages, C, cache, valids, dtype=jnp.float32,
+                 d=32, seed=0):
+    rng = np.random.default_rng(seed)
+    b = len(cache)
+    nb = b * npages + 1
+    q = jnp.asarray(rng.standard_normal((b, C, hq, d)), dtype)
+    k_pool = jnp.asarray(rng.standard_normal((nb, hkv, blk, d)), dtype)
+    v_pool = jnp.asarray(rng.standard_normal((nb, hkv, blk, d)), dtype)
+    ids = rng.permutation(np.arange(1, nb))[: b * npages].reshape(b, npages)
+    return (q, k_pool, v_pool, jnp.asarray(cache, jnp.int32),
+            jnp.asarray(valids, jnp.int32), jnp.asarray(ids, jnp.int32))
+
+
+@pytest.mark.parametrize("name", list(TILING_CASES))
+def test_kernel_parity_across_tiling_boundaries(name):
+    """At every tiling boundary the interpret-mode kernel matches the
+    gathered-view twin bit for bit and the quadratic jnp oracle on every
+    real query position (f32 at 1e-5, bf16 at bf16 tolerance); query
+    tiles wholly past a row's valid width (other than its first) are
+    zeros, the walk having skipped them."""
+    spec = TILING_CASES[name]
+    case = _tiling_case(**spec)
+    q, k_pool = case[0], case[1]
+    b, C, hq, d = q.shape
+    out = np.asarray(paged_prefill_attention_bcd(*case, interpret=True),
+                     np.float32)
+    oracle = np.asarray(paged_prefill_attention_gathered_oracle(*case),
+                        np.float32)
+    np.testing.assert_array_equal(out, oracle)
+    ref = np.asarray(paged_prefill_attention_ref(*case), np.float32)
+    valids = np.asarray(spec["valids"])
+    live = valids[:, None] > np.arange(C)[None, :]
+    tol = 2e-2 if q.dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(out[live], ref[live], atol=tol, rtol=tol)
+    plan = kernel_plan(C=C, hq=hq, hkv=spec["hkv"], blk=spec["blk"], d=d,
+                       dv=d, npages=spec["npages"], q_dtype=q.dtype,
+                       kv_dtype=k_pool.dtype)
+    tile = np.arange(C) // plan.tile_positions
+    skipped = (tile[None, :] > 0) & (
+        tile[None, :] * plan.tile_positions >= valids[:, None])
+    assert not out[skipped].any()
+
+
+def test_kernel_never_reads_pages_past_the_live_length():
+    """Table entries past a row's live pages name a block full of NaN: the
+    walk copies no page past the live length (and zeroes V positions it
+    did not copy), so every output stays finite and equal to the result
+    over a clean pool."""
+    spec = TILING_CASES["g9-blk8-C128-query-tiles"]
+    q, k_pool, v_pool, cache, valids, pt = _tiling_case(**spec)
+    blk = spec["blk"]
+    live_pages = -(-(np.asarray(cache) + np.asarray(valids)) // blk)
+    live_pages = np.maximum(live_pages, 1)
+    dead = np.arange(pt.shape[1])[None, :] >= live_pages[:, None]
+    poisoned = jnp.where(jnp.asarray(dead), 0, pt)       # block 0: NaN
+    nan_k = k_pool.at[0].set(jnp.nan)
+    nan_v = v_pool.at[0].set(jnp.nan)
+    out = paged_prefill_attention_bcd(q, nan_k, nan_v, cache, valids,
+                                      poisoned, interpret=True)
+    clean = paged_prefill_attention_bcd(q, k_pool, v_pool, cache, valids,
+                                        poisoned, interpret=True)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
+
+
 # ------------------------------------------------------------ engine level
 
 @pytest.fixture(scope="module")
@@ -236,6 +345,28 @@ def test_megastep_matches_legacy_tokens_at_f32(setup):
         return {r: eng.reqs[r].out_tokens for r in rids}
 
     assert run(True) == run(False)
+
+
+def test_engine_records_kernel_plan_per_compiled_bucket(setup):
+    """An engine on the kernel path records, for every bucket it compiles,
+    the tiling the kernel runs there: the plan of the shapes the megastep
+    hands the kernel. The CPU engine runs the jnp reference and records
+    none; flipping the flag after construction records the plans without
+    changing the attention path the engine already traced."""
+    cfg, params = setup
+    eng = _paged(cfg, params, token_budget=16)
+    eng.compile_buckets()
+    assert eng.kernel_plans == {}
+    eng.paged_kernel = True
+    eng.compiled_buckets.clear()
+    eng.compile_buckets()
+    assert set(eng.kernel_plans) == set(eng.bucket_set)
+    hd = cfg.resolved_head_dim
+    for C, plan in eng.kernel_plans.items():
+        assert plan == kernel_plan(
+            C=C, hq=cfg.n_heads, hkv=cfg.n_kv_heads, blk=BLOCK_SIZE, d=hd,
+            dv=hd, npages=eng.max_pages, q_dtype=cfg.compute_dtype,
+            kv_dtype=eng.cache.k.dtype)
 
 
 def test_prefix_dedup_survives_hibernate_wake(setup):

@@ -68,6 +68,8 @@ def _tol(dtype):
     (3, 4, 2, 32, 32, 16, 4, (37, 1, 64)),      # ragged, non-multiple of blk
     (2, 8, 1, 64, 64, 32, 3, (95, 17)),         # MQA, partial last page
     (1, 4, 4, 64, 32, 16, 2, (32,)),            # narrow V, exact multiple
+    (2, 36, 4, 32, 32, 16, 40, (640, 1)),       # g 9, two KV blocks
+    (2, 4, 2, 32, 32, 8, 70, (512, 560)),       # block edge, whole table
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_matches_dense_and_ref(b, hq, hkv, d, dv, blk,

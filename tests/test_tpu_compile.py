@@ -24,9 +24,12 @@ from repro.distributed.sharding import (TP, kv_pool_pspec,
                                         megastep_input_pspecs,
                                         megastep_output_pspec,
                                         serving_param_pspecs)
-from repro.kernels.paged_attention.kernel import paged_prefill_attention_bcd
+from repro.kernels.paged_attention.kernel import (SCOPED_VMEM_BYTES,
+                                                  kernel_plan,
+                                                  paged_prefill_attention_bcd)
 from repro.models import abstract_params, build
 from repro.models import transformer as tr
+from repro.serving import budget_buckets
 
 # the serving shapes chip_smoke.py runs: --block-size 16 (one bf16 sublane
 # tile per (blk, head_dim) slab) and --token-budget 256, whose widest
@@ -35,6 +38,10 @@ BLOCK_SIZE = 16
 TOKEN_BUDGET = 256
 MAX_BATCH, NUM_BLOCKS, MAX_LEN = 4, 1024, 3072
 V5E_HBM_BYTES = 16 * 2**30
+# the benchmark's serving shapes (bench/configs/chatglm3-6b.json): 8 rows,
+# max_len 8192 in pages of 16, 3456 blocks, budget 1024
+SERVE_BATCH, SERVE_PAGES, SERVE_BLOCKS = 8, 8192 // BLOCK_SIZE, 3456
+SERVE_BUCKETS = budget_buckets(1024)
 
 
 @pytest.fixture(scope="module")
@@ -75,16 +82,18 @@ def _megastep_args(sharding, C):
             _sds(sharding, (B,), jnp.bool_))
 
 
-@pytest.mark.parametrize("C", [1, TOKEN_BUDGET])
+@pytest.mark.parametrize("C", SERVE_BUCKETS)
 @pytest.mark.parametrize("arch", ["chatglm3-6b", "starcoder2-7b"])
 def test_paged_kernel_compiles_for_v5e(one_chip, arch, C):
     """The chunked-prefill kernel at published head widths (chatglm3-6b:
     hq 32, hkv 2, d 128; starcoder2-7b: hq 36, hkv 4, d 128) over a bf16
-    pool lowers through Mosaic: the head-major (blk, d) K/V slab is a
-    legal block for every hkv."""
+    pool lowers through Mosaic at every bucket of a 1024-token budget,
+    with the benchmark's table of 512 pages, inside Mosaic's default
+    scoped VMEM: the per-page copies of (blk, d) slabs, the (2, P, blk, d)
+    block buffers and the query tiles the plan picks are all legal."""
     cfg = get_config(arch)
     hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    b, npages, nb = MAX_BATCH, MAX_LEN // BLOCK_SIZE, NUM_BLOCKS
+    b, npages, nb = SERVE_BATCH, SERVE_PAGES, SERVE_BLOCKS
     bf16, i32 = jnp.bfloat16, jnp.int32
     args = (_sds(one_chip, (b, C, hq, d), bf16),
             _sds(one_chip, (nb, hkv, BLOCK_SIZE, d), bf16),
@@ -93,6 +102,36 @@ def test_paged_kernel_compiles_for_v5e(one_chip, arch, C):
             _sds(one_chip, (b, npages), i32))
     compiled = jax.jit(paged_prefill_attention_bcd).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("arch", ["chatglm3-6b", "starcoder2-7b"])
+def test_kernel_plan_fits_scoped_vmem(arch):
+    """The kernel's plan at every bucket of a 1024-token budget, for the
+    whole model and for a tp=2 shard (half the heads of each kind): its
+    buffers fit the scoped VMEM it assumes, a tile holds the whole GQA
+    group for its positions in sublane-aligned rows, the tiles cover the
+    chunk, and a decode tile is the group alone."""
+    cfg = get_config(arch)
+    d = cfg.resolved_head_dim
+    for tp in (1, 2):
+        hq, hkv = cfg.n_heads // tp, cfg.n_kv_heads // tp
+        g = hq // hkv
+        for C in SERVE_BUCKETS:
+            plan = kernel_plan(C=C, hq=hq, hkv=hkv, blk=BLOCK_SIZE, d=d,
+                               dv=d, npages=SERVE_PAGES,
+                               q_dtype=jnp.bfloat16, kv_dtype=jnp.bfloat16)
+            assert plan.vmem_bytes <= SCOPED_VMEM_BYTES
+            assert plan.rows % 16 == 0
+            assert g * plan.tile_positions <= plan.rows \
+                < g * plan.tile_positions + 16
+            assert (plan.query_tiles - 1) * plan.tile_positions < C \
+                <= plan.query_tiles * plan.tile_positions
+            assert 1 <= plan.pages_per_block <= SERVE_PAGES
+        decode = kernel_plan(C=1, hq=hq, hkv=hkv, blk=BLOCK_SIZE, d=d,
+                             dv=d, npages=SERVE_PAGES, q_dtype=jnp.bfloat16,
+                             kv_dtype=jnp.bfloat16)
+        assert (decode.tile_positions, decode.query_tiles) == (1, 1)
+        assert decode.rows == -(-g // 16) * 16
 
 
 def test_chatglm3_bf16_init_makes_no_f32_copy(one_chip):
