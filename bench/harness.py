@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -23,7 +24,7 @@ import sys
 import tempfile
 import time
 from types import SimpleNamespace
-from typing import Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -53,6 +54,7 @@ class Cell:
     end_to_end: List[Dict]
     per_layer: List[Dict]
     roots: tuple
+    arch: Any = None              # the configuration's archs/<name>.py
 
 
 def load_cell(workload: str, spec: Optional[Dict] = None,
@@ -75,19 +77,63 @@ def load_cell(workload: str, spec: Optional[Dict] = None,
     per_layer = [m for m in spec["per_layer"]
                  if (workload in m["workloads"] if "workloads" in m
                      else m["moves"] in e2e_names)]
-    return Cell(workload, model.load("configs", w["config"], roots),
+    config_path = model.find("configs", w["config"], roots)
+    config = model.load("configs", w["config"], roots)
+    return Cell(workload, config,
                 model.load("traffic", w["traffic"], roots), int(w["chips"]),
-                e2e, per_layer, tuple(roots))
+                e2e, per_layer, tuple(roots),
+                config_arch(config, config_path, tuple(roots)))
+
+
+def _module(kind: str, name: str, roots):
+    """``<kind>/<name>.py``, found by name and loaded as a module."""
+    path = model.find(kind, name, roots, ext=".py")
+    tag = "".join(c if c.isalnum() else "_" for c in name)
+    mod_spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{tag}", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_reader(name: str, roots) -> Callable:
     """``read(ctx)`` of ``metrics/<name>.py``, found by the metric's name."""
-    path = model.find("metrics", name, roots, ext=".py")
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return _module("metrics", name, roots).read
+
+
+# What an architecture module defines: ``dims(config)`` the normalised
+# sizes (with ``dtype`` and ``vocab_size``); ``param_shapes(d)`` the served
+# parameter tree as shape tuples; ``program_config(config, d)`` the served
+# program's ModelConfig; ``cache_shapes(d, cache_len)`` the reference's
+# cache as a tree of shape tuples; ``forward_chunk(d, control, params,
+# cache, toks, nxt, start)`` one block of the reference (with ``control``
+# its lower-precision control), returning (cache, max logit, logit of
+# ``nxt``, argmax); ``paged_attn(d, rows)`` the paged-attention kernel's
+# (FLOPs, bytes) and ``model_flops(d, rows)`` the step's model FLOPs.
+ARCH_API = ("dims", "param_shapes", "program_config", "cache_shapes",
+            "forward_chunk", "paged_attn", "model_flops")
+
+
+@functools.lru_cache(maxsize=None)
+def arch_module(name: str, roots=(BENCH,)):
+    """``archs/<name>.py``, found by name; loaded once per process, so the
+    reference's compiled blocks are shared by every cell that names it."""
+    mod = _module("archs", name, roots)
+    missing = [f for f in ARCH_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise HarnessError(f"{mod.__file__} does not define {missing}")
+    return mod
+
+
+def config_arch(config: Dict, path: str, roots=(BENCH,)):
+    """The architecture module that the configuration file names."""
+    if "arch" not in config:
+        raise HarnessError(f"{path}: no \"arch\" key naming its "
+                           "architecture module (archs/<name>.py)")
+    try:
+        return arch_module(config["arch"], tuple(roots))
+    except FileNotFoundError as e:
+        raise HarnessError(f"{path}: arch {config['arch']!r}: {e}") from None
 
 
 def peaks_for(kind: str, path: str = os.path.join(BENCH, "peaks.json")):
@@ -100,20 +146,6 @@ def peaks_for(kind: str, path: str = os.path.join(BENCH, "peaks.json")):
 
 
 # ---------------------------------------------------- the program's side
-def program_config(config: Dict, d: Dict):
-    """The served program's ModelConfig for a configuration file."""
-    from repro.configs import get_config
-    acts = {"swiglu": "silu", "gelu_tanh": "gelu_mlp"}
-    return get_config(config["program_arch"]).replace(
-        n_layers=d["n_layers"], d_model=d["d_model"], n_heads=d["n_heads"],
-        n_kv_heads=d["n_kv_heads"], head_dim=d["head_dim"], d_ff=d["d_ff"],
-        vocab_size=d["vocab_size"], act=acts[d["mlp"]],
-        rope_theta=d["rope_theta"],
-        rotary_pct=d["rotary_dims"] / d["head_dim"], norm_eps=d["norm_eps"],
-        tie_embeddings=False, gqa_mode="tiled", remat=False,
-        compute_dtype=d["dtype"], param_dtype=d["dtype"])
-
-
 def serve_args(config: Dict):
     from repro.launch.serve import build_parser
     s = config["serving"]
@@ -457,11 +489,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace_on: bool, *,
     phases = {"imports_s": t_entry - t_start,
               "devices_s": t_devices - t_entry,
               "start_s": time.perf_counter() - t_start}
-    config = cell.config
-    d = model.dims(config)
-    cfg = program_config(config, d)
+    config, arch = cell.config, cell.arch
+    d = arch.dims(config)
+    cfg = arch.program_config(config, d)
     t = time.perf_counter()
-    params = jax.block_until_ready(model.make_params(d, seed))
+    params = jax.block_until_ready(
+        model.make_params(arch.param_shapes(d), d["dtype"], seed))
     phases["weights_s"] = time.perf_counter() - t
     want = jax.tree.map(lambda s: (s.shape, s.dtype), abstract_params(cfg))
     have = jax.tree.map(lambda a: (a.shape, a.dtype), params)
@@ -511,8 +544,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace_on: bool, *,
     max_ref = config["serving"]["max_len"] * 12
     seqs = served_sequences(clients.sessions, seed, max_ref)
     t = time.perf_counter()
-    chk = reference.compare(d, params, seqs, config["serving"]["max_len"],
-                            control=control)
+    chk = reference.compare(arch, d, params, seqs,
+                            config["serving"]["max_len"], control=control)
     ref_s = time.perf_counter() - t
 
     compared = {
@@ -530,6 +563,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace_on: bool, *,
     report = {
         "setup": {**phases, "setup_s": setup_s},
         "samples": e2e["samples"], "window_s": w1 - w0,
+        "ttft_p95_s": e2e["ttft_p95_s"],
         "setup_turns": setup_turns,
         "padded_token_fraction": st["padded_token_fraction"],
         "trace_buckets": st["trace_buckets"],
@@ -561,12 +595,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace_on: bool, *,
                      "idle_gaps": trace.idle_gaps(events, lo, hi)}
         h0, h1 = rec.traced or (w0, w1)
         ctx = SimpleNamespace(
-            dims=d, peaks=peaks, flops=flops, stats=stats, trace=trace,
+            dims=d, peaks=peaks, flops=flops.counts(arch), stats=stats,
+            trace=trace,
             events=events, trace_window=(lo, hi), window=(h0, h1),
             steps=[s for s in rec.steps if s["t0"] >= h0 and s["t1"] <= h1],
             turns=turns, compile_seconds=bucket_compile, phases=phases)
         if peaks is not None:
-            bound = [flops.least_time(*flops.paged_attn(d, s["rows"]),
+            bound = [flops.least_time(*arch.paged_attn(d, s["rows"]),
                                       peaks)[1] for s in ctx.steps]
             log(f"traced steps {len(ctx.steps)}; paged-attention roofline "
                 f"bound by flops in {bound.count('flops')}, by bytes in "

@@ -3,8 +3,9 @@
     python3 -m bench.run --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Run from the root of a checkout. The cell (a ``workloads`` entry of
-``BENCHMARK.json``) names a configuration (``bench/configs/<name>.json``)
-and a traffic mix (``bench/traffic/<name>.json``); ``--trace 1`` reports
+``BENCHMARK.json``) names a configuration (``bench/configs/<name>.json``,
+which names its architecture module ``bench/archs/<name>.py``) and a
+traffic mix (``bench/traffic/<name>.json``); ``--trace 1`` reports
 the cell's per-layer metrics, each read by ``bench/metrics/<name>.py``,
 and ``--trace 0`` its end-to-end metrics. The last line on standard output
 is the result as one JSON object; the last lines on standard error are the

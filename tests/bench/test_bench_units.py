@@ -1,22 +1,30 @@
 """The benchmark's yardstick on the CPU: the traffic generator, the
 end-to-end arithmetic, the work counts, the trace reduction, the peak
-table and how files are found by name. Nothing here needs a chip."""
+table, the architecture modules and how files are found by name. Nothing
+here needs a chip."""
+import glob
 import hashlib
 import json
 import math
 import os
+import re
+import shutil
 import sys
+from types import SimpleNamespace
 
 import jax
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path[:0] = [REPO, os.path.join(REPO, "src")]
 
-from bench import flops, harness, model, stats, trace, traffic  # noqa: E402
+from bench import flops, harness, model, reference  # noqa: E402
+from bench import stats, trace, traffic  # noqa: E402
 
 DATA = os.path.join(REPO, "tests", "bench", "data")
+GQA = harness.arch_module("gqa")
 MIXES = ["agent-tools", "short-chat"]
 
 
@@ -113,6 +121,17 @@ def test_end_to_end_arithmetic_with_censoring_and_edges():
     assert e["samples"]["first_tokens"] == 3
 
 
+def test_tail_ttft_reader_is_the_end_to_end_p95_of_the_traced_window():
+    turns = [_turn(12.0, [13.0], 1), _turn(15.0, [21.0], 4),
+             _turn(19.0, [], 4), _turn(11.0, [11.2], 1)]
+    ctx = SimpleNamespace(window=(10.0, 20.0), turns=turns, stats=stats)
+    read = harness.metric_reader("ttft_tail_p95_s", (harness.BENCH,))
+    assert read(ctx) == pytest.approx(
+        stats.end_to_end(turns, 10.0, 20.0)["ttft_p95_s"])
+    assert read(SimpleNamespace(window=(30.0, 40.0), turns=turns,
+                                stats=stats)) is None
+
+
 def test_queue_wait_counts_unbegun_turns_until_the_window_ends():
     turns = [_turn(0, [], 1, submit=11.0, begin=12.5),
              _turn(0, [], 1, submit=18.0, begin=None),
@@ -133,21 +152,21 @@ D = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
 
 def test_attention_counts_match_a_hand_computed_case():
     # prefill row: 3 new tokens after 5 cached; causal pairs = 3*8 - 3 = 21
-    assert flops.attn_flops_row(D, 5, 3) == 4 * 4 * 16 * 21
+    assert GQA.attn_flops_row(D, 5, 3) == 4 * 4 * 16 * 21
     # K and V of 8 positions for 2 kv heads, bf16, plus q and out of 3 rows
-    assert flops.attn_bytes_row(D, 5, 3) == 2 * 2 * 16 * 8 * 2 + \
+    assert GQA.attn_bytes_row(D, 5, 3) == 2 * 2 * 16 * 8 * 2 + \
         2 * 3 * 4 * 16 * 2
-    f, b = flops.paged_attn(D, [(5, 3), (9, 1)])
+    f, b = GQA.paged_attn(D, [(5, 3), (9, 1)])
     assert f == 2 * (4 * 4 * 16 * 21 + 4 * 4 * 16 * 10)
-    assert b == 2 * (flops.attn_bytes_row(D, 5, 3)
-                     + flops.attn_bytes_row(D, 9, 1))
+    assert b == 2 * (GQA.attn_bytes_row(D, 5, 3)
+                     + GQA.attn_bytes_row(D, 9, 1))
 
 
 def test_counts_are_brute_force_pairs():
     for cache_len, q in [(0, 1), (0, 7), (13, 1), (100, 37)]:
         pairs = sum(1 for i in range(q) for j in range(cache_len + q)
                     if j <= cache_len + i)
-        assert flops.attn_flops_row(D, cache_len, q) == \
+        assert GQA.attn_flops_row(D, cache_len, q) == \
             4 * D["n_heads"] * D["head_dim"] * pairs
 
 
@@ -159,18 +178,18 @@ def test_counts_do_not_depend_on_the_kernel_grid(serving):
     give the same work whatever the pool's page size or the batch width."""
     rows = [(1500, 1), (0, 200), (37, 1)]
     d = dict(D, **serving)
-    assert flops.paged_attn(d, rows) == flops.paged_attn(D, rows)
-    assert flops.model_flops(d, rows) == flops.model_flops(D, rows)
+    assert GQA.paged_attn(d, rows) == GQA.paged_attn(D, rows)
+    assert GQA.model_flops(d, rows) == GQA.model_flops(D, rows)
 
 
 def test_model_flops_count_the_head_once_per_row():
-    per_tok = 2 * flops.matmul_params(D)
+    per_tok = 2 * GQA.matmul_params(D)
     head = 2 * 64 * 256
-    assert flops.model_flops(D, [(0, 4)]) == \
-        4 * per_tok + head + 2 * flops.attn_flops_row(D, 0, 4)
+    assert GQA.model_flops(D, [(0, 4)]) == \
+        4 * per_tok + head + 2 * GQA.attn_flops_row(D, 0, 4)
     # chatglm3-6b: 5.6e9 non-embedding matmul weights
-    d = model.dims(model.load("configs", "chatglm3-6b"))
-    assert 5.5e9 < flops.matmul_params(d) < 5.8e9
+    d = GQA.dims(model.load("configs", "chatglm3-6b"))
+    assert 5.5e9 < GQA.matmul_params(d) < 5.8e9
 
 
 def test_least_time_names_its_bound():
@@ -248,11 +267,23 @@ def _tree_digest(root):
     return h.hexdigest()
 
 
+def _greedy(arch, d, params, prompt, n):
+    """``prompt`` and ``n`` tokens that the reference puts first."""
+    toks = list(prompt)
+    for _ in range(n):
+        arg = reference._run(arch, d, False, params,
+                             np.asarray(toks + [0], np.int32), None,
+                             reference.CHUNK)[2]
+        toks.append(int(arg[-1]))
+    return toks
+
+
 def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
     before = _tree_digest(harness.BENCH)
-    for kind in ("configs", "traffic", "metrics"):
+    for kind in ("configs", "traffic", "metrics", "archs"):
         (tmp_path / kind).mkdir()
-    cfg = model.load("configs", "tiny", roots=(DATA,))
+    shutil.copy(GQA.__file__, tmp_path / "archs" / "new-arch.py")
+    cfg = dict(model.load("configs", "tiny", roots=(DATA,)), arch="new-arch")
     (tmp_path / "configs" / "new-model.json").write_text(json.dumps(cfg))
     mix = dict(model.load("traffic", "short-chat"), sessions=3)
     (tmp_path / "traffic" / "new-mix.json").write_text(json.dumps(mix))
@@ -272,6 +303,23 @@ def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
     assert harness.metric_reader("new_metric.x", roots)(None) == 42.0
     assert harness.metric_reader("step_mfu", roots).__module__ \
         .endswith("step_mfu")
+    # the new architecture module is the one the cell runs, and the
+    # reference scores a served sequence through it
+    arch = cell.arch
+    assert arch is not GQA
+    assert arch.__file__ == str(tmp_path / "archs" / "new-arch.py")
+    d = arch.dims(cell.config)
+    params = model.make_params(arch.param_shapes(d), d["dtype"], 3)
+    toks = _greedy(GQA, d, params, [5, 9, 14, 200, 31, 77, 2, 64], 6)
+    served = [False] * 8 + [True] * 6
+    out = reference.compare(arch, d, params, [(toks, served)],
+                            cell.config["serving"]["max_len"])
+    assert out == {"max_logit_gap": 0.0, "served_checked": 6,
+                   "argmax_agreement": 1.0}
+    toks[10] = (toks[10] + 1) % d["vocab_size"]
+    out = reference.compare(arch, d, params, [(toks, served)],
+                            cell.config["serving"]["max_len"])
+    assert out["max_logit_gap"] > 0 and out["argmax_agreement"] < 1
     assert _tree_digest(harness.BENCH) == before
 
 
@@ -287,11 +335,97 @@ def test_every_named_file_of_the_benchmark_exists():
         assert os.path.isfile(os.path.join(REPO, c["file"]))
 
 
+# ------------------------------------------------ architecture modules
+CONFIGS = sorted(glob.glob(os.path.join(harness.BENCH, "configs", "*.json"))
+                 + glob.glob(os.path.join(DATA, "configs", "*.json")))
+
+
+@pytest.mark.parametrize("path", CONFIGS, ids=os.path.basename)
+def test_every_config_names_an_arch_that_matches_the_program(path):
+    """The module exists and defines the contract, and its parameter tree
+    is the program's at the file's own sizes (shapes only, no arrays)."""
+    from repro.models import abstract_params
+    with open(path) as f:
+        config = json.load(f)
+    arch = harness.config_arch(config, path, (DATA, harness.BENCH))
+    assert all(callable(getattr(arch, n)) for n in harness.ARCH_API)
+    d = arch.dims(config)
+    want = jax.tree.map(lambda s: (s.shape, s.dtype),
+                        abstract_params(arch.program_config(config, d)))
+    have = jax.tree.map(lambda s: (s, np.dtype(d["dtype"])),
+                        arch.param_shapes(d),
+                        is_leaf=lambda x: isinstance(x, tuple))
+    assert want == have
+
+
+@pytest.mark.parametrize("arch", [None, "no-such-arch"],
+                         ids=["arch_key_missing", "arch_module_missing"])
+def test_a_config_without_its_arch_module_is_refused(tmp_path, arch):
+    (tmp_path / "configs").mkdir()
+    cfg = model.load("configs", "tiny", roots=(DATA,))
+    cfg.pop("arch")
+    if arch is not None:
+        cfg["arch"] = arch
+    path = tmp_path / "configs" / "lost.json"
+    path.write_text(json.dumps(cfg))
+    spec = {"workloads": [{"name": "lost.short-chat", "config": "lost",
+                           "traffic": "short-chat", "chips": 1, "why": "t"}],
+            "end_to_end": [], "per_layer": []}
+    with pytest.raises(harness.HarnessError, match=re.escape(str(path))):
+        harness.load_cell("lost.short-chat", spec,
+                          roots=(str(tmp_path), harness.BENCH))
+
+
+def _golden():
+    with open(os.path.join(DATA, "gqa_golden.json")) as f:
+        return json.load(f)
+
+
+def test_gqa_weights_are_the_recorded_ones():
+    g = _golden()["weights"]
+    d = GQA.dims(model.load("configs", g["config"], roots=(DATA,)))
+    params = model.make_params(GQA.param_shapes(d), d["dtype"], g["seed"])
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        a = np.asarray(leaf)
+        h.update(f"{jax.tree_util.keystr(path)}:{a.shape}:{a.dtype}"
+                 .encode())
+        h.update(a.tobytes())
+    assert h.hexdigest() == g["sha256"]
+
+
+@pytest.mark.parametrize("control", [False, True], ids=["plain", "control"])
+def test_gqa_reference_gives_the_recorded_logits(control):
+    g = _golden()["reference"]
+    d = GQA.dims(model.load("configs", g["config"], roots=(DATA,)))
+    params = model.make_params(GQA.param_shapes(d), d["dtype"], g["seed"])
+    mx, picked, arg = reference._run(GQA, d, control, params,
+                                     np.asarray(g["tokens"], np.int32),
+                                     None, g["cache_len"])
+    want = g["control" if control else "plain"]
+    np.testing.assert_array_equal(mx, np.float32(want["max_logit"]))
+    np.testing.assert_array_equal(picked, np.float32(want["picked"]))
+    np.testing.assert_array_equal(arg, np.int32(want["argmax"]))
+
+
+def test_gqa_work_counts_are_the_recorded_ones():
+    g = _golden()["work"]
+    d = GQA.dims(model.load("configs", g["config"]))
+    rows = [tuple(r) for r in g["rows"]]
+    counts = flops.counts(GQA)
+    assert list(counts.paged_attn(d, rows)) == g["paged_attn"]
+    assert counts.model_flops(d, rows) == g["model_flops"]
+    assert counts.least_time is flops.least_time
+
+
 @pytest.mark.parametrize("name", ["chatglm3-6b"])
 def test_configs_keep_published_widths(name):
-    d = model.dims(model.load("configs", name))
+    path = model.find("configs", name)
+    config = model.load("configs", name)
+    arch = harness.config_arch(config, path)
+    d = arch.dims(config)
     assert d["head_dim"] == 128 and d["n_heads"] % d["n_kv_heads"] == 0
-    shapes = model.param_shapes(d)
+    shapes = arch.param_shapes(d)
     n = sum(math.prod(s) for s in jax.tree_util.tree_leaves(
         shapes, is_leaf=lambda x: isinstance(x, tuple)))
     want = {"chatglm3-6b": 6.24e9}[name]
