@@ -2,12 +2,14 @@
 
 The served path is the normal one: ``repro.launch.serve.build_backend``
 (paged megastep engine, every bucket compiled) behind ``AgentRM`` and its
-fused MLFQ dispatcher. The clients are one benchmark thread that calls
-``AgentRM.submit``; between the dispatcher and the engine's backend sits
-``ClientSide``, a thin wrapper that timestamps ``begin_turn``, every
-``step`` and ``collect``, sees each output token in the step at which it
-reaches the host, and sets each turn's output length from the traffic
-plan through the backend's ``max_new_tokens`` just before it begins.
+fused MLFQ dispatcher; a cell of N chips runs N one-chip engines behind
+the fleet router (``--fleet N``, member ``i`` on chip ``i``). The clients
+are one benchmark thread that calls ``AgentRM.submit``; between the
+dispatcher and the engines' backend sits ``ClientSide``, a thin wrapper
+that timestamps ``begin_turn``, every ``step`` and ``collect``, sees each
+output token in the step at which it reaches the host, times each
+engine's own step, and sets each turn's output length from the traffic
+plan through the backends' ``max_new_tokens`` just before it begins.
 """
 from __future__ import annotations
 
@@ -146,7 +148,7 @@ def peaks_for(kind: str, path: str = os.path.join(BENCH, "peaks.json")):
 
 
 # ---------------------------------------------------- the program's side
-def serve_args(config: Dict):
+def serve_args(config: Dict, engines: int = 1):
     from repro.launch.serve import build_parser
     s = config["serving"]
     return build_parser().parse_args([
@@ -154,7 +156,14 @@ def serve_args(config: Dict):
         "--token-budget", str(s["token_budget"]),
         "--max-batch", str(s["max_batch"]), "--max-len", str(s["max_len"]),
         "--num-blocks", str(s["num_blocks"]),
-        "--block-size", str(s["block_size"])])
+        "--block-size", str(s["block_size"]), "--fleet", str(engines)])
+
+
+def member_backends(backend) -> List:
+    """The one-engine backends behind ``backend``: a fleet's members in
+    order, or the backend itself."""
+    members = getattr(backend, "members", None)
+    return [m.backend for m in members] if members else [backend]
 
 
 def byte_ids(text: str, vocab: int) -> np.ndarray:
@@ -207,45 +216,35 @@ class Recorder:
 
 
 class ClientSide(SteppableBackend):
-    """The wrapper between the dispatcher and the engine's backend."""
+    """The wrapper between the dispatcher and the engines' backend. What
+    it does not wrap it hands on to the backend, so the dispatcher sees
+    the backend's own surface (a fleet's members and hooks among it).
+    Each engine's backend gets its ``step`` timed, so in a fleet every
+    step record holds one member's own step, not the fleet's."""
 
     def __init__(self, inner, rec: Recorder):
         self.inner = inner
-        self.engine = inner.engine
+        self.parts = member_backends(inner)
+        self.fleet = inner if len(self.parts) > 1 else None
         self.rec = rec
         self.pending: Dict[str, Dict] = {}   # agent -> turn record
         self.live: Dict[int, Dict] = {}      # rid -> turn record
+        for i, b in enumerate(self.parts):
+            b.step = functools.partial(self._engine_step, i, b, b.step)
 
-    @property
-    def obs(self):
-        return self.inner.obs
+    def __getattr__(self, name):
+        if name == "inner" or name.startswith("__"):
+            raise AttributeError(name)
+        return getattr(self.inner, name)
 
-    def begin_turn(self, agent_id, context, prompt):
-        t = self.pending.pop(agent_id)
-        if t.get("begin") is None:
-            t["begin"] = time.perf_counter()
-        self.inner.max_new_tokens = t["n_out"]
-        with self.rec.annotate("bench.begin_turn"):
-            rid = self.inner.begin_turn(agent_id, context, prompt)
-        self.live[rid] = t
-        return rid
-
-    def step(self):
-        eng, rec = self.engine, self.rec
-        rec.maybe_trace(time.perf_counter())
+    def _engine_step(self, i, backend, step):
+        """One engine's step, recorded: its own interval, dispatch width,
+        real tokens, rows ``(cache_len, q)`` and pool bytes."""
+        eng = backend.engine
         disp0, real0 = eng.tokens_dispatched, eng.tokens_real
         t0 = time.perf_counter()
-        with rec.annotate("bench.step"):
-            rep = self.inner.step()
+        rep = step()
         t1 = time.perf_counter()
-        for rid in set(rep.serviced) | set(rep.finished):
-            t = self.live.get(rid)
-            req = eng.reqs.get(rid)
-            if t is None or req is None:
-                continue
-            new = req.out_tokens[len(t["out_ids"]):]
-            t["out_ids"] += [int(x) for x in new]
-            t["tok_t"] += [t1] * len(new)
         width = (eng.tokens_dispatched - disp0) // eng.max_batch
         if width:
             rows = []
@@ -253,12 +252,59 @@ class ClientSide(SteppableBackend):
                 req = eng.reqs.get(rid)
                 if req is not None and req.table is not None:
                     rows.append((req.num_tokens - T, T))
-            rec.steps.append({
-                "t0": t0, "t1": t1, "C": width,
-                "real": eng.tokens_real - real0, "rows": rows,
-                "kv_used": eng.cache.bytes_in_use,
-                "kv_total": eng.cache.bytes_total})
+            s = {"t0": t0, "t1": t1, "C": width,
+                 "real": eng.tokens_real - real0, "rows": rows,
+                 "kv_used": eng.cache.bytes_in_use,
+                 "kv_total": eng.cache.bytes_total}
+            if self.fleet is not None:
+                s["member"] = i
+            self.rec.steps.append(s)
         return rep
+
+    def _request(self, rid, agent):
+        """The engine request behind ``rid``. A fleet's rids are its own,
+        so there the member that holds the agent's session is asked."""
+        if self.fleet is None:
+            return self.parts[0].engine.reqs.get(rid)
+        for b in self.parts:
+            r = b.sessions.get(agent)
+            if r is not None:
+                return b.engine.reqs.get(r)
+        return None
+
+    def begin_turn(self, agent_id, context, prompt):
+        t = self.pending.pop(agent_id)
+        if t.get("begin") is None:
+            t["begin"] = time.perf_counter()
+        for b in self.parts:
+            b.max_new_tokens = t["n_out"]
+        with self.rec.annotate("bench.begin_turn"):
+            rid = self.inner.begin_turn(agent_id, context, prompt)
+        self.live[rid] = t
+        return rid
+
+    def step(self):
+        self.rec.maybe_trace(time.perf_counter())
+        with self.rec.annotate("bench.step"):
+            rep = self.inner.step()
+        t1 = time.perf_counter()
+        for rid in set(rep.serviced) | set(rep.finished):
+            t = self.live.get(rid)
+            req = None if t is None else self._request(rid, t["agent"])
+            if req is None:
+                continue
+            new = req.out_tokens[len(t["out_ids"]):]
+            t["out_ids"] += [int(x) for x in new]
+            t["tok_t"] += [t1] * len(new)
+        return rep
+
+    def rebalance_for_admission(self, agent_id, prompt):
+        return self.inner.rebalance_for_admission(agent_id, prompt)
+
+    def placement(self) -> Dict[str, int]:
+        """agent -> the fleet member that holds its session."""
+        return {a: m.idx for m in self.fleet.members if m.alive
+                for a in m.backend.sessions}
 
     def collect(self, rid):
         with self.rec.annotate("bench.collect"):
@@ -285,9 +331,6 @@ class ClientSide(SteppableBackend):
     def abort_turn(self, rid):
         self.inner.abort_turn(rid)
 
-    def rebuild(self):
-        return self.inner.rebuild()
-
     def session_busy(self, agent_id):
         return self.inner.session_busy(agent_id)
 
@@ -296,15 +339,6 @@ class ClientSide(SteppableBackend):
 
     def victim_parkable(self, rid):
         return self.inner.victim_parkable(rid)
-
-    def hibernate_session(self, agent_id):
-        self.inner.hibernate_session(agent_id)
-
-    def wake_session(self, agent_id):
-        self.inner.wake_session(agent_id)
-
-    def idle_sessions(self):
-        return self.inner.idle_sessions()
 
 
 # ---------------------------------------------------------- the clients
@@ -317,7 +351,7 @@ class Clients:
         self.rm, self.wrap, self.mix, self.vocab = rm, wrap, cell.mix, vocab
         self.slots = traffic.permuted(traffic.plan_slots(cell.mix), seed)
         self.rng = random.Random(f"{seed}:text")
-        self.system = traffic.text(self.rng, cell.mix["system_prompt_tokens"])
+        self.systems = traffic.system_prompts(self.rng, cell.mix)
         self.sessions: Dict[str, List[Dict]] = {}
         self.state = [{"idx": 0, "gen": 0, "len": 0, "agent": None,
                        "handle": None, "turn": None, "due": None}
@@ -333,7 +367,8 @@ class Clients:
             st["len"] = 0
             self.sessions[st["agent"]] = []
         body = traffic.text(self.rng, n_in)
-        prompt = self.system + body if fresh else body
+        system = self.systems[traffic.tenant_of(i, self.mix)]
+        prompt = system + body if fresh else body
         t = {"agent": st["agent"], "slot": i, "n_in": len(prompt),
              "n_out": n_out, "due": due, "tok_t": [], "out_ids": [],
              "prompt_ids": byte_ids(prompt, self.vocab), "parks": 0}
@@ -365,10 +400,12 @@ class Clients:
 
     def setup(self, timeout: float = 900.0):
         """Every session's first prefill: the system prompt plus its
-        history, one token out. Slot 0 goes first, so the system prompt is
-        computed once and the others adopt it from the prefix index."""
+        history, one token out. One slot of each tenant goes first, so
+        each system prompt is computed once and the others adopt it from
+        the prefix index."""
         hist = [s.history for s in self.slots]
-        order = [[0], list(range(1, len(self.slots)))]
+        n = min(traffic.tenants(self.mix), len(self.slots))
+        order = [list(range(n)), list(range(n, len(self.slots)))]
         deadline = time.perf_counter() + timeout
         for group in order:
             for i in group:
@@ -405,6 +442,13 @@ class Clients:
             nxt = min([st["due"] for st in self.state
                        if st["handle"] is None] + [w1])
             time.sleep(min(max(nxt - time.perf_counter(), 0.0), 0.005))
+
+    def placement(self) -> List[tuple]:
+        """(tenant, member) of every slot's current session that the
+        engines hold."""
+        where = self.wrap.placement()
+        return [(traffic.tenant_of(i, self.mix), where[st["agent"]])
+                for i, st in enumerate(self.state) if st["agent"] in where]
 
 
 # ------------------------------------------------------------- the check
@@ -502,17 +546,22 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace_on: bool, *,
         raise HarnessError("the benchmark's weights do not match the "
                            "program's parameter tree")
     t = time.perf_counter()
-    engine, backend = build_backend(cfg, params, serve_args(config),
-                                    obs=Observability())
+    _, backend = build_backend(cfg, params,
+                               serve_args(config, cell.chips),
+                               obs=Observability())
     phases["compile_s"] = time.perf_counter() - t
+    parts = member_backends(backend)
     if break_path is not None:
-        break_path(engine)
-    backend.prompt_tokens = config["serving"]["max_len"]
+        for b in parts:
+            break_path(b.engine)
+    for b in parts:
+        b.prompt_tokens = config["serving"]["max_len"]
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace_on else None
     rec = Recorder(trace_dir)
     wrap = ClientSide(backend, rec)
-    rm = AgentRM(wrap, AgentRMConfig(lanes=config["serving"]["max_batch"],
-                                     detect_after_s=120.0))
+    rm = AgentRM(wrap, AgentRMConfig(
+        lanes=config["serving"]["max_batch"] * cell.chips,
+        detect_after_s=120.0))
     clients = Clients(rm, wrap, cell, seed, d["vocab_size"])
     try:
         t = time.perf_counter()
@@ -523,6 +572,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace_on: bool, *,
         w1 = w0 + seconds
         rec.w0, rec.w1 = w0, w1
         clients.run(w0, w1)
+        placement = clients.placement() if wrap.fleet is not None else []
     finally:
         rm.shutdown()
         for th in (rm._dispatcher, rm._reaper):
@@ -530,12 +580,18 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace_on: bool, *,
         rec.stop_trace()
     setup_s = w0 - t_start
     in_window = sum(1 for c in compiles if w0 <= c <= w1)
-    mem = dev.memory_stats() or {}
-    peak = mem.get("peak_bytes_in_use")
-    st = engine.step_stats()
-    kv = engine.kv_stats()
-    bucket_compile = dict(engine.compile_seconds)
-    del rm, wrap, backend, engine, clients.rm, clients.wrap
+    mems = [x.memory_stats() or {} for x in devices[:cell.chips]]
+    peak = max((m["peak_bytes_in_use"] for m in mems
+                if "peak_bytes_in_use" in m), default=None)
+    engines = [b.engine for b in parts]
+    moves = ({k: v for k, v in backend.fleet_stats().items()
+              if "migrations" in k} if cell.chips > 1 else None)
+    st = [e.step_stats() for e in engines]
+    kv = [e.kv_stats() for e in engines]
+    bucket_compile = (dict(engines[0].compile_seconds) if len(engines) == 1
+                      else {f"{e.name}/{k}": v for e in engines
+                            for k, v in e.compile_seconds.items()})
+    del rm, wrap, backend, parts, engines, clients.rm, clients.wrap
     gc.collect()
 
     turns = rec.turns[setup_turns:]
@@ -560,46 +616,58 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace_on: bool, *,
     correct = all((c["value"] <= c["limit"]) if c["rule"] == "<=" else
                   (c["value"] >= c["limit"]) for c in compared.values())
 
+    def each(xs, key):
+        """One member's value, or the list of every member's."""
+        vals = [x.get(key) for x in xs]
+        return vals[0] if len(vals) == 1 else vals
+
     report = {
         "setup": {**phases, "setup_s": setup_s},
         "samples": e2e["samples"], "window_s": w1 - w0,
         "ttft_p95_s": e2e["ttft_p95_s"],
         "setup_turns": setup_turns,
-        "padded_token_fraction": st["padded_token_fraction"],
-        "trace_buckets": st["trace_buckets"],
+        "padded_token_fraction": each(st, "padded_token_fraction"),
+        "trace_buckets": each(st, "trace_buckets"),
         "generator_lateness_s": {
             "p50": stats.quantile(clients.lateness, 0.5),
             "max": max(clients.lateness) if clients.lateness else None},
-        "peak_bytes_in_use": peak, "bytes_limit": mem.get("bytes_limit"),
+        "peak_bytes_in_use": each(mems, "peak_bytes_in_use"),
+        "bytes_limit": mems[0].get("bytes_limit"),
         "compiles_in_window": in_window,
-        "kv_blocks_in_use": kv["blocks_in_use"],
-        "kv_blocks_total": kv["blocks_total"],
-        "prefix_hits": kv["prefix_hits"],
-        "swap_bytes_out": kv.get("swap_bytes_out"),
+        "kv_blocks_in_use": each(kv, "blocks_in_use"),
+        "kv_blocks_total": each(kv, "blocks_total"),
+        "prefix_hits": each(kv, "prefix_hits"),
+        "swap_bytes_out": each(kv, "swap_bytes_out"),
         "reference_s": ref_s, "argmax_agreement": chk["argmax_agreement"],
         "sessions_checked": len(seqs),
     }
+    if cell.chips > 1:
+        report["sessions_by_member"] = [
+            sum(1 for _, m in placement if m == i)
+            for i in range(cell.chips)]
+        report["fleet_migrations"] = moves
     log("report " + json.dumps(report))
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices), "memory_peak_bytes": peak}
     metrics: Dict[str, Dict] = {}
     breakdown = None
     if trace_on:
-        events = trace.extract(trace_dir)
+        events = trace.extract(trace_dir, cell.chips)
         shutil.rmtree(trace_dir, ignore_errors=True)
         lo_hi = trace.window(events)
         lo, hi = lo_hi if lo_hi else (0.0, 0.0)
-        device["busy_s"] = trace.busy_ns(events, lo, hi) * 1e-9
+        device["busy_s"] = trace.busy_ns(events, lo, hi) * 1e-9 / cell.chips
         device["window_s"] = (hi - lo) * 1e-9
         breakdown = {"device_ops": trace.top_ops(events, lo, hi),
                      "idle_gaps": trace.idle_gaps(events, lo, hi)}
         h0, h1 = rec.traced or (w0, w1)
         ctx = SimpleNamespace(
             dims=d, peaks=peaks, flops=flops.counts(arch), stats=stats,
-            trace=trace,
+            trace=trace, chips=cell.chips, members=cell.chips,
             events=events, trace_window=(lo, hi), window=(h0, h1),
             steps=[s for s in rec.steps if s["t0"] >= h0 and s["t1"] <= h1],
-            turns=turns, compile_seconds=bucket_compile, phases=phases)
+            turns=turns, compile_seconds=bucket_compile, phases=phases,
+            placement=placement)
         if peaks is not None:
             bound = [flops.least_time(*arch.paged_attn(d, s["rows"]),
                                       peaks)[1] for s in ctx.steps]

@@ -1,4 +1,5 @@
-"""Share of the device's busy time spent in the paged-attention kernel."""
+"""Share of the device's busy time spent in the paged-attention kernel,
+both summed over the cell's chips."""
 from bench.kernels import is_paged_attn
 
 

@@ -1,8 +1,9 @@
 """The paged-attention kernel's share of its roofline, in percent: the
-least time its work could take on this chip (the larger of FLOPs over the
+least time its work could take on one chip (the larger of FLOPs over the
 bf16 peak and bytes over the HBM bandwidth, per step, with the work
-counted from the live shapes by ``bench/flops.py``), over the kernel's
-device time in the trace. Nothing when the trace has no kernel time."""
+counted from the live shapes by the architecture module's ``paged_attn``),
+summed over every engine's steps, over the kernel's device time in the
+trace summed over the chips. Nothing when the trace has no kernel time."""
 from bench.kernels import is_paged_attn
 
 

@@ -2,9 +2,12 @@
 
 ``extract`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote and keeps
 two flat lists, in nanoseconds on the profiler's clock: the operations
-that ran on the first TPU (its ``XLA Ops`` line) and the benchmark's own
-host annotations (``bench.*``). Everything after that is plain arithmetic
-on those lists, which is what the tests pin on a small recorded trace.
+that ran on the cell's TPUs (each one's ``XLA Ops`` line), each tagged by
+its chip, and the benchmark's own host annotations (``bench.*``).
+Everything after that is plain arithmetic on those lists, which is what
+the tests pin on small recorded traces. Busy time and kernel time are
+sums over the chips, each chip's busy time the union of its own
+operations; an operation without a chip tag is chip 0's.
 """
 from __future__ import annotations
 
@@ -17,9 +20,10 @@ WINDOW = "bench.window"
 OPS_LINE = "XLA Ops"
 
 
-def extract(trace_dir: str) -> Dict:
-    """{"device": [[name, start_ns, dur_ns, hlo_op_long_name], ...],
-    "host": [[name, start_ns, dur_ns], ...]} of the newest trace."""
+def extract(trace_dir: str, chips: int = 1) -> Dict:
+    """{"device": [[name, start_ns, dur_ns, hlo_op_long_name, chip], ...],
+    "host": [[name, start_ns, dur_ns], ...]} of the newest trace, with the
+    operations of its first ``chips`` TPUs."""
     from jax.profiler import ProfileData
 
     paths = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile",
@@ -30,7 +34,7 @@ def extract(trace_dir: str) -> Dict:
     device, host = [], []
     tpus = sorted((p for p in pd.planes if p.name.startswith("/device:TPU:")),
                   key=lambda p: p.name)
-    for plane in tpus[:1]:
+    for chip, plane in enumerate(tpus[:chips]):
         for line in plane.lines:
             if line.name != OPS_LINE:
                 continue
@@ -40,7 +44,7 @@ def extract(trace_dir: str) -> Dict:
                 # instruction's own name, the part before " = "
                 device.append([ev.name.split(" = ")[0].lstrip("%"),
                                float(ev.start_ns), float(ev.duration_ns),
-                               str(stats.get("long_name", ""))])
+                               str(stats.get("long_name", "")), chip])
     for plane in pd.planes:
         if plane.name.startswith("/device:"):
             continue
@@ -59,13 +63,25 @@ def extract(trace_dir: str) -> Dict:
     return {"device": device, "host": host}
 
 
+def by_chip(events: Dict) -> Dict[int, Dict]:
+    """The events with each chip's operations alone, by chip."""
+    chips: Dict[int, List] = defaultdict(list)
+    for e in events["device"]:
+        chips[e[4] if len(e) > 4 else 0].append(e)
+    return {c: {"device": ops, "host": events.get("host", [])}
+            for c, ops in sorted(chips.items())}
+
+
 def leaves(events: Dict) -> List:
-    """Device operations that contain no other: a loop (the layer scan's
-    ``while``) is listed beside the operations it runs, so only the
-    innermost ones are counted by name."""
-    dev = sorted(events["device"], key=lambda e: (e[1], -e[2]))
-    return [e for e, nxt in zip(dev, dev[1:] + [None])
-            if nxt is None or nxt[1] >= e[1] + e[2]]
+    """Device operations that contain no other on their chip: a loop (the
+    layer scan's ``while``) is listed beside the operations it runs, so
+    only the innermost ones are counted by name."""
+    out = []
+    for one in by_chip(events).values():
+        dev = sorted(one["device"], key=lambda e: (e[1], -e[2]))
+        out += [e for e, nxt in zip(dev, dev[1:] + [None])
+                if nxt is None or nxt[1] >= e[1] + e[2]]
+    return sorted(out, key=lambda e: (e[1], -e[2]))
 
 
 def window(events: Dict) -> Optional[Tuple[float, float]]:
@@ -84,7 +100,8 @@ def _clip(events, lo: float, hi: float):
 
 
 def busy_intervals(events: Dict, lo: float, hi: float) -> List[List[float]]:
-    """Union of the device operations' intervals inside [lo, hi]."""
+    """Union of the device operations' intervals inside [lo, hi], of one
+    chip's events."""
     out: List[List[float]] = []
     for _, s, t in sorted(_clip(events["device"], lo, hi),
                           key=lambda x: x[1]):
@@ -96,7 +113,9 @@ def busy_intervals(events: Dict, lo: float, hi: float) -> List[List[float]]:
 
 
 def busy_ns(events: Dict, lo: float, hi: float) -> float:
-    return sum(t - s for s, t in busy_intervals(events, lo, hi))
+    """Busy time inside [lo, hi], summed over the chips."""
+    return sum(sum(t - s for s, t in busy_intervals(one, lo, hi))
+               for one in by_chip(events).values())
 
 
 def op_ns(events: Dict, lo: float, hi: float, match) -> float:
@@ -119,16 +138,21 @@ def top_ops(events: Dict, lo: float, hi: float, n: int = 10):
 def idle_gaps(events: Dict, lo: float, hi: float, n: int = 10):
     """The longest idle gaps of the device inside [lo, hi], in seconds,
     each named by the innermost benchmark annotation open at its middle
-    (``host`` when none is: the dispatcher between calls)."""
-    busy = busy_intervals(events, lo, hi)
-    edges = [lo] + [x for iv in busy for x in iv] + [hi]
-    gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
-            if edges[i + 1] > edges[i]]
+    (``host`` when none is: the dispatcher between calls). With several
+    chips, each chip's gaps, the name followed by `` @chip<c>``."""
+    chips = by_chip(events) or {0: events}
+    gaps = []
+    for c, one in chips.items():
+        busy = busy_intervals(one, lo, hi)
+        edges = [lo] + [x for iv in busy for x in iv] + [hi]
+        gaps += [(edges[i], edges[i + 1], c)
+                 for i in range(0, len(edges), 2) if edges[i + 1] > edges[i]]
     spans = [h for h in events["host"] if h[0] != WINDOW]
     out = []
-    for s, t in sorted(gaps, key=lambda g: g[0] - g[1])[:n]:
+    for s, t, c in sorted(gaps, key=lambda g: g[0] - g[1])[:n]:
         mid = 0.5 * (s + t)
         open_ = [h for h in spans if h[1] <= mid <= h[1] + h[2]]
         name = max(open_, key=lambda h: h[1])[0] if open_ else "host"
-        out.append([name, (t - s) * 1e-9])
+        out.append([name if len(chips) == 1 else f"{name} @chip{c}",
+                    (t - s) * 1e-9])
     return out

@@ -1,10 +1,13 @@
 """The one traffic generator: a mix file of parameters in, a session plan out.
 
 A mix (``bench/traffic/<name>.json``) describes closed-loop agent sessions:
-how many, how long their shared system prompt is, how much history each
-brings, the shape of a turn (tokens sent, tokens generated), the think
-time between a reply and the next turn, and the history length at which a
-session ends and a fresh one starts from the system prompt.
+how many, how long their system prompt is, how much history each brings,
+the shape of a turn (tokens sent, tokens generated), the think time
+between a reply and the next turn, and the history length at which a
+session ends and a fresh one starts from the system prompt. With
+``"tenants": N`` there are N system prompts, one per tenant, and session
+slot ``i`` belongs to tenant ``i mod N``; without it every session shares
+one.
 
 Every length and think time comes from the mix's own ``size_seed``, so the
 work is the same for every run seed: the run seed only permutes which
@@ -93,6 +96,22 @@ def permuted(slots: List[Slot], seed: int) -> List[Slot]:
     order = list(range(len(slots)))
     random.Random(seed).shuffle(order)
     return [slots[i] for i in order]
+
+
+def tenants(mix: Dict) -> int:
+    return int(mix.get("tenants", 1))
+
+
+def tenant_of(slot: int, mix: Dict) -> int:
+    """The tenant of session slot ``slot``: round-robin over the slots."""
+    return slot % tenants(mix)
+
+
+def system_prompts(rng: random.Random, mix: Dict) -> List[str]:
+    """One system prompt per tenant, drawn in turn from ``rng``; the first
+    is the one prompt of a mix without tenants."""
+    return [text(rng, mix["system_prompt_tokens"])
+            for _ in range(tenants(mix))]
 
 
 def text(rng: random.Random, n: int) -> str:

@@ -10,6 +10,7 @@ import os
 import re
 import shutil
 import sys
+import time
 from types import SimpleNamespace
 
 import jax
@@ -84,9 +85,120 @@ def test_every_session_gets_identical_system_prompt_bytes():
     assert len({p[:n] for p in sent}) == 1
     assert all(len(p) == n + s.history for p, s in zip(sent, c.slots))
     c2 = harness.Clients(FakeRM(), FakeWrap(), cell, seed=5, vocab=65024)
-    assert c2.system == c.system
+    assert c2.systems == c.systems and len(c.systems) == 1
     assert harness.Clients(FakeRM(), FakeWrap(), cell, seed=6,
-                           vocab=65024).system != c.system
+                           vocab=65024).systems != c.systems
+
+
+def _sent(mix_name, seed):
+    """Every prompt the clients send for ``mix_name`` and ``seed``: each
+    session's first prefill, then each one's first turn."""
+    cell = harness.Cell("x", {}, model.load("traffic", mix_name), 1,
+                        [], [], (harness.BENCH,))
+    sent = []
+
+    class FakeRM:
+        def submit(self, agent, prompt):
+            sent.append((agent, prompt))
+
+    class FakeWrap:
+        pending = {}
+        rec = harness.Recorder(None)
+
+    c = harness.Clients(FakeRM(), FakeWrap(), cell, seed=seed, vocab=65024)
+    for i in range(len(c.slots)):
+        c._send(i, c.slots[i].history, 1, 0.0, fresh=True)
+    for i in range(len(c.slots)):
+        t = c.slots[i].turns[0]
+        c._send(i, t.n_in, t.n_out, 0.0, fresh=False)
+    return c, sent
+
+
+# digests of each plan and of every prompt sent, recorded before mixes
+# could name tenants
+TRAFFIC_GOLDEN = {
+    ("agent-tools", 5):
+        "2cf7e7f18649492e6354676c80b38712c22766b30c7e07070c3715a51977494c",
+    ("agent-tools", 2**31 + 12345):
+        "23ab794135e7908ac8680e673ad85802811f3091b23f9111401c4bb5d78756b9",
+    ("short-chat", 5):
+        "b2d083a8b463b4360b8726c2883dbad56de5149c8b19ffd5b921a4c5b1179385",
+    ("short-chat", 2**31 + 12345):
+        "fc6776a45012db212d25956dfb6a83a60233a5b17db8b574da4d952e65690375",
+}
+
+
+@pytest.mark.parametrize("mix,seed", sorted(TRAFFIC_GOLDEN))
+def test_a_mix_without_tenants_sends_the_recorded_traffic(mix, seed):
+    c, sent = _sent(mix, seed)
+    h = hashlib.sha256()
+    h.update(json.dumps([[s.history, [[t.n_in, t.n_out, repr(t.think_s)]
+                                      for t in s.turns]]
+                         for s in c.slots]).encode())
+    h.update(json.dumps(sent).encode())
+    assert h.hexdigest() == TRAFFIC_GOLDEN[mix, seed]
+
+
+def test_tenants_have_distinct_prompts_and_take_slots_round_robin():
+    c, sent = _sent("fleet4-tenants", 2**31 + 99)
+    m = c.mix
+    n, k = m["system_prompt_tokens"], m["tenants"]
+    assert len(c.systems) == k == 4
+    assert len(set(c.systems)) == k
+    assert all(len(p) == n for p in c.systems)
+    first = sent[:m["sessions"]]
+    for i, (agent, prompt) in enumerate(first):
+        assert agent.startswith(f"slot{i}-")
+        assert prompt[:n] == c.systems[i % k]
+        assert len(prompt) == n + c.slots[i].history
+    # the first tenant's prompt is the one prompt of a mix without tenants
+    shared, _ = _sent("fleet4-shared", 2**31 + 99)
+    assert shared.systems == c.systems[:1]
+    assert [s.history for s in c.slots] == [s.history for s in shared.slots]
+
+
+def test_set_up_prefills_one_slot_of_each_tenant_first():
+    """The others are sent once those have finished, so each tenant's
+    prompt is computed once and adopted from the prefix index."""
+    events = []
+
+    class Done:
+        def __init__(self, agent):
+            self.agent = agent
+
+        def result(self, timeout=None):
+            events.append(("done", self.agent))
+            return "tok:1"
+
+    class FakeRM:
+        def submit(self, agent, prompt):
+            events.append(("sent", agent))
+            return Done(agent)
+
+    class FakeWrap:
+        pending = {}
+        rec = harness.Recorder(None)
+
+    cell = harness.Cell("x", {}, model.load("traffic", "fleet4-tenants"), 4,
+                        [], [], (harness.BENCH,))
+    c = harness.Clients(FakeRM(), FakeWrap(), cell, seed=3, vocab=65024)
+    c.setup()
+    sent = [a for e, a in events if e == "sent"]
+    first_rest = events.index(("sent", sent[4]))
+    assert sent[:4] == [f"slot{i}-session0" for i in range(4)]
+    assert {a for e, a in events[:first_rest] if e == "done"} == set(sent[:4])
+    assert len(sent) == 64
+
+
+def test_the_fleet_mixes_send_the_same_work():
+    a = model.load("traffic", "fleet4-tenants")
+    b = model.load("traffic", "fleet4-shared")
+    assert {k: v for k, v in a.items() if k not in ("about", "assumed",
+                                                    "tenants")} == \
+        {k: v for k, v in b.items() if k not in ("about", "assumed",
+                                                 "tenants")}
+    assert traffic.plan_slots(a) == traffic.plan_slots(b)
+    assert (a["tenants"], b["tenants"]) == (4, 1)
 
 
 def test_byte_tokens_are_one_per_byte():
@@ -234,6 +346,108 @@ def test_trace_reduction_on_a_recorded_trace():
     assert k == pytest.approx(0.963288918e9, rel=1e-9)
 
 
+def _one_chip_ctx(ev, steps, chips=1, members=1):
+    lo, hi = trace.window(ev)
+    d = GQA.dims(model.load("configs", "chatglm3-6b"))
+    return SimpleNamespace(
+        dims=d, peaks=harness.peaks_for("TPU v5 lite"),
+        flops=flops.counts(GQA), stats=stats, trace=trace, events=ev,
+        trace_window=(lo, hi), window=(0.0, 1.0), steps=steps, turns=[],
+        compile_seconds={}, phases={}, chips=chips, members=members,
+        placement=[])
+
+
+def _recorded_readers():
+    with open(os.path.join(DATA, "one_chip_readers.json")) as f:
+        return json.load(f)
+
+
+DEVICE_READERS = ["device_idle_frac", "paged_attn.busy_frac",
+                  "paged_attn_roofline", "step_mfu", "kv_in_use_frac"]
+
+
+@pytest.mark.parametrize("tagged", [False, True],
+                         ids=["untagged", "tagged_chip0"])
+def test_one_chip_readers_read_the_recorded_values(tagged):
+    """On the recorded one-chip trace every reader returns, to the bit,
+    the value it returned before the trace kept several chips; tagging
+    each op with chip 0, as ``extract`` now does, changes nothing."""
+    g = _recorded_readers()
+    ev = _trace()
+    if tagged:
+        ev = {"device": [e + [0] for e in ev["device"]], "host": ev["host"]}
+    steps = [dict(s, rows=[tuple(r) for r in s["rows"]]) for s in g["steps"]]
+    ctx = _one_chip_ctx(ev, steps)
+    for name in DEVICE_READERS:
+        assert harness.metric_reader(name, (harness.BENCH,))(ctx) == \
+            g["readers"][name], name
+    lo, hi = trace.window(ev)
+    assert trace.busy_ns(ev, lo, hi) == g["busy_ns"]
+    assert trace.top_ops(ev, lo, hi) == g["top_ops"]
+    assert trace.idle_gaps(ev, lo, hi) == g["idle_gaps"]
+
+
+def _two_chips(ev, shift_ns):
+    """The recorded chip as chip 0 and a copy of it, ``shift_ns`` later,
+    as chip 1."""
+    dev = [e + [0] for e in ev["device"]]
+    dev += [[e[0], e[1] + shift_ns, e[2], e[3], 1] for e in ev["device"]]
+    return {"device": dev, "host": ev["host"]}
+
+
+@pytest.mark.parametrize("shift_ns", [0.0, 37e6], ids=["aligned", "shifted"])
+def test_two_chip_trace_sums_each_chips_own_busy_and_kernel_time(shift_ns):
+    one = _trace()
+    two = _two_chips(one, shift_ns)
+    lo, hi = trace.window(one)
+    moved = {"device": [[e[0], e[1] + shift_ns, e[2], e[3]]
+                        for e in one["device"]], "host": one["host"]}
+    assert trace.busy_ns(two, lo, hi) == \
+        trace.busy_ns(one, lo, hi) + trace.busy_ns(moved, lo, hi)
+    assert len(trace.leaves(two)) == 2 * len(trace.leaves(one))
+    from bench.kernels import is_paged_attn
+    assert trace.op_ns(two, lo, hi, is_paged_attn) == pytest.approx(
+        trace.op_ns(one, lo, hi, is_paged_attn)
+        + trace.op_ns(moved, lo, hi, is_paged_attn), rel=1e-12)
+    gaps = trace.idle_gaps(two, lo, hi, n=10**6)
+    assert sum(g for _, g in gaps) * 1e9 == pytest.approx(
+        2 * (hi - lo) - trace.busy_ns(two, lo, hi), rel=1e-9)
+    assert {g[0].rsplit(" @", 1)[1] for g in gaps} == {"chip0", "chip1"}
+
+
+def test_two_chip_readers_average_the_chips_and_sum_the_work():
+    """Two chips that each ran the recorded step, each engine serving the
+    same rows: every share reads as one chip's does."""
+    g = _recorded_readers()
+    steps = [dict(s, rows=[tuple(r) for r in s["rows"]]) for s in g["steps"]]
+    two = _two_chips(_trace(), 0.0)
+    ctx = _one_chip_ctx(two, [dict(s, member=m) for m in (0, 1)
+                              for s in steps], chips=2, members=2)
+    for name in DEVICE_READERS:
+        assert harness.metric_reader(name, (harness.BENCH,))(ctx) == \
+            pytest.approx(g["readers"][name], rel=1e-12), name
+    # read as one chip, the same events would count the busy time twice
+    ctx.chips = 1
+    idle = harness.metric_reader("device_idle_frac", (harness.BENCH,))(ctx)
+    assert idle < 0
+
+
+def test_fleet_readers():
+    tok = harness.metric_reader("fleet.tokens_imbalance", (harness.BENCH,))
+    col = harness.metric_reader("fleet.tenant_colocation", (harness.BENCH,))
+    steps = [{"member": 0, "real": 30}, {"member": 1, "real": 10},
+             {"member": 0, "real": 20}, {"member": 3, "real": 20}]
+    ctx = SimpleNamespace(members=4, steps=steps, placement=[
+        (0, 0), (0, 0), (0, 1), (1, 2), (1, 2), (2, 3)])
+    assert tok(ctx) == 50 / 20            # member 2 served nothing
+    assert col(ctx) == (2 + 2 + 1) / 6
+    ctx.placement = [(t, t) for t in range(4) for _ in range(16)]
+    assert col(ctx) == 1.0
+    one = SimpleNamespace(members=1, steps=[{"real": 5}], placement=[(0, 0)])
+    assert tok(one) is None and col(one) is None
+    assert tok(SimpleNamespace(members=2, steps=[], placement=[])) is None
+
+
 def test_idle_gaps_are_named_by_the_open_annotation():
     ev = {"device": [["a", 0, 10, ""], ["b", 35, 10, ""], ["c", 60, 40, ""]],
           "host": [[trace.WINDOW, 0, 100], ["bench.step", 0, 45],
@@ -321,6 +535,109 @@ def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
                             cell.config["serving"]["max_len"])
     assert out["max_logit_gap"] > 0 and out["argmax_agreement"] < 1
     assert _tree_digest(harness.BENCH) == before
+
+
+@pytest.mark.parametrize("chips", [1, 2, 4])
+def test_a_cell_runs_one_engine_per_chip(chips):
+    spec = {"workloads": [{"name": "tiny.x", "config": "tiny",
+                           "traffic": "tiny-fleet", "chips": chips,
+                           "why": "t"}],
+            "end_to_end": [], "per_layer": []}
+    cell = harness.load_cell("tiny.x", spec, roots=(DATA, harness.BENCH))
+    args = harness.serve_args(cell.config, cell.chips)
+    assert cell.chips == args.fleet == chips
+
+
+def test_each_fleet_member_records_its_own_step():
+    """A fleet steps its members one after another: every step record
+    holds one member's own interval and work, and the tokens reach each
+    turn through the member that holds its session."""
+    class Engine:
+        max_batch = 2
+
+        def __init__(self, work_s):
+            self.work_s, self.tokens_dispatched, self.tokens_real = \
+                work_s, 0, 0
+            self.reqs = {7: SimpleNamespace(out_tokens=[], table=[0],
+                                            num_tokens=10)}
+            self.last_serviced = {}
+            self.cache = SimpleNamespace(bytes_in_use=3, bytes_total=8)
+
+    class Backend:
+        def __init__(self, work_s, agent):
+            self.engine, self.sessions = Engine(work_s), {agent: 7}
+
+        def step(self):
+            eng = self.engine
+            time.sleep(eng.work_s)
+            eng.tokens_dispatched += eng.max_batch
+            eng.tokens_real += 1
+            eng.last_serviced = {7: 1}
+            eng.reqs[7].out_tokens.append(len(eng.reqs[7].out_tokens))
+            eng.reqs[7].num_tokens += 1
+            return SimpleNamespace(serviced={7: 1}, finished=[])
+
+    class Fleet:
+        def __init__(self):
+            self.members = [SimpleNamespace(idx=i, alive=True, backend=b)
+                            for i, b in enumerate(
+                                [Backend(0.0, "a"), Backend(0.05, "b")])]
+
+        def step(self):
+            for ext, m in zip((101, 102), self.members):
+                m.backend.step()
+            return SimpleNamespace(serviced={101: 1, 102: 1}, finished=[])
+
+    fleet = Fleet()
+    rec = harness.Recorder(None)
+    wrap = harness.ClientSide(fleet, rec)
+    turns = {101: {"agent": "a", "out_ids": [], "tok_t": []},
+             102: {"agent": "b", "out_ids": [], "tok_t": []}}
+    wrap.live.update(turns)
+    for _ in range(2):
+        wrap.step()
+    assert [s["member"] for s in rec.steps] == [0, 1, 0, 1]
+    fast, slow = rec.steps[2], rec.steps[3]
+    assert fast["t1"] <= slow["t0"]
+    assert fast["t1"] - fast["t0"] < 0.04 <= slow["t1"] - slow["t0"]
+    assert slow == {**slow, "C": 1, "real": 1, "rows": [(11, 1)],
+                    "kv_used": 3, "kv_total": 8}
+    assert [t["out_ids"] for t in turns.values()] == [[0, 1], [0, 1]]
+    assert wrap.placement() == {"a": 0, "b": 1}
+
+
+def test_the_client_side_hands_on_the_fleets_own_surface():
+    """The dispatcher finds what it finds on the fleet itself: its
+    members and rebalance hook, and no ``idle_sessions`` where the fleet
+    has none."""
+    class Engine:
+        reqs = {}
+
+    class Member:
+        def __init__(self, i):
+            self.idx, self.backend = i, SimpleNamespace(
+                engine=Engine(), sessions={}, step=lambda: None)
+
+    class Fleet:
+        obs = None
+        members = [Member(0), Member(1)]
+
+        def rebalance_for_admission(self, agent_id, prompt):
+            return (agent_id, prompt)
+
+    fleet = Fleet()
+    wrap = harness.ClientSide(fleet, harness.Recorder(None))
+    assert wrap.members is fleet.members and wrap.fleet is fleet
+    assert [b.engine for b in wrap.parts] == \
+        [m.backend.engine for m in fleet.members]
+    assert getattr(wrap, "idle_sessions", None) is None
+    assert wrap.rebalance_for_admission("a", "p") == ("a", "p")
+    single = SimpleNamespace(engine=Engine(), obs=None, sessions={},
+                             step=lambda: None,
+                             idle_sessions=lambda: [("a", 1, 2)])
+    wrap = harness.ClientSide(single, harness.Recorder(None))
+    assert wrap.parts == [single] and wrap.fleet is None
+    assert wrap.idle_sessions() == [("a", 1, 2)]
 
 
 def test_every_named_file_of_the_benchmark_exists():
