@@ -1,12 +1,17 @@
 """Pallas TPU paged attention: GQA-group query tiles walking multi-page KV
 blocks up to the live length.
 
-The KV cache is *paged*: keys/values live in a pooled ``(num_blocks, hkv,
-blk, d)`` array shared by all sequences, and each sequence owns an int32
-page table naming its blocks in position order. A page of every kv head,
-``(hkv, blk, d)``, is one contiguous slab of the pool.
+The KV cache is *paged*: keys/values live in a pooled ``(L, num_blocks,
+hkv, blk, d)`` stack shared by all sequences, and each sequence owns an
+int32 page table naming its blocks in position order (the same ids in
+every layer). A page of every kv head, ``(hkv, blk, d)``, is one
+contiguous slab of the pool, and the kernel copies it from
+``(layer, page)``: the layer arrives as a scalar-prefetch operand, so the
+megastep hands the kernel the whole stack it carries through its layer
+scan, never a layer's slice. ``paged_prefill_attention_bcd`` takes one
+layer's pool as a stack of one.
 
-``paged_prefill_attention_bcd`` is chunked prefill (Sarathi): row ``b``
+``paged_prefill_attention_stacked`` is chunked prefill (Sarathi): row ``b``
 holds ``valids[b]`` real query tokens at absolute positions
 ``cache_lens[b] + [0, C)``. Decode rows are its C == 1 special case, which
 is what lets the engine fuse prefill chunks and decode tokens into ONE
@@ -120,13 +125,14 @@ def kernel_plan(*, C: int, hq: int, hkv: int, blk: int, d: int, dv: int,
                       pages_per_block=pages, vmem_bytes=vmem)
 
 
-def _prefill_kernel(lens_ref, off_ref, val_ref, pt_ref, q_ref, k_hbm, v_hbm,
-                    o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr, *,
-                    page_at, scale: float, blk: int, pages: int, tc: int,
-                    npages: int):
+def _prefill_kernel(lens_ref, off_ref, val_ref, pt_ref, layer_ref, q_ref,
+                    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_scr, l_scr,
+                    acc_scr, *, page_at, scale: float, blk: int, pages: int,
+                    tc: int, npages: int):
     """One grid step: the (hkv, rows, d) query tile of one (row, query tile)
     walks the row's live KV blocks with an online softmax per kv head."""
     b, t = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
     kb = pages * blk
     hkv, rows, d = q_ref.shape
     dv = v_buf.shape[-1]
@@ -146,8 +152,8 @@ def _prefill_kernel(lens_ref, off_ref, val_ref, pt_ref, q_ref, k_hbm, v_hbm,
 
         def one(p, c):
             cp = pltpu.make_async_copy(
-                page_at(src, pt_ref, b, i * pages + p), buf.at[slot, p],
-                sems.at[sem, slot])
+                page_at(src, layer, pt_ref, b, i * pages + p),
+                buf.at[slot, p], sems.at[sem, slot])
             cp.start() if start else cp.wait()
             return c
 
@@ -228,13 +234,18 @@ def _prefill_kernel(lens_ref, off_ref, val_ref, pt_ref, q_ref, k_hbm, v_hbm,
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-def _prefill_call(q, k_src, v_src, cache_lens, valids, page_tables, *,
-                  scale, hkv: int, blk: int, page_at, interpret: bool):
+def _prefill_call(q, k_src, v_src, layer, cache_lens, valids, page_tables,
+                  *, scale, hkv: int, blk: int, page_at, interpret: bool):
     """Shared scaffolding for the chunked-prefill kernel and its gathered-
     view twin: everything except ``page_at`` (the HBM address a page copy
     reads) lives HERE, so the two traced programs are structurally 'the
     same except the indirection' — which is what makes their bit-for-bit
-    parity a test of the page walk rather than of float associativity."""
+    parity a test of the page walk rather than of float associativity.
+
+    For the paged kernel ``k_src``/``v_src`` are the whole layer stack
+    ``(L, nb, hkv, blk, d|dv)`` that the megastep carries through its
+    layer scan and writes in place; ``layer`` (a scalar-prefetch operand)
+    picks the layer, so no layer's slice is ever cut out of the pool."""
     b, C, hq, d = q.shape
     dv = v_src.shape[-1]
     npages = page_tables.shape[1]
@@ -245,6 +256,7 @@ def _prefill_call(q, k_src, v_src, cache_lens, valids, page_tables, *,
     tc, rows, nq = plan.tile_positions, plan.rows, plan.query_tiles
     cache_lens = jnp.asarray(cache_lens, jnp.int32).reshape(b)
     valids = jnp.asarray(valids, jnp.int32).reshape(b)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     # (b, C, hq, d) -> (b, nq, hkv, rows, d): q head h = gi * hkv + j goes
     # to kv head j's rows of its tile at gi * tc + (position mod tc)
     q = jnp.pad(q, ((0, 0), (0, nq * tc - C), (0, 0), (0, 0)))
@@ -258,7 +270,7 @@ def _prefill_call(q, k_src, v_src, cache_lens, valids, page_tables, *,
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(b, nq),
             in_specs=[
                 pl.BlockSpec((None, None, hkv, rows, d), tile),
@@ -282,32 +294,45 @@ def _prefill_call(q, k_src, v_src, cache_lens, valids, page_tables, *,
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(cache_lens + valids, cache_lens, valids,
-      jnp.asarray(page_tables, jnp.int32), q, k_src, v_src)
+      jnp.asarray(page_tables, jnp.int32), layer, q, k_src, v_src)
     out = out[:, :, :, :g * tc].reshape(b, nq, hkv, g, tc, dv)
     out = out.transpose(0, 1, 4, 3, 2, 5).reshape(b, nq * tc, hq, dv)
     return out[:, :C]
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_prefill_attention_bcd(q, k_pool, v_pool, cache_lens, valids,
-                                page_tables, *, scale=None,
-                                interpret: bool = False):
-    """Chunked-prefill paged attention over a batch of mixed-width rows.
+def paged_prefill_attention_stacked(q, k_pools, v_pools, layer, cache_lens,
+                                    valids, page_tables, *, scale=None,
+                                    interpret: bool = False):
+    """Chunked-prefill paged attention over a batch of mixed-width rows,
+    reading layer ``layer`` of the stacked pools.
 
     q: (b, C, hq, d) — row ``i`` holds a chunk of ``valids[i]`` real query
     tokens at absolute positions ``cache_lens[i] + [0, C)`` (decode rows are
-    C-padded width-1 chunks); k_pool: (nb, hkv, blk, d); v_pool:
-    (nb, hkv, blk, dv); cache_lens/valids: (b,) int32; page_tables:
-    (b, npages) int32 block ids in position order. Each row attends
-    causally within its chunk and fully over its already-resident pages;
-    q head ``h`` reads kv head ``h % hkv``. Positions beyond ``valids``
-    produce garbage the caller discards (zeros in query tiles wholly past
-    it). Returns (b, C, hq, dv)."""
-    hkv, blk = k_pool.shape[1], k_pool.shape[2]
+    C-padded width-1 chunks); k_pools: (L, nb, hkv, blk, d); v_pools:
+    (L, nb, hkv, blk, dv); layer: scalar int32; cache_lens/valids: (b,)
+    int32; page_tables: (b, npages) int32 block ids in position order. A
+    page copy reads ``pool[layer, page_tables[row, page]]`` straight from
+    HBM. Each row attends causally within its chunk and fully over its
+    already-resident pages; q head ``h`` reads kv head ``h % hkv``.
+    Positions beyond ``valids`` produce garbage the caller discards (zeros
+    in query tiles wholly past it). Returns (b, C, hq, dv)."""
+    hkv, blk = k_pools.shape[2], k_pools.shape[3]
     return _prefill_call(
-        q, k_pool, v_pool, cache_lens, valids, page_tables, scale=scale,
-        hkv=hkv, blk=blk, interpret=interpret,
-        page_at=lambda src, pt, b_, page: src.at[pt[b_, page]])
+        q, k_pools, v_pools, layer, cache_lens, valids, page_tables,
+        scale=scale, hkv=hkv, blk=blk, interpret=interpret,
+        page_at=lambda src, lyr, pt, b_, page: src.at[lyr, pt[b_, page]])
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_prefill_attention_bcd(q, k_pool, v_pool, cache_lens, valids,
+                                page_tables, *, scale=None,
+                                interpret: bool = False):
+    """``paged_prefill_attention_stacked`` over one layer's pool
+    (nb, hkv, blk, d|dv): the pool as a stack of one, at layer 0."""
+    return paged_prefill_attention_stacked(
+        q, k_pool[None], v_pool[None], 0, cache_lens, valids, page_tables,
+        scale=scale, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -331,6 +356,6 @@ def paged_prefill_attention_contig(q, k_contig, v_contig, cache_lens, valids,
         return x.reshape(b, hkv, npages, blk, x.shape[-1]).swapaxes(1, 2)
 
     return _prefill_call(
-        q, pages(k_contig), pages(v_contig), cache_lens, valids, page_tables,
-        scale=scale, hkv=hkv, blk=blk, interpret=interpret,
-        page_at=lambda src, pt, b_, page: src.at[b_, page])
+        q, pages(k_contig), pages(v_contig), 0, cache_lens, valids,
+        page_tables, scale=scale, hkv=hkv, blk=blk, interpret=interpret,
+        page_at=lambda src, lyr, pt, b_, page: src.at[b_, page])

@@ -116,20 +116,23 @@ def gqa_decode(params, x, cache_k, cache_v, cache_len, cfg: ModelConfig,
     return out, (cache_k, cache_v)
 
 
-def paged_kernel_attention(q, k_pool, v_pool, cache_lens, valids,
+def paged_kernel_attention(q, k_pools, v_pools, layer, cache_lens, valids,
                            page_tables, cfg: ModelConfig, *,
                            interpret: bool = False):
-    """The Pallas paged kernel for (B, C) query rows. It pairs q head ``h``
-    with kv head ``h % hkv``, the pairing of ``gqa_mode="tiled"``; a model
-    pairing heads the other way would silently become a different model,
-    so that combination is refused."""
+    """The Pallas paged kernel for (B, C) query rows over layer ``layer``
+    of the (L, nb, hkv, blk, hd) pools (one layer's pool is ``pool[None]``
+    at layer 0). It pairs q head ``h`` with kv head ``h % hkv``, the
+    pairing of ``gqa_mode="tiled"``; a model pairing heads the other way
+    would silently become a different model, so that combination is
+    refused."""
     if cfg.gqa_mode != "tiled":
         raise ValueError(
             f"the paged kernel pairs heads g-major (gqa_mode='tiled'); "
             f"{cfg.name} has gqa_mode={cfg.gqa_mode!r}")
     from repro.kernels.paged_attention import ops as pa
-    return pa.paged_prefill_attention(q, k_pool, v_pool, cache_lens, valids,
-                                      page_tables, interpret=interpret)
+    return pa.paged_prefill_attention(q, k_pools, v_pools, layer, cache_lens,
+                                      valids, page_tables,
+                                      interpret=interpret)
 
 
 def gqa_decode_paged(params, x, k_pool, v_pool, page_tables, cache_len,
@@ -168,9 +171,9 @@ def gqa_decode_paged(params, x, k_pool, v_pool, page_tables, cache_len,
     k_pool = k_pool.at[bids, :, offs].set(k[:, 0].astype(k_pool.dtype))
     v_pool = v_pool.at[bids, :, offs].set(v[:, 0].astype(v_pool.dtype))
     if paged_kernel:
-        o = paged_kernel_attention(q, k_pool, v_pool, cache_len,
-                                   jnp.ones_like(cache_len), page_tables,
-                                   cfg, interpret=interpret)
+        o = paged_kernel_attention(q, k_pool[None], v_pool[None], 0,
+                                   cache_len, jnp.ones_like(cache_len),
+                                   page_tables, cfg, interpret=interpret)
     else:
         from repro.kernels.paged_attention.ref import gather_pages
         kg = gather_pages(k_pool, page_tables).astype(q.dtype)
@@ -222,8 +225,9 @@ def gqa_prefill_chunk_paged(params, x, k_pool, v_pool, page_table, cache_len,
     k_pool = k_pool.at[bids, :, offs].set(k[0].astype(k_pool.dtype))
     v_pool = v_pool.at[bids, :, offs].set(v[0].astype(v_pool.dtype))
     if paged_kernel:
-        o = paged_kernel_attention(q, k_pool, v_pool, cache_len[None],
-                                   valid[None], page_table[None], cfg)
+        o = paged_kernel_attention(q, k_pool[None], v_pool[None], 0,
+                                   cache_len[None], valid[None],
+                                   page_table[None], cfg)
     else:
         from repro.kernels.paged_attention.ref import gather_pages
         kg = gather_pages(k_pool, page_table[None]).astype(q.dtype)
@@ -236,8 +240,8 @@ def gqa_prefill_chunk_paged(params, x, k_pool, v_pool, page_table, cache_len,
     return out, (k_pool, v_pool)
 
 
-def gqa_mixed_step_paged(params, x, k_pool, v_pool, page_tables, cache_lens,
-                         valids, cfg: ModelConfig, *,
+def gqa_mixed_step_paged(params, x, k_pool, v_pool, layer, page_tables,
+                         cache_lens, valids, cfg: ModelConfig, *,
                          paged_kernel: bool = False, interpret: bool = False,
                          axis_name: Optional[str] = None):
     """One fused Sarathi megastep row set: every row of the ``(B, C)``
@@ -246,8 +250,12 @@ def gqa_mixed_step_paged(params, x, k_pool, v_pool, page_tables, cache_lens,
     over chunk + resident history.
 
     x: (B, C, d) embeddings (token padding beyond ``valids`` is garbage the
-    caller discards); k_pool/v_pool: (num_blocks, hkv, blk, hd) one layer's
-    pool slice; page_tables: (B, npages) int32, null-padded; cache_lens:
+    caller discards); k_pool/v_pool: (L, num_blocks, hkv, blk, hd) — the
+    WHOLE layer stack, which the megastep carries through its layer scan;
+    this layer's K/V is written into it in place at ``layer`` and the
+    attention reads ``layer`` of it, so no layer's slice is cut out,
+    relaid or restacked; layer: scalar int32 (traced: the scan's layer
+    index); page_tables: (B, npages) int32, null-padded; cache_lens:
     (B,) int32 tokens resident *before* this step; valids: (B,) int32 real
     tokens per row (0 = inactive slot; its writes land in the null block and
     its outputs are discarded). C is whatever trace bucket the engine's
@@ -271,7 +279,7 @@ def gqa_mixed_step_paged(params, x, k_pool, v_pool, page_tables, cache_lens,
     """
     b, C, _ = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    blk = k_pool.shape[2]
+    blk = k_pool.shape[3]
     npages = page_tables.shape[1]
     cache_lens = jnp.asarray(cache_lens)
     valids = jnp.asarray(valids)
@@ -286,8 +294,12 @@ def gqa_mixed_step_paged(params, x, k_pool, v_pool, page_tables, cache_lens,
             cos, sin = rope_tables(pos, rot, cfg.rope_theta)
             q = apply_rope(q, cos, sin, cfg.rotary_pct)
             k = apply_rope(k, cos, sin, cfg.rotary_pct)
-    # scatter every row's chunk into its own blocks; padding positions (and
-    # inactive rows) aim at the reserved null block 0
+    # scatter every row's chunk into its own blocks of this layer; padding
+    # positions (and inactive rows) aim at the reserved null block 0. Every
+    # axis but the minor one is indexed (the head axis too), so each update
+    # is one hd-wide row and XLA keeps the pool's own layout; a ``:`` on
+    # the head axis makes the window (hkv, hd) and XLA relays the whole
+    # stack around the scatter, inside the layer loop (DESIGN.md §10)
     with jax.named_scope("kv_write"):
         live = jnp.arange(C)[None, :] < valids[:, None]
         page_idx = jnp.clip(pos // blk, 0, npages - 1)
@@ -295,18 +307,21 @@ def gqa_mixed_step_paged(params, x, k_pool, v_pool, page_tables, cache_lens,
                          jnp.take_along_axis(page_tables, page_idx, axis=1),
                          0)
         offs = pos % blk
-        k_pool = k_pool.at[bids, :, offs].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[bids, :, offs].set(v.astype(v_pool.dtype))
+        at = (layer, bids[..., None], jnp.arange(hkv)[None, None, :],
+              offs[..., None])
+        k_pool = k_pool.at[at].set(k.astype(k_pool.dtype))
+        v_pool = v_pool.at[at].set(v.astype(v_pool.dtype))
     with jax.named_scope("paged"):
         if paged_kernel:
-            o = paged_kernel_attention(q, k_pool, v_pool, cache_lens, valids,
-                                       page_tables, cfg, interpret=interpret)
+            o = paged_kernel_attention(q, k_pool, v_pool, layer, cache_lens,
+                                       valids, page_tables, cfg,
+                                       interpret=interpret)
         else:
             from repro.kernels.paged_attention.ref import \
                 paged_prefill_attention_ref
             pairing = "g_major" if cfg.gqa_mode == "tiled" else "kv_major"
-            o = paged_prefill_attention_ref(q, k_pool, v_pool, cache_lens,
-                                            valids, page_tables,
+            o = paged_prefill_attention_ref(q, k_pool[layer], v_pool[layer],
+                                            cache_lens, valids, page_tables,
                                             pairing=pairing)
     with jax.named_scope("out"):
         out = o.reshape(b, C, hq * hd) @ params["wo"]

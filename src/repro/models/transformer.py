@@ -282,7 +282,10 @@ def init_paged_pools(cfg: ModelConfig, num_blocks: int,
     shape (L, num_blocks, hkv, blk, hd). Block ids are shared across layers
     (every layer stores the same token positions in the same block id), so
     one page table per sequence serves the whole stack. Each page is
-    head-major, so the paged kernel reads one (blk, hd) slab per kv head."""
+    head-major, so the paged kernel reads one (blk, hd) slab per kv head.
+    The megastep (``mixed_step_paged``) carries each pool as ONE buffer
+    through its layer scan and writes it in place at (layer, block, head,
+    offset); no layer's slice is ever cut out or restacked."""
     assert cfg.family in ("dense", "moe", "vlm") and cfg.mla is None, \
         "paged KV pools target the decoder-only GQA family"
     assert cfg.first_dense_layers == 0, \
@@ -374,6 +377,14 @@ def mixed_step_paged(params, pools: Dict, tokens, cache_lens, valids,
     engine sets it from the platform of its device (the kernel on a TPU,
     the jnp gather reference on a CPU).
 
+    The pools ride the layer scan as its carry, not as xs/ys: each
+    (L, num_blocks, hkv, blk, hd) stack stays one buffer in its own layout
+    through all L layers, layer ``l`` writes its K/V into it in place and
+    its attention reads ``(l, page)`` of it, and the carried stacks are
+    returned. Scanned as xs/ys instead, every layer's slice would be cut
+    out, relaid and restacked and both stacks copied after the loop, on
+    every step (DESIGN.md §10).
+
     ``axis_name`` is set when this body runs inside the sharded megastep's
     shard_map (DESIGN.md §13): ``cfg`` then carries per-shard head counts,
     ``pools`` is this shard's KV-head slice, and each layer's attention
@@ -387,21 +398,23 @@ def mixed_step_paged(params, pools: Dict, tokens, cache_lens, valids,
     with jax.named_scope("embed"):
         x = _embed(params, tokens, cfg)
 
-    def body(h, xs):
-        lp, kp, vp = xs
+    def body(carry, xs):
+        h, kp, vp = carry
+        lp, layer = xs
         with jax.named_scope("attn"):
             with jax.named_scope("qkv"):
                 hh = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
             a, (kp, vp) = attn_mod.gqa_mixed_step_paged(
-                lp["attn"], hh, kp, vp, page_tables, cache_lens, valids, cfg,
-                paged_kernel=paged_kernel, axis_name=axis_name)
+                lp["attn"], hh, kp, vp, layer, page_tables, cache_lens,
+                valids, cfg, paged_kernel=paged_kernel, axis_name=axis_name)
         h = h + a
         with jax.named_scope("mlp"):
             m, _, _ = _mlp_or_moe(lp, h, cfg)
-        return h + m, (kp, vp)
+        return (h + m, kp, vp), None
 
-    x, (k, v) = jax.lax.scan(body, x, (params["layers"], pools["k"],
-                                       pools["v"]))
+    layers = jnp.arange(pools["k"].shape[0], dtype=jnp.int32)
+    (x, k, v), _ = jax.lax.scan(body, (x, pools["k"], pools["v"]),
+                                (params["layers"], layers))
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         rows = jnp.arange(x.shape[0])

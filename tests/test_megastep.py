@@ -9,8 +9,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
-from repro.kernels.paged_attention.kernel import (kernel_plan,
-                                                  paged_prefill_attention_bcd)
+from repro.kernels.paged_attention.kernel import (
+    kernel_plan, paged_prefill_attention_bcd, paged_prefill_attention_stacked)
 from repro.kernels.paged_attention.ref import (
     paged_prefill_attention_gathered_oracle, paged_prefill_attention_ref)
 from repro.models import build
@@ -142,7 +142,8 @@ def test_mixed_step_kernel_matches_reference_path_at_f32():
     """One megastep layer through the Pallas kernel (interpret mode) agrees
     with the jnp reference path the engine runs on a CPU: same attention
     output rows and bit-identical pool writes, at f32 on a GQA model with
-    hkv 2, g 2 and ragged rows (decode, partial chunk, inactive)."""
+    hkv 2, g 2 and ragged rows (decode, partial chunk, inactive), writing
+    and reading the middle layer of a three-layer pool stack."""
     from repro.models.attention import gqa_mixed_step_paged, init_gqa
     cfg = get_smoke_config("chatglm3-6b").replace(compute_dtype="float32")
     hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
@@ -152,9 +153,9 @@ def test_mixed_step_kernel_matches_reference_path_at_f32():
     B, C, npages = 3, PREFILL_CHUNK, 4
     nb = B * npages + 1
     x = jnp.asarray(rng.standard_normal((B, C, cfg.d_model)), jnp.float32)
-    k_pool = jnp.asarray(rng.standard_normal((nb, hkv, BLOCK_SIZE, hd)),
+    k_pool = jnp.asarray(rng.standard_normal((3, nb, hkv, BLOCK_SIZE, hd)),
                          jnp.float32)
-    v_pool = jnp.asarray(rng.standard_normal((nb, hkv, BLOCK_SIZE, hd)),
+    v_pool = jnp.asarray(rng.standard_normal((3, nb, hkv, BLOCK_SIZE, hd)),
                          jnp.float32)
     ids = rng.permutation(np.arange(1, nb))[: B * npages].reshape(B, npages)
     tables = jnp.asarray(ids, jnp.int32)
@@ -162,7 +163,7 @@ def test_mixed_step_kernel_matches_reference_path_at_f32():
     valids = jnp.asarray([C, 1, 0], jnp.int32)
 
     def run(kernel):
-        return gqa_mixed_step_paged(params, x, k_pool, v_pool, tables,
+        return gqa_mixed_step_paged(params, x, k_pool, v_pool, 1, tables,
                                     cache_lens, valids, cfg,
                                     paged_kernel=kernel, interpret=kernel)
 
@@ -173,6 +174,91 @@ def test_mixed_step_kernel_matches_reference_path_at_f32():
                                np.asarray(out_r)[live], atol=1e-4, rtol=1e-4)
     np.testing.assert_array_equal(np.asarray(kk), np.asarray(kr))
     np.testing.assert_array_equal(np.asarray(vk), np.asarray(vr))
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_stacked_pool_kernel_reads_its_layer_bitwise(layer):
+    """The kernel over the stacked (L, nb, hkv, blk, d) pool at layer
+    ``layer`` (the first and the last of three) equals, bit for bit, the
+    single-layer call on ``pool[layer]``: the page copies read
+    ``(layer, page)`` and nothing of any other layer."""
+    q, k_pool, v_pool, cache, valids, pt = _mixed_case(
+        3, PREFILL_CHUNK, 4, 2, 32, 32, BLOCK_SIZE, 4, seed=31)
+    rng = np.random.default_rng(32)
+    k_stack = jnp.asarray(rng.standard_normal((3,) + k_pool.shape),
+                          jnp.float32).at[layer].set(k_pool)
+    v_stack = jnp.asarray(rng.standard_normal((3,) + v_pool.shape),
+                          jnp.float32).at[layer].set(v_pool)
+    out = paged_prefill_attention_stacked(q, k_stack, v_stack, layer, cache,
+                                          valids, pt, interpret=True)
+    one = paged_prefill_attention_bcd(q, k_pool, v_pool, cache, valids, pt,
+                                      interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(one))
+
+
+def _layer_sliced_megastep(params, pools, tokens, cache_lens, valids, tables,
+                           cfg):
+    """``mixed_step_paged`` composed layer by layer the way it scanned
+    before it carried the pools: each layer's pool slice rides the scan as
+    xs, is written as a stack of one, and is restacked as ys."""
+    from repro.models import transformer as tr
+    from repro.models.attention import gqa_mixed_step_paged
+    from repro.models.layers import cast_floats, rms_norm
+    params = cast_floats(params, cfg.compute_dtype)
+    x = tr._embed(params, tokens, cfg)
+
+    def body(h, xs):
+        lp, kp, vp = xs
+        hh = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+        a, (kp, vp) = gqa_mixed_step_paged(
+            lp["attn"], hh, kp[None], vp[None], 0, tables, cache_lens,
+            valids, cfg)
+        h = h + a
+        m, _, _ = tr._mlp_or_moe(lp, h, cfg)
+        return h + m, (kp[0], vp[0])
+
+    x, (k, v) = jax.lax.scan(body, x, (params["layers"], pools["k"],
+                                       pools["v"]))
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    rows = jnp.arange(x.shape[0])
+    last = jnp.clip(valids - 1, 0, x.shape[1] - 1)
+    logits = tr._unembed(params, x[rows, last], cfg)
+    ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    ids = jnp.where(jnp.all(jnp.isfinite(logits), axis=-1), ids, -1)
+    return ids, {"k": k, "v": v}
+
+
+def test_megastep_with_carried_pools_equals_layer_sliced_composition():
+    """The megastep, whose layer scan carries the whole pool stacks and
+    writes them in place, gives the same ids and the same pools, bit for
+    bit, as a layer-by-layer composition over each layer's pool slice, on
+    a row mix of a full chunk, a partial chunk, a decode row and an
+    inactive row over filled pools: every layer's writes land in its own
+    slice and no other layer changes."""
+    from repro.models import transformer as tr
+    cfg = get_smoke_config("chatglm3-6b")
+    params = build(cfg).init_params(jax.random.PRNGKey(3))
+    B, C, npages = 4, PREFILL_CHUNK, 4
+    nb = B * npages + 1
+    rng = np.random.default_rng(8)
+    pools = tr.init_paged_pools(cfg, nb, BLOCK_SIZE)
+    pools = {n: jnp.asarray(rng.standard_normal(p.shape), p.dtype)
+             for n, p in pools.items()}
+    ids = rng.permutation(np.arange(1, nb))[: B * npages].reshape(B, npages)
+    tables = jnp.asarray(ids, jnp.int32)
+    cache_lens = jnp.asarray([0, 9, 21, 0], jnp.int32)
+    valids = jnp.asarray([C, 5, 1, 0], jnp.int32)
+    tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, C)), jnp.int32)
+    args = (params, pools, tokens, cache_lens, valids, tables)
+    got_ids, got = jax.jit(
+        lambda *a: tr.mixed_step_paged(*a, cfg))(*args)
+    want_ids, want = jax.jit(
+        lambda *a: _layer_sliced_megastep(*a, cfg))(*args)
+    np.testing.assert_array_equal(np.asarray(got_ids), np.asarray(want_ids))
+    for n in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(got[n]),
+                                      np.asarray(want[n]))
+        assert not np.array_equal(np.asarray(got[n]), np.asarray(pools[n]))
 
 
 # --------------------------------------------------- kernel tiling limits

@@ -11,6 +11,7 @@ one process at a time may load the TPU library, and test workers import
 every test file.
 """
 import os
+import re
 from functools import partial
 
 import jax
@@ -173,6 +174,82 @@ def test_chatglm3_megastep_fits_one_v5e(one_chip):
     assert mem.argument_size_in_bytes > 12e9          # the weights are there
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
+
+
+# one HLO instruction: name, output array shape (not a tuple), opcode
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT\s+)?%(\S+)\s*=\s*\w+\[([\d,]*)\]\S*\s+([\w-]+)\(")
+# ops that name or reuse a buffer without writing a new one; the scatter
+# (alone or as a fusion's root) writes the carried pool in place
+_NO_NEW_BUFFER = {"parameter", "get-tuple-element", "bitcast", "scatter"}
+
+
+def _pool_sized_ops(hlo: str, sizes) -> list:
+    """(name, shape, opcode) of every instruction whose output holds as
+    many elements as one of ``sizes`` and that materialises it: anything
+    but a parameter, a tuple element, a bitcast, a scatter, or a fusion
+    whose root is a scatter."""
+    roots, comp = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%(\S+)\s+\(.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+        m = _HLO_INSTR.match(line)
+        if m and line.lstrip().startswith("ROOT") and comp:
+            roots[comp] = m.group(3)
+    found = []
+    for line in hlo.splitlines():
+        m = _HLO_INSTR.match(line)
+        if not m:
+            continue
+        name, dims, op = m.groups()
+        n = 1
+        for x in filter(None, dims.split(",")):
+            n *= int(x)
+        if n not in sizes or op in _NO_NEW_BUFFER:
+            continue
+        calls = re.search(r"calls=%([^,\s]+)", line)
+        if op == "fusion" and calls and roots.get(calls.group(1)) \
+                == "scatter":
+            continue
+        found.append((name, dims, op))
+    return found
+
+
+@pytest.mark.parametrize("C", [1, 1024])
+def test_chatglm3_megastep_keeps_the_pool_in_place(one_chip, C):
+    """The chatglm3-6b megastep at the benchmark's serving shapes (8 rows,
+    512 pages, 3456 blocks of 16) at decode width and at the widest
+    bucket carries each (28, 3456, 2, 16, 128) pool through its layer
+    scan as one buffer: no compiled op copies, slices, relays or
+    restacks the stack or one layer's slice of it (28.3 MB, in either
+    layout), and the temporaries hold no copy of a pool. A step that
+    scans the pools as xs/ys fails both checks: it relays and restacks
+    every layer's slice and copies both 0.79 GB stacks after the loop
+    (temporaries 1.59e9 B at C = 1, 2.22e9 B at C = 1024)."""
+    cfg = get_config("chatglm3-6b").replace(remat=False,
+                                            param_dtype="bfloat16")
+
+    def place(tree):
+        return jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+
+    params = place(abstract_params(cfg))
+    pools = place(jax.eval_shape(
+        partial(tr.init_paged_pools, cfg, SERVE_BLOCKS, BLOCK_SIZE)))
+    L, nb, hkv, blk, hd = pools["k"].shape
+    i32, B = jnp.int32, SERVE_BATCH
+    rows = (_sds(one_chip, (B, C), i32), _sds(one_chip, (B,), i32),
+            _sds(one_chip, (B,), i32), _sds(one_chip, (B, SERVE_PAGES), i32),
+            _sds(one_chip, (B,), jnp.bool_))
+    step = jax.jit(
+        lambda p, pools, toks, lens, valids, tables, poison:
+        tr.mixed_step_paged(p, pools, toks, lens, valids, tables, cfg,
+                            poison, paged_kernel=True),
+        donate_argnums=(1,))
+    compiled = step.lower(params, pools, *rows).compile()
+    layer = nb * hkv * blk * hd
+    assert _pool_sized_ops(compiled.as_text(), {L * layer, layer}) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
 
 
 def test_starcoder2_tp4_megastep_fits_each_v5e_chip(topo):
