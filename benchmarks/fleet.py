@@ -84,7 +84,7 @@ def _mk_backend(cfg, params, *, name: str, journal=None, factory_kw=None,
     from repro.serving import PagedEngineBackend, PagedInferenceEngine
 
     kw = dict(num_blocks=48, block_size=8, max_batch=4, max_len=160,
-              prefill_chunk=16, megastep=True)
+              prefill_chunk=16)
     kw.update(factory_kw or {})
 
     def factory():

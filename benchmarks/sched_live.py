@@ -2,41 +2,31 @@
 each dispatch sized to the live workload mix?
 
 Three traffic scenarios, all driving the SAME paged engine configuration
-(same model, same block pool, same ``max_batch``) through the AgentRM
-middleware — what changes per mode is the dispatch discipline:
+(same model, same block pool, same ``max_batch``, same token budget)
+through the AgentRM middleware — what changes per mode is the dispatch
+discipline:
 
   * ``serialized-lanes`` — the pre-fusion design: thread-per-lane dispatch
     over ``SerializedPagedBackend`` (backend-wide lock per turn). Mixed
-    scenario only; the historical baseline.
-  * ``fused-mlfq`` — the PR 2 iteration-level design: one dispatcher loop,
-    but ``1 + n_prefilling`` jitted dispatches per engine iteration. Mixed
-    scenario only.
-  * ``fused-megastep`` — the PR 3 fixed-chunk megastep: ONE jitted dispatch
-    per iteration, C in {1, prefill_chunk} — one prefilling row forces every
-    decode batchmate through chunk-width FLOPs, and a long prompt is capped
-    at one fixed chunk per step no matter how empty the batch is.
-  * ``fused-budget`` — this PR (DESIGN.md §11): per-step token budget,
-    decode-first packing, variable-width prefill chunks, dispatch width
-    drawn from the bounded pow2 bucket set. Still one dispatch per step.
+    scenario only; the paper's serialized baseline.
+  * ``fused-budget`` — the fused iteration-level dispatcher over the
+    budget megastep (DESIGN.md §11): per-step token budget, decode-first
+    packing, variable-width prefill chunks, dispatch width drawn from the
+    bounded pow2 bucket set, one dispatch per step.
 
 Scenarios (token budgets are per-scenario knobs — right-sizing is the whole
 point — but within a scenario every mode runs at equal hardware):
 
   * ``mixed``         — sub-chunk agent prompts interleave with sustained,
                         desynced decode against a throughput-tuned large
-                        chunk. The budget right-sizes the dispatch width to
-                        the live mix, so decode batchmates stop paying
-                        full-chunk FLOPs: P95 inter-token latency and
-                        padded_token_fraction must both improve.
+                        chunk; the budget right-sizes the dispatch width
+                        to the live mix.
   * ``prefill-heavy`` — long prompts, near-empty batch, latency-tuned
-                        small chunk. The budget lets a prompt burn many
-                        chunks' worth of budget in one dispatch instead of
-                        dripping one fixed chunk per step: >= 1.3x
-                        tokens/sec.
-  * ``decode-heavy``  — short prompts, long generations. Mostly C == 1
-                        steps in both megastep modes; the budget must not
-                        regress throughput, and the prefill bursts fit the
-                        budget at a right-sized (narrower) width.
+                        small chunk: a prompt burns many chunks' worth of
+                        budget in one dispatch.
+  * ``decode-heavy``  — short prompts, long generations: mostly C == 1
+                        steps, the prefill bursts fit the budget at a
+                        right-sized width.
 
 Timed regions end with ``engine.sync()`` (``jax.block_until_ready`` over
 the KV pools) so async dispatch cannot flatter wall-clock numbers. TTFT and
@@ -50,15 +40,16 @@ full-stack first-token latency.
 
 Reports per run: wall seconds, decoded tokens/sec, TTFT p95, P95
 inter-token latency, ``padded_token_fraction``, trace buckets used vs the
-bounded bucket set, ``jit_dispatches_per_step`` (must be 1.0 for both
-megastep modes), zombies (must be 0). Emits ``BENCH_sched_live.json``.
+bounded bucket set, ``jit_dispatches_per_step`` (must be 1.0), zombies
+(must be 0). Emits ``BENCH_sched_live.json``. These are CPU runs: their
+timings are no speed numbers (PERF.md holds the chip's).
 
     PYTHONPATH=src python -m benchmarks.sched_live [--smoke] [--check]
 
 ``--check`` is the CI smoke gate: non-zero exit if any fused run reaped a
-zombie or failed a turn, if either megastep mode dispatched more than one
-jit call per step, or if a budget run's distinct trace buckets exceeded its
-bounded pow2 bucket set (the recompile guard).
+zombie or failed a turn, if any run dispatched more than one jit call per
+step, or if a run's distinct trace buckets exceeded its bounded pow2
+bucket set (the recompile guard).
 """
 from __future__ import annotations
 
@@ -75,32 +66,25 @@ SCENARIOS = {
     # string repeated 1 + (i % prompt_repeat) times (capped at
     # prompt_tokens), so prompt lengths vary across agents and prefill
     # overlaps decode instead of the whole fleet phase-locking; budget is
-    # the fused-budget mode's per-step token budget
+    # every mode's per-step token budget
     # a throughput-tuned deployment runs a LARGE prefill chunk (128 here;
-    # real Sarathi/vLLM chunks are 512+): great when prompts fill it, but
-    # agent-turn prompts here are sub-chunk (17-32 tokens), so under mixed
-    # traffic every decode batchmate is padded to the full chunk width
-    # whenever anyone prefills — a 4x+ wider (and costlier) dispatch than
-    # the work needs. The budget (64 >= any prompt, so it almost never
-    # rations) right-sizes C down to the pow2 bucket the live mix actually
-    # needs (<= 32) — same real work per step, a quarter the dispatched
-    # slots on every prefill-carrying step
+    # real Sarathi/vLLM chunks are 512+), but agent-turn prompts here are
+    # sub-chunk (17-32 tokens). The budget (64 >= any prompt, so it almost
+    # never rations) right-sizes C down to the pow2 bucket the live mix
+    # actually needs (<= 32)
     "mixed": dict(agents=8, turns=2, new_tokens=10, jitter=8,
                   prompt_tokens=32, prompt_repeat=4, budget=64, chunk=128,
                   max_len=192),
     # long prompts against a near-EMPTY batch and a latency-tuned small
-    # chunk (8): the fixed chunk drips one chunk per step no matter how
-    # idle the batch is, so a 192-token prompt takes 24 dispatches; the
-    # budget lets a prompt burn the whole budget (24 chunks' worth) in one
-    # right-sized dispatch. Two desynced agents at max_batch 2 keep the
-    # batch prefill-dominated — the regime the fixed chunk wastes most
+    # chunk (8): the budget lets a prompt burn the whole budget (24
+    # chunks' worth) in one right-sized dispatch. Two desynced agents at
+    # max_batch 2 keep the batch prefill-dominated
     "prefill-heavy": dict(agents=2, turns=2, new_tokens=2, jitter=2,
                           prompt_tokens=192, prompt_repeat=1,
                           prompt_scale=12, budget=192, chunk=8,
                           max_len=448, max_batch=2),
-    # short prompts, long generations: mostly C == 1 steps either way; the
-    # budget's win is the prefill bursts (8 rows x 8 tokens fit the budget
-    # exactly, dispatched at C == 8 instead of chunk width 16)
+    # short prompts, long generations: mostly C == 1 steps; the prefill
+    # bursts (8 rows x 8 tokens) fit the budget exactly at C == 8
     "decode-heavy": dict(agents=8, turns=1, new_tokens=24, prompt_tokens=8,
                          prompt_repeat=1, budget=64, chunk=16, max_len=192),
 }
@@ -149,15 +133,12 @@ def run_mode(cfg, params, mode: str, sc: dict, *, max_batch: int,
     from repro.serving import (PagedEngineBackend, PagedInferenceEngine,
                                SerializedPagedBackend)
 
-    megastep = mode in ("fused-megastep", "fused-budget")
     max_batch = sc.get("max_batch", max_batch)   # scenario override: a
     # near-empty-batch scenario measures at the batch width it describes
     eng = PagedInferenceEngine(
         cfg, params, num_blocks=num_blocks, block_size=block_size,
         max_batch=max_batch, max_len=sc["max_len"],
-        prefill_chunk=sc["chunk"], megastep=megastep,
-        token_budget=budget if mode == "fused-budget" else None,
-        mesh=mesh, obs=obs)
+        prefill_chunk=sc["chunk"], token_budget=budget, mesh=mesh, obs=obs)
     backend_cls = (SerializedPagedBackend if mode == "serialized-lanes"
                    else PagedEngineBackend)
     # every mode — including the serialized baseline — gets the exact same
@@ -234,11 +215,10 @@ def sched_live(seed: int = 0, *, max_batch: int = 8, num_blocks: int = 193,
 
     results = {}
     for name, sc in scenarios.items():
-        # the full 4-way comparison on mixed traffic; the two megastep
-        # variants head-to-head on the skewed scenarios
-        modes = (("serialized-lanes", "fused-mlfq", "fused-megastep",
-                  "fused-budget") if name == "mixed"
-                 else ("fused-megastep", "fused-budget"))
+        # serialized vs fused dispatch over one engine on mixed traffic;
+        # the fused dispatcher alone on the skewed scenarios
+        modes = (("serialized-lanes", "fused-budget") if name == "mixed"
+                 else ("fused-budget",))
         # CPU wall clocks at these sizes are noisy: run each mode several
         # times and report the per-metric median (shape-derived metrics
         # like padded_token_fraction are identical across repeats anyway);
@@ -265,16 +245,10 @@ def sched_live(seed: int = 0, *, max_batch: int = 8, num_blocks: int = 193,
             rows.append(agg)
         by = {r["Method"]: r for r in rows}
         summary = {}
-        if "fused-mlfq" in by:
+        if "serialized-lanes" in by:
             summary["fused_speedup_tokens_per_s"] = round(
-                by["fused-mlfq"]["tokens_per_s"]
+                by["fused-budget"]["tokens_per_s"]
                 / max(by["serialized-lanes"]["tokens_per_s"], 1e-9), 2)
-            summary["megastep_speedup_tokens_per_s"] = round(
-                by["fused-megastep"]["tokens_per_s"]
-                / max(by["fused-mlfq"]["tokens_per_s"], 1e-9), 2)
-        summary["budget_speedup_tokens_per_s"] = round(
-            by["fused-budget"]["tokens_per_s"]
-            / max(by["fused-megastep"]["tokens_per_s"], 1e-9), 2)
         results[name] = {"config": sc, "rows": rows, "summary": summary}
 
     payload = {
@@ -298,8 +272,10 @@ def format_tables(results: dict) -> str:
         out.append("|" + "---|" * len(hdr))
         for r in res["rows"]:
             out.append("| " + " | ".join(str(r[h]) for h in hdr) + " |")
-        out.append("summary: " + ", ".join(
-            f"{k}={v}x" for k, v in res["summary"].items()) + "\n")
+        if res["summary"]:
+            out.append("summary: " + ", ".join(
+                f"{k}={v}x" for k, v in res["summary"].items()))
+        out.append("")
     return "\n".join(out)
 
 
@@ -319,25 +295,24 @@ def check(results: dict, smoke: bool):
             if r["completed_turns"] != expect:
                 problems.append(f"{tag} completed "
                                 f"{r['completed_turns']}/{expect} turns")
-            if r["Method"] in ("fused-megastep", "fused-budget"):
-                if r["jit_dispatches_per_step"] != 1.0:
-                    problems.append(
-                        f"{tag} dispatched {r['jit_dispatches_per_step']} "
-                        "jit calls per step (must be exactly 1)")
-                # recompile guard: every dispatch width must come from the
-                # bounded bucket set, so retraces stay <= len(bucket_set)
-                extra = set(r["trace_buckets"]) - set(r["bucket_set"])
-                if extra:
-                    problems.append(f"{tag} traced widths {sorted(extra)} "
-                                    f"outside bucket set {r['bucket_set']}")
-                if len(r["trace_buckets"]) > len(r["bucket_set"]):
-                    problems.append(
-                        f"{tag} used {len(r['trace_buckets'])} trace "
-                        f"buckets > |bucket set| {len(r['bucket_set'])}")
+            if r["jit_dispatches_per_step"] != 1.0:
+                problems.append(
+                    f"{tag} dispatched {r['jit_dispatches_per_step']} "
+                    "jit calls per step (must be exactly 1)")
+            # recompile guard: every dispatch width must come from the
+            # bounded bucket set, so retraces stay <= len(bucket_set)
+            extra = set(r["trace_buckets"]) - set(r["bucket_set"])
+            if extra:
+                problems.append(f"{tag} traced widths {sorted(extra)} "
+                                f"outside bucket set {r['bucket_set']}")
+            if len(r["trace_buckets"]) > len(r["bucket_set"]):
+                problems.append(
+                    f"{tag} used {len(r['trace_buckets'])} trace "
+                    f"buckets > |bucket set| {len(r['bucket_set'])}")
     if problems:
         raise SystemExit("; ".join(problems))
     print("[sched_live] check passed: 0 zombies, all turns completed, "
-          "megastep modes at 1 jit dispatch per step, trace buckets "
+          "1 jit dispatch per step, trace buckets "
           "within the bounded pow2 set")
 
 
@@ -419,8 +394,7 @@ def run_chaos_scenario(cfg, params, name: str, sc: dict, *, seed: int,
         return PagedInferenceEngine(
             cfg, params, num_blocks=num_blocks, block_size=8,
             max_batch=max_batch, max_len=max_len,
-            prefill_chunk=sc["chunk"], megastep=True,
-            swap_store=store, obs=obs)
+            prefill_chunk=sc["chunk"], swap_store=store, obs=obs)
 
     engine = factory()
     engine.compile_buckets()
@@ -503,7 +477,7 @@ def _chaos_parity(cfg, params, sc: dict, *, smoke: bool) -> bool:
         eng = PagedInferenceEngine(
             cfg, params, num_blocks=193, block_size=8,
             max_batch=max_batch, max_len=sc["max_len"],
-            prefill_chunk=sc["chunk"], megastep=True)
+            prefill_chunk=sc["chunk"])
         eng.compile_buckets()
         be = PagedEngineBackend(eng, max_new_tokens=sc["new_tokens"],
                                 prompt_tokens=sc["prompt_tokens"],
@@ -627,7 +601,7 @@ def parity_tokens(cfg, params, mesh) -> List[int]:
 
     eng = PagedInferenceEngine(cfg, params, num_blocks=65, block_size=8,
                                max_batch=4, max_len=96, prefill_chunk=16,
-                               token_budget=16, megastep=True, mesh=mesh)
+                               token_budget=16, mesh=mesh)
     rid = eng.submit(np.arange(1, 20, dtype=np.int32), max_new_tokens=8,
                      retain=True)
     eng.run_to_completion()

@@ -16,7 +16,8 @@ that traffic (seeded, reproducible) and drives it through four dispatch
 arms at equal hardware:
 
   * ``serialized``    — the historical thread-per-lane baseline over
-                        ``SerializedPagedBackend`` (record only).
+                        ``SerializedPagedBackend``, on the same budgeted
+                        engine as the other arms (record only).
   * ``static-budget`` — the fused budgeted megastep with every knob a
                         constant: no feedback, nothing sheds. Under
                         sustained overload its queue (and therefore its
@@ -58,7 +59,7 @@ overload" meaning 3x overload everywhere.
 
 Emits ``BENCH_overload.json``. ``--check`` is the CI gate described
 above, plus: every shed is a typed ``BackpressureError`` with a finite
-``retry_after_s``, no arm fails a turn untyped, and the megastep arms'
+``retry_after_s``, no arm fails a turn untyped, and every arm's
 distinct trace buckets stay within the pre-traced pow2 set (the
 autopilot's live retuning must cause ZERO mid-run recompiles).
 """
@@ -184,13 +185,10 @@ def build_arm(arm: str, cfg, params, sc: dict, *, n_sessions: int,
 
     obs = obs or Observability()
     kw = _engine_kw(n_sessions, turns_per_session, sc)
-    megastep = arm != "serialized"
 
     def make_engine():
         return PagedInferenceEngine(
-            cfg, params, megastep=megastep,
-            token_budget=sc["budget"] if megastep else None,
-            obs=obs, **kw)
+            cfg, params, token_budget=sc["budget"], obs=obs, **kw)
 
     backend_kw = dict(max_new_tokens=sc["new_tokens"],
                       prompt_tokens=sc["prompt_tokens"])
@@ -214,7 +212,7 @@ def build_arm(arm: str, cfg, params, sc: dict, *, n_sessions: int,
 
         def factory():
             eng = PagedInferenceEngine(
-                cfg, params, megastep=True, token_budget=sc["budget"],
+                cfg, params, token_budget=sc["budget"],
                 obs=obs, swap_store=store, **kw)
             return eng
 
@@ -229,8 +227,7 @@ def build_arm(arm: str, cfg, params, sc: dict, *, n_sessions: int,
         chaos.on_rate_limit = rm.report_rate_limited
         return rm, (lambda: inner.engine), chaos
     engine = make_engine()
-    if megastep:
-        engine.compile_buckets()
+    engine.compile_buckets()
     backend_cls = (SerializedPagedBackend if arm == "serialized"
                    else PagedEngineBackend)
     rm = AgentRM(backend_cls(engine, **backend_kw),
@@ -510,12 +507,11 @@ def overload_bench(seed: int = 0, smoke: bool = False,
                     chaos.store.fail_next_put = 0
                     chaos.store.fail_next_read = 0
                 chaos.release_squat()
-            if arm != "serialized":
-                st = eng.step_stats()
-                row["trace_buckets"] = list(st["trace_buckets"])
-                row["bucket_set"] = list(st["bucket_set"])
-                row["jit_dispatches_per_step"] = round(
-                    st["jit_dispatches_per_step"], 2)
+            st = eng.step_stats()
+            row["trace_buckets"] = list(st["trace_buckets"])
+            row["bucket_set"] = list(st["bucket_set"])
+            row["jit_dispatches_per_step"] = round(
+                st["jit_dispatches_per_step"], 2)
             # leak audit: drop every retained session — anything still
             # allocated leaked
             for rid in list(eng.reqs):
@@ -587,12 +583,11 @@ def check_overload(payload: dict):
         if outcomes != r["turns_total"]:
             problems.append(f"{arm}: outcomes sum to {outcomes}, not "
                             f"{r['turns_total']} turns")
-        if arm != "serialized":
-            extra = set(r["trace_buckets"]) - set(r["bucket_set"])
-            if extra:
-                problems.append(
-                    f"{arm}: traced widths {sorted(extra)} outside the "
-                    f"pre-traced set {r['bucket_set']} (mid-run recompile)")
+        extra = set(r["trace_buckets"]) - set(r["bucket_set"])
+        if extra:
+            problems.append(
+                f"{arm}: traced widths {sorted(extra)} outside the "
+                f"pre-traced set {r['bucket_set']} (mid-run recompile)")
     static, ap = arms["static-budget"], arms["autopilot"]
     if static["shed_rejections"] != 0:
         problems.append("static-budget arm shed turns without an autopilot")

@@ -9,11 +9,12 @@ accumulates each agent's context with PSI injection; the reaper watches
 heartbeats emitted per decode step.
 
 ``--paged`` swaps the dense slot engine for the paged megastep engine
-behind the fused iteration-level dispatcher; ``--token-budget N`` turns on
-the stall-free token-budget pack (DESIGN.md §11 — decode-first, bounded
+behind the fused iteration-level dispatcher. ``--token-budget N`` sets the
+megastep's per-step token budget (DESIGN.md §11 — decode-first, bounded
 pow2 trace buckets). The budget is validated by the engine: it must be at
 least ``--max-batch`` so every active row makes progress every step, and
-it is clamped to ``max_len``. Unset keeps fixed-chunk megastep behaviour.
+it is clamped to ``max_len``. Unset (0), the engine takes ``--max-batch``
+x ``--prefill-chunk``.
 
 ``--trace-out trace.json`` records the run in the flight recorder and
 exports a Chrome trace-event file on exit (open in Perfetto / about:
@@ -287,8 +288,9 @@ def build_parser() -> argparse.ArgumentParser:
                          "of the paged kernel's (blk, head_dim) slab)")
     ap.add_argument("--prefill-chunk", type=int, default=16)
     ap.add_argument("--token-budget", type=int, default=0,
-                    help="stall-free per-step token budget (0 = fixed "
-                         "chunk); must be >= --max-batch")
+                    help="per-step token budget of the megastep (0 = the "
+                         "engine's default, max-batch x prefill-chunk); "
+                         "must be >= --max-batch")
     ap.add_argument("--mesh", default=None, metavar="tp=N",
                     help="shard the megastep tensor-parallel over N "
                          "devices (requires --paged; N must divide the "

@@ -135,111 +135,6 @@ def paged_kernel_attention(q, k_pools, v_pools, layer, cache_lens, valids,
                                       interpret=interpret)
 
 
-def gqa_decode_paged(params, x, k_pool, v_pool, page_tables, cache_len,
-                     cfg: ModelConfig, *, paged_kernel: bool = False,
-                     interpret: bool = False):
-    """Paged-cache decode: one token per sequence against pooled KV blocks.
-
-    x: (b, 1, d); k_pool/v_pool: (num_blocks, hkv, blk, hd) — one layer's
-    slice of the shared block pool; page_tables: (b, npages) int32 block ids
-    in position order (entries beyond the live length must be valid ids —
-    the engine pads with the reserved null block 0); cache_len: (b,) int32
-    per-sequence lengths *before* this token.
-
-    The new token's K/V is scattered into block ``page_tables[b, len//blk]``
-    at offset ``len % blk``; rows whose page table is all-null (inactive
-    decode slots) harmlessly write to the null block. Attention then runs
-    either through the Pallas paged kernel (``paged_kernel``: page-table
-    scalar prefetch, no contiguous cache copy) or a gather-based jnp path.
-    """
-    b = x.shape[0]
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    blk = k_pool.shape[2]
-    cache_len = jnp.asarray(cache_len)
-    q = (x @ params["wq"]).reshape(b, 1, hq, hd)
-    k = (x @ params["wk"]).reshape(b, 1, hkv, hd)
-    v = (x @ params["wv"]).reshape(b, 1, hkv, hd)
-    if cfg.rotary_pct > 0:
-        pos = cache_len.reshape(b, 1)
-        rot = int(hd * cfg.rotary_pct)
-        cos, sin = rope_tables(pos, rot, cfg.rope_theta)
-        q = apply_rope(q, cos, sin, cfg.rotary_pct)
-        k = apply_rope(k, cos, sin, cfg.rotary_pct)
-    rows = jnp.arange(b)
-    bids = page_tables[rows, cache_len // blk]
-    offs = cache_len % blk
-    k_pool = k_pool.at[bids, :, offs].set(k[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[bids, :, offs].set(v[:, 0].astype(v_pool.dtype))
-    if paged_kernel:
-        o = paged_kernel_attention(q, k_pool[None], v_pool[None], 0,
-                                   cache_len, jnp.ones_like(cache_len),
-                                   page_tables, cfg, interpret=interpret)
-    else:
-        from repro.kernels.paged_attention.ref import gather_pages
-        kg = gather_pages(k_pool, page_tables).astype(q.dtype)
-        vg = gather_pages(v_pool, page_tables).astype(q.dtype)
-        pairing = "g_major" if cfg.gqa_mode == "tiled" else "kv_major"
-        o = simple_attention(q, kg, vg, causal=False, kv_len=cache_len + 1,
-                             f32_inputs=cfg.attn_f32_inputs, pairing=pairing)
-    out = o.reshape(b, 1, hq * hd) @ params["wo"]
-    return out, (k_pool, v_pool)
-
-
-def gqa_prefill_chunk_paged(params, x, k_pool, v_pool, page_table, cache_len,
-                            valid, cfg: ModelConfig, *,
-                            paged_kernel: bool = False):
-    """Sarathi-style chunked prefill against a paged cache: a fixed-width
-    window of ``C`` prompt tokens for ONE sequence is processed in a single
-    call, attending causally within the chunk and fully over the sequence's
-    already-written pages.
-
-    x: (1, C, d) chunk embeddings; k_pool/v_pool: (num_blocks, hkv, blk, hd)
-    one layer's pool slice; page_table: (npages,) int32 block ids in position
-    order (null-padded); cache_len: scalar int32 tokens already resident
-    *before* this chunk; valid: scalar int32 — how many of the C positions
-    are real (the tail of a prompt rarely fills the chunk width; padding
-    rows write to the reserved null block 0 and are masked out of
-    attention, so one traced shape serves every chunk).
-    """
-    b, C, _ = x.shape
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    blk = k_pool.shape[2]
-    npages = page_table.shape[0]
-    cache_len = jnp.asarray(cache_len)
-    valid = jnp.asarray(valid)
-    pos = cache_len + jnp.arange(C)
-    q = (x @ params["wq"]).reshape(b, C, hq, hd)
-    k = (x @ params["wk"]).reshape(b, C, hkv, hd)
-    v = (x @ params["wv"]).reshape(b, C, hkv, hd)
-    if cfg.rotary_pct > 0:
-        rot = int(hd * cfg.rotary_pct)
-        cos, sin = rope_tables(pos, rot, cfg.rope_theta)
-        q = apply_rope(q, cos, sin, cfg.rotary_pct)
-        k = apply_rope(k, cos, sin, cfg.rotary_pct)
-    # scatter the chunk's K/V into the sequence's blocks; padding positions
-    # (and any position past the table) land in null block 0
-    live = jnp.arange(C) < valid
-    page_idx = jnp.clip(pos // blk, 0, npages - 1)
-    bids = jnp.where(live, page_table[page_idx], 0)
-    offs = pos % blk
-    k_pool = k_pool.at[bids, :, offs].set(k[0].astype(k_pool.dtype))
-    v_pool = v_pool.at[bids, :, offs].set(v[0].astype(v_pool.dtype))
-    if paged_kernel:
-        o = paged_kernel_attention(q, k_pool[None], v_pool[None], 0,
-                                   cache_len[None], valid[None],
-                                   page_table[None], cfg)
-    else:
-        from repro.kernels.paged_attention.ref import gather_pages
-        kg = gather_pages(k_pool, page_table[None]).astype(q.dtype)
-        vg = gather_pages(v_pool, page_table[None]).astype(q.dtype)
-        pairing = "g_major" if cfg.gqa_mode == "tiled" else "kv_major"
-        o = simple_attention(q, kg, vg, causal=True, q_offset=cache_len,
-                             kv_len=cache_len + valid,
-                             f32_inputs=cfg.attn_f32_inputs, pairing=pairing)
-    out = o.reshape(b, C, hq * hd) @ params["wo"]
-    return out, (k_pool, v_pool)
-
-
 def gqa_mixed_step_paged(params, x, k_pool, v_pool, layer, page_tables,
                          cache_lens, valids, cfg: ModelConfig, *,
                          paged_kernel: bool = False, interpret: bool = False,

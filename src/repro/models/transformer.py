@@ -15,7 +15,7 @@ API (functions returned by ``repro.models.model.build``):
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -294,60 +294,6 @@ def init_paged_pools(cfg: ModelConfig, num_blocks: int,
     hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     shape = (cfg.n_layers, num_blocks, hkv, block_size, hd)
     return {"k": jnp.zeros(shape, ct), "v": jnp.zeros(shape, ct)}
-
-
-def decode_step_paged(params, pools: Dict, token, cache_len, page_tables,
-                      cfg: ModelConfig, *, paged_kernel: bool = False):
-    """Paged analogue of ``decode_step``: token (b, 1), cache_len (b,) int32
-    lengths before this token, page_tables (b, npages) int32.
-    ``paged_kernel`` selects the Pallas attention kernel (see
-    ``mixed_step_paged``). Returns (logits (b, 1, V) f32, updated pools)."""
-    params = cast_floats(params, cfg.compute_dtype)
-    x = _embed(params, token, cfg)
-
-    def body(h, xs):
-        lp, kp, vp = xs
-        hh = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-        a, (kp, vp) = attn_mod.gqa_decode_paged(
-            lp["attn"], hh, kp, vp, page_tables, cache_len, cfg,
-            paged_kernel=paged_kernel)
-        h = h + a
-        m, _, _ = _mlp_or_moe(lp, h, cfg)
-        return h + m, (kp, vp)
-
-    x, (k, v) = jax.lax.scan(body, x, (params["layers"], pools["k"],
-                                       pools["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return _unembed(params, x, cfg), {"k": k, "v": v}
-
-
-def prefill_chunk_paged(params, pools: Dict, tokens, cache_len, valid,
-                        page_table, cfg: ModelConfig, *,
-                        paged_kernel: bool = False):
-    """Chunked prefill for one sequence over the paged pools (Sarathi-style
-    admission: a long prompt enters the batch ``C`` tokens per engine step
-    instead of blocking it). tokens: (1, C) int32 (null-padded to the fixed
-    chunk width), cache_len/valid: scalar int32, page_table: (npages,) int32.
-    Returns (logits (1, C, V) f32 — caller reads position ``valid - 1``,
-    updated pools). The chunk's K/V is written into the sequence's pages, so
-    after the call the cache holds positions [0, cache_len + valid)."""
-    params = cast_floats(params, cfg.compute_dtype)
-    x = _embed(params, tokens, cfg)
-
-    def body(h, xs):
-        lp, kp, vp = xs
-        hh = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-        a, (kp, vp) = attn_mod.gqa_prefill_chunk_paged(
-            lp["attn"], hh, kp, vp, page_table, cache_len, valid, cfg,
-            paged_kernel=paged_kernel)
-        h = h + a
-        m, _, _ = _mlp_or_moe(lp, h, cfg)
-        return h + m, (kp, vp)
-
-    x, (k, v) = jax.lax.scan(body, x, (params["layers"], pools["k"],
-                                       pools["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return _unembed(params, x, cfg), {"k": k, "v": v}
 
 
 def mixed_step_paged(params, pools: Dict, tokens, cache_lens, valids,

@@ -154,8 +154,7 @@ class SLOAutopilot:
             self.obs = obs
         rungs = [len(e.budget_rungs())
                  for e in _live_engines(backend)
-                 if getattr(e, "token_budget", None) is not None
-                 and hasattr(e, "budget_rungs")]
+                 if hasattr(e, "budget_rungs")]
         self._budget_steps = max(rungs) - 1 if rungs else 0
         m = self._metrics()
         if m is not None:
@@ -273,7 +272,7 @@ class SLOAutopilot:
     # ----------------------------------------------------------- actions
     def _apply_budgets(self):
         """Install the current severity's token budget on every live
-        budgeted engine: ``steps_down`` buckets below its full budget,
+        paged engine: ``steps_down`` buckets below its full budget,
         floored at its own decode-first rung — but ONLY while a latency
         SLO is actually breached (a queue-only breach leaves the budget
         at full: see ``latency_breached``). Idempotent; always within
@@ -281,8 +280,7 @@ class SLOAutopilot:
         steps_down = (min(self.severity, self._budget_steps)
                       if self.latency_breached else 0)
         for eng in _live_engines(self._backend):
-            if getattr(eng, "token_budget", None) is None \
-                    or not hasattr(eng, "budget_rungs"):
+            if not hasattr(eng, "budget_rungs"):
                 continue
             ladder = eng.budget_rungs()
             if not ladder:

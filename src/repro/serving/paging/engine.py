@@ -9,19 +9,17 @@ Differences from the dense ``repro.serving.engine.InferenceEngine``:
     can far exceed what slot-granularity admission could hold at equal
     memory.
   * Prefill is **chunked** (Sarathi-style): a prompt enters the decode
-    batch immediately and is written ``prefill_chunk`` tokens per step
-    while its batchmates keep decoding — a long prompt never stalls the
-    batch, and admission only needs blocks for the first chunk. New-turn
-    prompt tokens on a retained session (``extend``) ride the same path,
-    so multi-turn extension costs O(plen / chunk) steps, not O(plen).
+    batch immediately and is written in budgeted chunks while its
+    batchmates keep decoding — a long prompt never stalls the batch, and
+    admission only needs blocks for the first chunk. New-turn prompt
+    tokens on a retained session (``extend``) ride the same path, so
+    multi-turn extension costs O(plen / chunk) steps, not O(plen).
   * The iteration is **one jitted megastep**: decode rows and prefill
     chunks are fused into a single (max_batch, C) token matrix — decode
     rows are width-1 prefill rows — and greedy sampling runs inside the
     jit, so one dispatch and one (max_batch,) int32 transfer advance the
-    whole batch (see DESIGN.md §10). The PR 2 loop (a dispatch per
-    prefilling sequence + a decode call) survives behind ``megastep=False``
-    as the benchmark baseline.
-  * With a ``token_budget`` the megastep is **stall-free** (Sarathi's
+    whole batch (see DESIGN.md §10).
+  * The megastep is **stall-free** under a ``token_budget`` (Sarathi's
     token-budget scheduler, DESIGN.md §11): every iteration is packed
     decode-first (one token per decoding row), then the remaining budget
     is split across prefilling rows as *variable-width* chunks — a lone
@@ -30,8 +28,9 @@ Differences from the dense ``repro.serving.engine.InferenceEngine``:
     so prefill work can never balloon a batchmate's inter-token latency.
     The dispatch width C is the packed maximum row width rounded up to a
     small pow2 bucket set ({1, 8, 16, ..., budget}) so jit retraces stay
-    bounded at ``len(bucket_set)``. Unset (None) keeps the PR 3 fixed
-    two-bucket behaviour (C in {1, prefill_chunk}).
+    bounded at ``len(bucket_set)``. Without an explicit budget the engine
+    takes ``max_batch * prefill_chunk`` (clamped to ``max_len``): every
+    row of a full batch can still advance one whole chunk per step.
   * Sessions are first-class. A finished request may be *retained*
     (parked): its pages stay resident and evictable, and a later turn
     ``extend``s it. ``fork`` shares a session's pages copy-on-write, and
@@ -151,7 +150,6 @@ class PagedInferenceEngine:
                  max_len: int = 256, prefill_chunk: int = 32,
                  token_budget: Optional[int] = None,
                  swap_store: Optional[KVSwapStore] = None,
-                 megastep: bool = True,
                  mesh=None,
                  obs: Optional[Observability] = None,
                  name: str = "engine"):
@@ -165,22 +163,17 @@ class PagedInferenceEngine:
         self.name = name
         self.model = build(cfg)
         # ---- tensor-parallel mesh (DESIGN.md §13) ------------------------
-        # mesh=None is the single-device engine, bit-for-bit the PR 3/4
-        # megastep. With a ("tp",) mesh the megastep becomes ONE
-        # shard_map-wrapped dispatch: KV pools sharded over the hkv axis,
-        # row inputs replicated, one psum per layer. Validation raises
-        # ValueError so launchers surface mesh-shape mistakes as CLI
-        # errors, never as shard_map tracebacks mid-trace.
+        # mesh=None is the single-device megastep. With a ("tp",) mesh the
+        # megastep becomes ONE shard_map-wrapped dispatch: KV pools sharded
+        # over the hkv axis, row inputs replicated, one psum per layer.
+        # Validation raises ValueError so launchers surface mesh-shape
+        # mistakes as CLI errors, never as shard_map tracebacks mid-trace.
         self.mesh = mesh
         if mesh is not None:
             if TP not in dict(mesh.shape):
                 raise ValueError(
                     f"mesh axes {tuple(mesh.axis_names)} lack the '{TP}' "
                     "axis the sharded megastep partitions heads over")
-            if not megastep:
-                raise ValueError(
-                    "mesh requires the megastep (megastep=True): the "
-                    "legacy per-sequence dispatch loop is single-device")
             validate_tp(cfg, mesh.shape[TP])
         self.tp = mesh.shape[TP] if mesh is not None else 1
         # ---- the device this engine runs on, and its attention path -----
@@ -214,36 +207,26 @@ class PagedInferenceEngine:
         # budget >= max_batch guarantees the decode-first pack always fits
         # every decoding row AND leaves >= 1 token for every prefilling row
         # (n_decode + n_prefill <= max_batch <= budget), so no active row
-        # ever starves. None keeps the PR 3 fixed-chunk behaviour.
-        if token_budget is not None:
-            if token_budget < max_batch:
-                raise ValueError(
-                    f"token_budget {token_budget} < max_batch {max_batch}: "
-                    "the decode-first pack needs one token per batch row "
-                    "to keep every active sequence stall-free")
-            token_budget = min(token_budget, self.max_len)
-            self.bucket_set = budget_buckets(token_budget)
-        else:
-            # legacy two-bucket megastep: C in {1, prefill_chunk}
-            self.bucket_set = tuple(
-                dict.fromkeys((1, self.prefill_chunk)))
-        self.token_budget = token_budget
+        # ever starves. Unset, it is max_batch * prefill_chunk: every row
+        # of a full batch can advance one whole chunk per step.
+        if token_budget is None:
+            token_budget = max_batch * self.prefill_chunk
+        elif token_budget < max_batch:
+            raise ValueError(
+                f"token_budget {token_budget} < max_batch {max_batch}: "
+                "the decode-first pack needs one token per batch row "
+                "to keep every active sequence stall-free")
+        self.token_budget = min(token_budget, self.max_len)
+        self.bucket_set = budget_buckets(self.token_budget)
         # admission reserves blocks for the FIRST dispatch's worth of prompt
         # only; with a budget smaller than the chunk that is the budget —
-        # reserving chunk-width blocks would over-reserve (issue #4 sat. 1)
-        self.first_chunk_cap = (min(self.prefill_chunk, token_budget)
-                                if token_budget else self.prefill_chunk)
+        # reserving chunk-width blocks would over-reserve
+        self.first_chunk_cap = min(self.prefill_chunk, self.token_budget)
         self.cache = PagedKVCache(cfg, num_blocks, block_size, mesh=mesh,
                                   device=self.device)
         self.swap = SwapManager(self.cache, swap_store,
                                 on_evict=self._on_evicted)
         self.max_pages = self.cache.pages_for(self.max_len)
-        # megastep=True (default): ONE jitted dispatch per engine iteration
-        # — decode tokens and prefill chunks fused into a single (B, C)
-        # forward with in-jit greedy sampling. megastep=False keeps the
-        # PR 2 loop (one _chunk dispatch per prefilling sequence plus a
-        # separate _decode call) as the benchmark baseline / fallback.
-        self.use_megastep = megastep
 
         self.reqs: Dict[int, PagedRequest] = {}
         self.active: Dict[int, PagedRequest] = {}
@@ -331,8 +314,6 @@ class PagedInferenceEngine:
         self._ev_psum = rec.name(
             "collective.psum",
             ("tp", "psums", "bytes_per_shard", "shard_tokens_dispatched"))
-        self._ev_legacy = rec.name("engine.step.legacy",
-                                   ("dispatches", "tokens_real"))
         self._ev_row = rec.name("row.work", ("rid", "tokens", "prefill"))
         self._ev_enq = rec.name("session.enqueued", ("rid", "pending"))
         self._ev_queued = rec.name("session.queued", ("rid",))
@@ -349,17 +330,6 @@ class PagedInferenceEngine:
         self._ev_finish = rec.name("session.finished",
                                    ("rid", "out_tokens"))
 
-        kern = self.paged_kernel
-        self._decode = jax.jit(
-            lambda params, pools, tok, lens, tables:
-            tr.decode_step_paged(params, pools, tok, lens, tables, cfg,
-                                 paged_kernel=kern),
-            donate_argnums=(1,))
-        self._chunk = jax.jit(
-            lambda params, pools, toks, n, t, table:
-            tr.prefill_chunk_paged(params, pools, toks, n, t, table, cfg,
-                                   paged_kernel=kern),
-            donate_argnums=(1,))
         self._mega = self._build_mega()
 
     def _build_mega(self):
@@ -410,8 +380,6 @@ class PagedInferenceEngine:
         ``compile_seconds`` records each bucket's set-up wall time: trace,
         compile (or a persistent-cache load) and one dummy dispatch;
         ``kernel_plans`` the paged kernel's tiling there, where it runs."""
-        if not self.use_megastep:
-            return
         for C in self.bucket_set:
             if C in self.compiled_buckets:
                 continue
@@ -454,10 +422,6 @@ class PagedInferenceEngine:
         overload autopilot shrinking toward decode-first can never starve
         an active row. Returns the budget actually installed.
         """
-        if self.token_budget is None:
-            raise ValueError(
-                "set_token_budget requires a budgeted megastep engine "
-                "(constructed with token_budget=...)")
         budget = int(budget)
         if budget not in self.bucket_set:
             raise ValueError(
@@ -783,14 +747,11 @@ class PagedInferenceEngine:
 
     # ------------------------------------------------------------- step
     def step(self) -> List[PagedRequest]:
-        """Advance the batch one iteration: every prefilling sequence takes
-        one prompt chunk, every decoding sequence one token. Returns
-        requests whose turn finished this step; per-rid service counts (in
-        tokens) land in ``last_serviced``.
-
-        With ``megastep`` (the default) the whole iteration is ONE jitted
-        dispatch; the legacy path (one dispatch per prefilling sequence plus
-        a decode call) is kept as the benchmark baseline."""
+        """Advance the batch one iteration in ONE jitted dispatch: every
+        decoding sequence takes one token, every prefilling sequence a
+        share of the remaining token budget. Returns requests whose turn
+        finished this step; per-rid service counts (in tokens) land in
+        ``last_serviced``."""
         self.last_serviced = {}
         self.last_failures = []
         # the megastep's flight-recorder span opens before admission
@@ -800,10 +761,7 @@ class PagedInferenceEngine:
             return []
         t0 = time.perf_counter()
         before = self._c_jit.value
-        if self.use_megastep:
-            fins = self._step_megastep(t0, t_admit)
-        else:
-            fins = self._step_legacy(t0)
+        fins = self._step_megastep(t0, t_admit)
         if self._c_jit.value != before:     # a work-doing iteration
             self.h_step.observe(time.perf_counter() - t0)
         return fins
@@ -974,10 +932,7 @@ class PagedInferenceEngine:
     def _pack_rows(self) -> List[tuple]:
         """Assemble one iteration's (req, T) rows.
 
-        Without a budget this is the PR 3 fixed-chunk pack: every
-        prefilling row takes ``min(prefill_chunk, pending)``.
-
-        With a ``token_budget`` the pack is **decode-first** (DESIGN.md
+        The pack is **decode-first** under the ``token_budget`` (DESIGN.md
         §11): decoding rows are packed first at one token each — decode is
         never stalled or rationed — then the remaining budget is split
         evenly across prefilling rows (ceil-divided over the rows still
@@ -986,18 +941,8 @@ class PagedInferenceEngine:
         covers at least one token per prefilling row: no active row is
         ever skipped, the total never exceeds the budget."""
         rows: List[tuple] = []
-        budget = self.token_budget
-        if budget is None:
-            for req in list(self.active.values()):
-                if req.prefilling:
-                    T = min(self.prefill_chunk, len(req.pending))
-                    if self._grown(req, req.num_tokens + T):
-                        rows.append((req, T))
-                elif self._grown(req, req.num_tokens + 1):
-                    rows.append((req, 1))
-            return rows
         prefilling: List[PagedRequest] = []
-        remaining = budget
+        remaining = self.token_budget
         for req in list(self.active.values()):
             if req.prefilling:
                 prefilling.append(req)
@@ -1024,10 +969,10 @@ class PagedInferenceEngine:
                 remaining -= T
         return rows
 
-    def _step_megastep(self, t0: float = 0.0,
-                       t_admit: float = 0.0) -> List[PagedRequest]:
+    def _step_megastep(self, t0: float,
+                       t_admit: float) -> List[PagedRequest]:
         """The fused iteration: pack one (max_batch, C) token matrix
-        (decode-first under a token budget — see ``_pack_rows``), run ONE
+        (decode-first under the token budget — see ``_pack_rows``), run ONE
         jitted forward over the union (K/V scatter, paged attention, greedy
         sampling all inside), and read back a single (max_batch,) int32
         token vector. C is the packed maximum row width rounded up to the
@@ -1048,9 +993,7 @@ class PagedInferenceEngine:
         rows = self._pack_rows()             # (req, T) surviving growth
         if not rows:
             return finished
-        C = self._bucket_for(max(T for _, T in rows)) \
-            if self.token_budget else \
-            (self.prefill_chunk if any(r.prefilling for r, _ in rows) else 1)
+        C = self._bucket_for(max(T for _, T in rows))
         self.trace_buckets.add(C)
         step_real = sum(T for _, T in rows)
         self.tokens_real += step_real
@@ -1141,91 +1084,6 @@ class PagedInferenceEngine:
                             self.max_batch * C)
         return finished
 
-    def _step_legacy(self, t0: float = 0.0) -> List[PagedRequest]:
-        """PR 2 iteration shape: one jitted ``_chunk`` call per prefilling
-        sequence, then one batched ``_decode`` call — 1 + n_prefilling
-        dispatches per step, full (B, vocab) logits crossing to host."""
-        finished: List[PagedRequest] = []
-        decoding = [r for r in self.active.values() if not r.prefilling]
-        prefilling = [r for r in self.active.values() if r.prefilling]
-        dispatches_before = self.jit_dispatches
-        tokens_before = self.tokens_real
-        rec = self.obs.recorder
-
-        # ---- chunked prefill: one block of prompt per sequence per step
-        for req in prefilling:
-            T = min(self.prefill_chunk, len(req.pending))
-            n = req.num_tokens
-            if not self._grown(req, n + T):
-                continue
-            buf = np.zeros((1, self.prefill_chunk), np.int32)
-            buf[0, :T] = req.pending[:T]
-            row = np.asarray(req.table.padded(self.max_pages), np.int32)
-            tc0 = time.perf_counter() if rec.enabled else 0.0
-            logits, pools = self._chunk(
-                self.params, self.cache.pools(), jnp.asarray(buf),
-                jnp.int32(n), jnp.int32(T), jnp.asarray(row))
-            self.cache.set_pools(pools)
-            self.jit_dispatches += 1
-            self.tokens_real += T
-            self.tokens_dispatched += self.prefill_chunk
-            req.table.num_tokens = n + T
-            del req.pending[:T]
-            if rec.enabled:
-                rec.complete(self._ev_prefill, self._sess_track(req.rid),
-                             tc0, req.rid, T, req.num_tokens)
-            if req.fresh_turn:
-                # only the original prompt's write window may feed the
-                # dedup index — extend turns write non-prompt tokens
-                self.cache.register_prefix(req.prompt, req.table,
-                                           req.num_tokens)
-            self.last_serviced[req.rid] = T
-            if not req.pending:
-                row = np.asarray(logits[0, T - 1])
-                if req.rid in self._poison_rids or not np.isfinite(row).all():
-                    self._fail_poisoned(req)
-                else:
-                    self._finish_token(req, int(row.argmax()), finished)
-
-        # ---- decode: one token for every sequence past prefill
-        decoding = [r for r in decoding
-                    if self._grown(r, r.num_tokens + 1)]
-        if decoding:
-            lens = np.zeros((self.max_batch,), np.int32)
-            tables = np.full((self.max_batch, self.max_pages), NULL_BLOCK,
-                             np.int32)
-            toks = np.zeros((self.max_batch, 1), np.int32)
-            for req in decoding:
-                lens[req.slot] = req.num_tokens
-                tables[req.slot] = req.table.padded(self.max_pages)
-                toks[req.slot, 0] = req.last_tok
-            logits, pools = self._decode(
-                self.params, self.cache.pools(), jnp.asarray(toks),
-                jnp.asarray(lens), jnp.asarray(tables))
-            self.cache.set_pools(pools)
-            self.jit_dispatches += 1
-            self.decode_steps += 1
-            self.tokens_real += len(decoding)
-            self.tokens_dispatched += self.max_batch
-            rows_np = np.asarray(logits[:, 0])
-            out = rows_np.argmax(axis=-1)
-            row_ok = np.isfinite(rows_np).all(axis=-1)
-            for req in decoding:
-                req.table.num_tokens += 1
-                if req.rid in self._poison_rids or not row_ok[req.slot]:
-                    self._fail_poisoned(req)
-                    continue
-                self.last_serviced[req.rid] = \
-                    self.last_serviced.get(req.rid, 0) + 1
-                self._finish_token(req, int(out[req.slot]), finished)
-        dispatched = self.jit_dispatches - dispatches_before
-        if dispatched:
-            self.steps_dispatched += 1
-            if rec.enabled:
-                rec.complete(self._ev_legacy, self._tr_step, t0, dispatched,
-                             self.tokens_real - tokens_before)
-        return finished
-
     def _retire(self, req: PagedRequest):
         """Turn complete: park a retained session, free everything else."""
         req.done = True
@@ -1311,8 +1169,9 @@ class PagedInferenceEngine:
 
     @property
     def jit_dispatches_per_step(self) -> float:
-        """Jitted model calls per work-doing iteration — 1.0 under the
-        megastep, 1 + mean(n_prefilling) under the legacy loop."""
+        """Jitted model calls per work-doing iteration: 1.0 by construction
+        (one megastep per ``step()``), kept as a guard against a second
+        dispatch creeping into the iteration."""
         return self.jit_dispatches / max(self.steps_dispatched, 1)
 
     @property
@@ -1320,7 +1179,7 @@ class PagedInferenceEngine:
         """Share of dispatched token slots that carried padding instead of
         real work: 1 - real / (rows x width summed over dispatches). This
         is the FLOP overhead the budget packer's right-sized buckets exist
-        to shrink (a fixed chunk pays it on every decode row whenever any
+        to shrink (a wide bucket pays it on every decode row whenever a
         batchmate is prefilling)."""
         if not self.tokens_dispatched:
             return 0.0

@@ -34,7 +34,7 @@ CFG = get_smoke_config("gemma-2b").replace(
     remat=False, n_layers=2, d_model=64, n_heads=8, n_kv_heads=4,
     head_dim=16, d_ff=128, vocab_size=256, compute_dtype="float32")
 ENGINE_KW = dict(num_blocks=65, block_size=8, max_batch=4, max_len=96,
-                 prefill_chunk=16, token_budget=16, megastep=True)
+                 prefill_chunk=16, token_budget=16)
 PROMPT = np.arange(1, 20, dtype=np.int32)
 EXTEND = np.arange(30, 38, dtype=np.int32)
 

@@ -65,7 +65,7 @@ def test_histogram_windowed_quantile_and_abstention():
 
 def test_set_token_budget_stays_within_pretraced_buckets(setup):
     cfg, params = setup
-    eng = _paged(cfg, params, token_budget=32, megastep=True)
+    eng = _paged(cfg, params, token_budget=32)
     assert eng.budget_rungs() == (8, 16, 32)
     eng.set_token_budget(8)
     assert eng.token_budget == 8 and eng.first_chunk_cap == 8
@@ -82,8 +82,7 @@ def test_budget_swap_causes_no_recompiles(setup):
     """Retuning mid-run must keep every traced width inside the fixed
     pow2 bucket set — the zero-recompile contract of the tentpole."""
     cfg, params = setup
-    eng = _paged(cfg, params, token_budget=32, megastep=True,
-                 prefill_chunk=16)
+    eng = _paged(cfg, params, token_budget=32, prefill_chunk=16)
     eng.compile_buckets()
     eng.submit(np.arange(1, 20) % 50, max_new_tokens=4)
     eng.run_to_completion()
@@ -290,7 +289,7 @@ def test_overloaded_rm_sheds_typed_backpressure(setup):
     submissions fail with BackpressureError + finite retry_after_s, while
     already-admitted turns still complete (shed touches only the edge)."""
     cfg, params = setup
-    eng = _paged(cfg, params, token_budget=32, megastep=True)
+    eng = _paged(cfg, params, token_budget=32)
     eng.compile_buckets()
     # shed_queue_floor=0: this test drives one turn at a time, so the
     # queue is empty at submit — force the binary valve to probe the
@@ -403,7 +402,7 @@ def test_turn_timeout_expiry_frees_blocks_and_raises_typed(setup):
     TurnCancelled, and the aborted turn's KV blocks are RELEASED (not
     orphaned) so the engine ends the run with an empty allocator."""
     cfg, params = setup
-    eng = _paged(cfg, params, token_budget=32, megastep=True)
+    eng = _paged(cfg, params, token_budget=32)
     eng.compile_buckets()
     be = _SlowStepBackend(PagedEngineBackend(eng, max_new_tokens=32))
     rm = AgentRM(be, AgentRMConfig(lanes=2, detect_after_s=60.0))

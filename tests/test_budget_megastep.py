@@ -1,8 +1,8 @@
 """Token-budget megastep tests (DESIGN.md §11): decode-first packing
 invariants (budget never exceeded, decode rows always serviced, no active
-row ever starved), bounded pow2 trace buckets, bucketed-C ≡ fixed-chunk
-token parity at f32, budget-aware admission accounting, a lone prompt
-burning the whole budget in one step, and budget validation."""
+row ever starved), bounded pow2 trace buckets, token parity across budget
+widths at f32, budget-aware admission accounting, a lone prompt burning
+the whole budget in one step, budget validation and the default budget."""
 import jax
 import numpy as np
 import pytest
@@ -58,6 +58,35 @@ def test_budget_validation(setup):
     assert eng.token_budget == 96
 
 
+def test_default_budget_gives_every_row_a_chunk(setup):
+    """An engine built without a budget takes max_batch * prefill_chunk,
+    clamped to max_len: every row of a full batch can advance one whole
+    chunk per step, and admission still reserves one chunk. The serving
+    CLI without --token-budget builds the same engine."""
+    from repro.launch.serve import build_backend, build_parser
+
+    cfg, params = setup
+    for kw in (dict(max_batch=4, prefill_chunk=16, max_len=96),
+               dict(max_batch=2, prefill_chunk=8, max_len=96),
+               dict(max_batch=4, prefill_chunk=32, max_len=96)):
+        eng = _paged(cfg, params, **kw)
+        assert isinstance(eng.token_budget, int)
+        assert eng.token_budget == min(
+            kw["max_batch"] * kw["prefill_chunk"], eng.max_len)
+        assert eng.bucket_set == budget_buckets(eng.token_budget)
+        assert eng.first_chunk_cap == eng.prefill_chunk
+
+    args = build_parser().parse_args(
+        ["--arch", "gemma-2b", "--smoke", "--paged", "--max-batch", "2",
+         "--prefill-chunk", "8", "--max-len", "64", "--num-blocks", "17",
+         "--block-size", "8"])
+    served, _ = build_backend(cfg, params, args)
+    assert served.token_budget == 16                 # 2 rows x 8-token chunk
+    assert served.bucket_set == budget_buckets(16)
+    assert served.first_chunk_cap == 8
+    assert served.compiled_buckets == set(served.bucket_set)
+
+
 # --------------------------------------------------------- packing rules
 
 def test_budget_packing_invariants(setup):
@@ -84,8 +113,8 @@ def test_budget_packing_invariants(setup):
 
 def test_lone_prompt_burns_whole_budget_in_one_step(setup):
     """An empty batch gives its whole budget to the one prefilling row —
-    the fixed-chunk engine needs ceil(plen/chunk) steps for the same
-    prompt."""
+    at a chunk-sized budget the same prompt needs ceil(plen/chunk)
+    steps."""
     cfg, params = setup
     eng = _paged(cfg, params, token_budget=32, prefill_chunk=8)
     r = eng.submit(np.arange(30) % 50, max_new_tokens=2)
@@ -93,7 +122,7 @@ def test_lone_prompt_burns_whole_budget_in_one_step(setup):
     assert eng.last_serviced[r] == 30          # whole prompt, one step
     assert max(eng.trace_buckets) == 32        # bucket_for(30) -> 32
 
-    fixed = _paged(cfg, params, prefill_chunk=8)
+    fixed = _paged(cfg, params, token_budget=8, prefill_chunk=8)
     rf = fixed.submit(np.arange(30) % 50, max_new_tokens=2)
     chunks = 0
     while fixed.reqs[rf].prefilling:
@@ -136,11 +165,11 @@ def test_trace_buckets_bounded_one_dispatch(setup):
     assert eng.jit_dispatches == eng.steps_dispatched > 0
 
 
-def test_budget_equals_fixed_chunk_tokens_at_f32(setup):
-    """At f32 compute the bucketed-width pack is the same model as the
-    fixed-chunk megastep: identical greedy tokens, token for token, across
-    a mixed submit+extend run (same caveat as megastep-vs-legacy: bf16
-    rounds differently across batch shapes)."""
+def test_budget_widths_give_equal_tokens_at_f32(setup):
+    """At f32 compute the budget changes only the pace, not the model:
+    the default budget, an odd one and a whole-prompt one give identical
+    greedy tokens, token for token, across a mixed submit+extend run
+    (bf16 rounds differently across batch shapes)."""
     cfg, _ = setup
     cfg32 = cfg.replace(compute_dtype="float32")
     params32 = build(cfg32).init_params(jax.random.PRNGKey(0))
@@ -155,9 +184,9 @@ def test_budget_equals_fixed_chunk_tokens_at_f32(setup):
         eng.run_to_completion()
         return {r: eng.reqs[r].out_tokens for r in rids}
 
-    fixed = run(None)
-    assert run(13) == fixed                 # odd budget, ragged buckets
-    assert run(96) == fixed                 # whole-prompt-at-once budget
+    default = run(None)                     # max_batch x prefill_chunk
+    assert run(13) == default               # odd budget, ragged buckets
+    assert run(96) == default               # whole-prompt-at-once budget
 
 
 # ------------------------------------------------------------- admission
@@ -165,18 +194,18 @@ def test_budget_equals_fixed_chunk_tokens_at_f32(setup):
 def test_can_admit_accounts_for_budget_not_chunk(setup):
     """With token_budget < prefill_chunk the first dispatch can write at
     most budget tokens, so admission must only reserve budget-sized
-    first-chunk blocks — the fixed-chunk reservation would bounce a prompt
+    first-chunk blocks — a chunk-width reservation would bounce a prompt
     the engine can actually take."""
     cfg, params = setup
     # 3 usable blocks; a hot 16-token sequence holds 2 -> 1 block free
     kw = dict(num_blocks=4, block_size=8, max_batch=2, max_len=30,
               prefill_chunk=16)
-    fixed = _paged(cfg, params, **kw)
-    hot = fixed.submit(np.arange(15) % 50, max_new_tokens=2)
-    fixed.step()                      # whole 15-token prompt in one chunk
-    assert fixed.reqs[hot].state == "active"
-    assert fixed.cache.allocator.num_free == 1
-    assert not fixed.can_admit(16)    # chunk needs 2 pages, only 1 free
+    wide = _paged(cfg, params, **kw)  # default budget, chunk cap 16
+    hot = wide.submit(np.arange(15) % 50, max_new_tokens=2)
+    wide.step()                       # whole 15-token prompt in one chunk
+    assert wide.reqs[hot].state == "active"
+    assert wide.cache.allocator.num_free == 1
+    assert not wide.can_admit(16)     # chunk needs 2 pages, only 1 free
 
     budget = _paged(cfg, params, token_budget=8, **kw)
     hot = budget.submit(np.arange(15) % 50, max_new_tokens=2)

@@ -149,7 +149,8 @@ def test_chunked_prefill_interleaves_with_decode(setup):
     assert one.last_serviced[r1] == 40   # whole prompt in one chunk
     oneshot = {r.rid: r.out_tokens for r in one.run_to_completion()}[r1]
 
-    eng = _paged(cfg, params, num_blocks=33, prefill_chunk=8)
+    # budget 9 = one decode token + one 8-token chunk per step
+    eng = _paged(cfg, params, num_blocks=33, prefill_chunk=8, token_budget=9)
     short = eng.submit((np.arange(5) + 20) % 50, max_new_tokens=12)
     eng.step()                           # short: prefilled + first token
     long = eng.submit(np.arange(40) % 50, max_new_tokens=4)

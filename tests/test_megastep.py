@@ -1,8 +1,9 @@
 """Megastep tests: chunked-prefill Pallas kernel parity (bitwise vs the
 gathered-view oracle, fp32 tolerance vs the quadratic jnp oracle), the
-one-dispatch-per-iteration engine contract, megastep-vs-legacy token parity
-at f32 compute, and prefix-dedup interactions (hibernate/wake re-indexing,
-index invalidation when the owner is retired mid-batch)."""
+one-dispatch-per-iteration engine contract, megastep tokens against a
+full-sequence greedy reference at f32 compute, and prefix-dedup
+interactions (hibernate/wake re-indexing, index invalidation when the
+owner is retired mid-batch)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -68,7 +69,7 @@ def test_chunked_prefill_kernel_parity(C, b, hq, hkv, d, dv, npages):
 @pytest.mark.parametrize("C", [24, 64])
 def test_budget_width_kernel_parity_ragged_valids(C):
     """The token-budget buckets instantiate the same kernel at widths far
-    beyond the old fixed chunk. Sweep wide C with fully ragged per-row
+    beyond the prefill chunk. Sweep wide C with fully ragged per-row
     valids (inactive 0, decode-like 1, partial, full) and pin bitwise
     parity against the gathered-view oracle plus fp32 agreement with the
     quadratic ref — the masking generalizes, the page walk doesn't care."""
@@ -391,8 +392,7 @@ def _paged(cfg, params, **kw):
 def test_megastep_is_one_jit_dispatch_per_iteration(setup):
     """The tentpole contract: a mixed prefill/decode workload (fresh
     prompts, extends, decodes interleaving) runs at exactly ONE jitted
-    dispatch per work-doing engine iteration; the legacy loop costs
-    1 + n_prefilling."""
+    dispatch per work-doing engine iteration."""
     cfg, params = setup
     eng = _paged(cfg, params)
     rids = [eng.submit(np.arange(20 + 3 * i) % 50, max_new_tokens=4,
@@ -404,33 +404,51 @@ def test_megastep_is_one_jit_dispatch_per_iteration(setup):
     assert eng.jit_dispatches == eng.steps_dispatched > 0
     assert eng.jit_dispatches_per_step == 1.0
 
-    legacy = _paged(cfg, params, megastep=False)
-    for i in range(3):
-        legacy.submit(np.arange(20 + 3 * i) % 50, max_new_tokens=4)
-    legacy.run_to_completion()
-    assert legacy.jit_dispatches_per_step > 1.0
+
+def _greedy_reference(logits_fn, turns, pad_to: int):
+    """Each turn's greedy tokens from full-sequence forward passes: the
+    sequence is every prompt so far and every token sampled so far, right-
+    padded to ``pad_to`` (causal attention: the padding cannot reach the
+    position read), so one trace serves every length."""
+    seq, outs = [], []
+    for prompt, n_new in turns:
+        seq += [int(t) for t in prompt]
+        out = []
+        for _ in range(n_new):
+            toks = np.zeros((1, pad_to), np.int32)
+            toks[0, :len(seq)] = seq
+            logits = np.asarray(logits_fn(toks))[0, len(seq) - 1]
+            out.append(int(np.argmax(logits)))
+            seq.append(out[-1])
+        outs.append(out)
+    return outs
 
 
-def test_megastep_matches_legacy_tokens_at_f32(setup):
-    """At float32 compute the megastep and the PR 2 per-sequence loop are
-    the same model: identical greedy tokens across a mixed multi-turn run.
-    (At bf16 compute the fused batch shapes round differently — megastep
-    self-consistency is what the park/resume suite pins there.)"""
+def test_megastep_matches_reference_greedy_at_f32(setup):
+    """At float32 compute the megastep is the model: across a mixed
+    multi-turn run (fresh prompts, then extends, rows prefilling while
+    batchmates decode), every turn's tokens equal greedy decoding by the
+    plain full-sequence forward."""
     cfg, _ = setup
     cfg32 = cfg.replace(compute_dtype="float32")
-    params32 = build(cfg32).init_params(jax.random.PRNGKey(0))
+    model = build(cfg32)
+    params32 = model.init_params(jax.random.PRNGKey(0))
+    prompts = [np.arange(5 + 7 * i) % 50 for i in range(3)]
+    extend = [3, 4, 5]
 
-    def run(megastep):
-        eng = _paged(cfg32, params32, megastep=megastep, prefill_chunk=8)
-        rids = [eng.submit(np.arange(5 + 7 * i) % 50, max_new_tokens=6,
-                           retain=True) for i in range(3)]
-        eng.run_to_completion()
-        for r in rids:
-            eng.extend(r, [3, 4, 5], max_new_tokens=4)
-        eng.run_to_completion()
-        return {r: eng.reqs[r].out_tokens for r in rids}
+    eng = _paged(cfg32, params32, prefill_chunk=8)
+    rids = [eng.submit(p, max_new_tokens=6, retain=True) for p in prompts]
+    eng.run_to_completion()
+    first = {r: list(eng.reqs[r].out_tokens) for r in rids}
+    for r in rids:
+        eng.extend(r, extend, max_new_tokens=4)
+    eng.run_to_completion()
 
-    assert run(True) == run(False)
+    fwd = jax.jit(lambda prm, t: model.forward(prm, {"tokens": t})[0])
+    for r, p in zip(rids, prompts):
+        want = _greedy_reference(lambda t: fwd(params32, t),
+                                 [(p, 6), (extend, 4)], pad_to=eng.max_len)
+        assert [first[r], eng.reqs[r].out_tokens] == want
 
 
 def test_engine_records_kernel_plan_per_compiled_bucket(setup):

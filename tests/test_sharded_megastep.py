@@ -101,13 +101,6 @@ def test_engine_rejects_mesh_without_tp_axis():
                              mesh=FakeMesh({"model": 2}))
 
 
-def test_engine_rejects_mesh_with_legacy_loop():
-    from repro.serving import PagedInferenceEngine
-    with pytest.raises(ValueError, match="megastep"):
-        PagedInferenceEngine(_smoke_cfg(), None, megastep=False,
-                             mesh=FakeMesh({"tp": 2}))
-
-
 def test_engine_rejects_indivisible_tp():
     from repro.serving import PagedInferenceEngine
     # smoke gemma-2b is MQA (hkv=1): nothing above tp=1 divides it
